@@ -21,6 +21,7 @@ import tempfile
 
 import jax
 
+from ...framework import compile_cache
 from .parser import (parse_spmd_warnings, parse_hlo_collectives,
                      ShardingEvent)
 
@@ -118,56 +119,10 @@ def audit_from_text(stderr_text, hlo_text=None, label=''):
         stderr_tail=(stderr_text or '')[-_TAIL_CHARS:])
 
 
-@contextlib.contextmanager
-def _compile_cache_suspended():
-    """Force the audited compile through XLA even when the process has a
-    persistent compile cache configured (restored on exit). The config
-    flip alone is not enough: jax memoizes cache-in-use at the first
-    compile of the process (compilation_cache._cache_checked), so the
-    latch must be dropped on BOTH transitions for the flip to be seen."""
-    try:
-        was = bool(jax.config.jax_enable_compilation_cache)
-    except Exception:
-        yield
-        return
-    if not was:
-        yield
-        return
-    try:
-        from ...framework.compile_cache import _drop_cache_latch
-    except Exception:
-        def _drop_cache_latch():
-            pass
-    try:
-        jax.config.update('jax_enable_compilation_cache', False)
-    except Exception:
-        yield
-        return
-    _drop_cache_latch()
-    try:
-        yield
-    finally:
-        try:
-            jax.config.update('jax_enable_compilation_cache', True)
-        except Exception:
-            pass
-        _drop_cache_latch()
-
-
-@contextlib.contextmanager
 def _mesh_scope(mesh):
     """Make `mesh` the ambient mesh for PartitionSpec-based constraints
-    inside the audited fn, across jax generations."""
-    if mesh is None:
-        yield
-        return
-    use_mesh = getattr(getattr(jax, 'sharding', None), 'use_mesh', None)
-    if use_mesh is not None:
-        with use_mesh(mesh):
-            yield
-        return
-    with mesh:
-        yield
+    inside the audited fn."""
+    return contextlib.nullcontext() if mesh is None else mesh
 
 
 def audit_callable(fn, args=(), kwargs=None, mesh=None, label=''):
@@ -179,7 +134,7 @@ def audit_callable(fn, args=(), kwargs=None, mesh=None, label=''):
     wrapped = jax.jit(lambda *a, **k: fn(*a, **k))
     with _mesh_scope(mesh):
         lowered = wrapped.lower(*args, **kwargs)
-        with _compile_cache_suspended(), capture_compiler_stderr() as cap:
+        with compile_cache.suspended(), capture_compiler_stderr() as cap:
             compiled = lowered.compile()
     try:
         hlo = compiled.as_text()
@@ -193,7 +148,7 @@ def audit_train_step(step, inputs, labels, label=''):
     """Audit a framework.functional.TrainStep for one batch. Uses
     compiled_executable (which re-lowers+recompiles every call, so the
     partitioner warnings are emitted even for a step that already ran)."""
-    with _compile_cache_suspended(), capture_compiler_stderr() as cap:
+    with compile_cache.suspended(), capture_compiler_stderr() as cap:
         compiled = step.compiled_executable(inputs, labels)
     try:
         hlo = compiled.as_text()

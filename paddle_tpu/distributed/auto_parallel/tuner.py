@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ...framework import compile_cache
 from . import audit as ap_audit
 from .planner import (PipelinePlan, plan_pipeline, _axis_sizes, _pad, _U)
 
@@ -268,7 +269,7 @@ def _audit_probe(fn, args, mesh, label):
     wrapped = jax.jit(lambda *a: fn(*a))
     with ap_audit._mesh_scope(mesh):
         lowered = wrapped.lower(*args)
-        with ap_audit._compile_cache_suspended(), \
+        with compile_cache.suspended(), \
                 ap_audit.capture_compiler_stderr() as cap:
             compiled = lowered.compile()
     try:

@@ -15,7 +15,6 @@ from jax import lax
 from ..framework.core import Tensor, run_op, wrap_out
 from ..tensor._helpers import ensure_tensor
 from .topology import Group
-from .shard_map_compat import axis_size as _axis_size
 from .env import get_world_size
 
 __all__ = ['ReduceOp', 'new_group', 'all_reduce', 'all_gather', 'broadcast',
@@ -159,7 +158,7 @@ def p2p_shift(x, axis_name, shift=1):
     t = ensure_tensor(x)
 
     def fn(a):
-        n = _axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         perm = [(i, (i + shift) % n) for i in range(n)]
         return lax.ppermute(a, axis_name, perm)
     return run_op('ppermute', fn, t)

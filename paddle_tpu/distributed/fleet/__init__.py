@@ -305,13 +305,23 @@ def fleet_train_step(model, loss_fn, optimizer, strategy=None, hcg=None):
     # sp-sharded sequence, under pp the loss runs inside the pipeline
     # engine — both have their own layouts.
     fce_sharding = None
+    attn_sharding = None
     mshape = dict(hcg.mesh.shape)
-    if mshape.get('mp', 1) > 1 and mshape.get('sp', 1) <= 1 \
-            and mshape.get('pp', 1) <= 1:
+    if mshape.get('sp', 1) <= 1 and mshape.get('pp', 1) <= 1:
         from jax.sharding import NamedSharding, PartitionSpec as P
         rows = tuple(a for a in ('dp', 'sharding') if mshape.get(a, 1) > 1)
-        fce_sharding = NamedSharding(
-            hcg.mesh, P(rows if rows else None, 'mp'))
+        heads = 'mp' if mshape.get('mp', 1) > 1 else None
+        if heads:
+            fce_sharding = NamedSharding(
+                hcg.mesh, P(rows if rows else None, 'mp'))
+        # the Pallas flash kernels are not GSPMD-partitionable: name the
+        # [B, N, H, D] operands' layout so each device runs them on its
+        # shard (ops/flash_attention.partitioned). Same restriction as
+        # above: sp attention has its own shard_map, and under pp the
+        # blocks already run inside the pipeline's.
+        if hcg.mesh.size > 1:
+            attn_sharding = NamedSharding(
+                hcg.mesh, P(rows if rows else None, None, heads, None))
 
     cfg = strategy_mod.build_shardings(model, optimizer, hcg.mesh, sdict)
     strategy_mod.place_params(model, cfg['param_shardings'])
@@ -330,7 +340,8 @@ def fleet_train_step(model, loss_fn, optimizer, strategy=None, hcg=None):
         pp_state=pp_state,
         init_loss_scaling=s.amp_configs.get('init_loss_scaling', 65536.0),
         ls_growth_interval=s.amp_configs.get('incr_every_n_steps', 2000),
-        fce_sharding=fce_sharding)
+        fce_sharding=fce_sharding,
+        attn_sharding=attn_sharding)
     return step
 
 

@@ -28,9 +28,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ...framework import functional as func_mod
 from ...framework import random as rng_mod
@@ -288,7 +287,7 @@ class ShardMapDPStep:
                 in_specs=(state_spec, (batch_spec, batch_spec), P(), P(),
                           P()),
                 out_specs=(state_spec, P()),
-                check_rep=False)(state, batch, lr, t, key)
+                check_vma=False)(state, batch, lr, t, key)
 
         return step
 

@@ -124,7 +124,7 @@ class LocalFS:
 
 class HDFSClient(LocalFS):
     """HDFS via shell pipes in the reference (framework/io/fs.cc); this env
-    has no HDFS. DECLARED shim (VERDICT r3 item 9): it warns at
+    has no HDFS. DECLARED shim: it warns at
     construction that it is LocalFS-backed (gcsfuse/NFS-mounted paths go
     through the LocalFS API) and raises on genuine `hdfs://` URIs rather
     than silently treating them as local paths."""

@@ -32,14 +32,13 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..framework import functional as func_mod
 from ..framework import random as rng_mod
 from ..framework.core import Tensor
 from .pipeline import _cpu_mesh
-from .shard_map_compat import shard_map
 from .auto_parallel import tuner as ap_tuner
 
 __all__ = ['one_f_one_b_loss', 'supports_1f1b']

@@ -17,7 +17,7 @@ tokens — and it goes through the ring.
 import functools
 
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 __all__ = ['enable_sequence_parallel', 'disable_sequence_parallel',
            'sequence_parallel_state', 'sp_attention', 'make_sp_state',
@@ -111,12 +111,12 @@ def sp_attention(q, k, v, causal, scale, state=None, dropout_p=0.0,
                       dropout_key=rank_key)
         wrapped = shard_map(body, mesh=mesh,
                             in_specs=(spec, spec, spec, P()),
-                            out_specs=spec, check_rep=False)
+                            out_specs=spec, check_vma=False)
         return wrapped(q, k, v, dropout_key)
     wrapped = shard_map(
         functools.partial(fn, axis_name=axis, causal=causal, scale=scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
     return wrapped(q, k, v)
 
 
@@ -140,12 +140,12 @@ def _zigzag_sp(q, k, v, scale, mesh, axis, spec, n_dev, dropout_p,
                 qq, kk, vv, axis_name=axis, scale=scale,
                 dropout_p=dropout_p, dropout_key=key)
         out = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec, P()),
-                        out_specs=spec, check_rep=False)(qz, kz, vz,
+                        out_specs=spec, check_vma=False)(qz, kz, vz,
                                                          dropout_key)
     else:
         out = shard_map(
             functools.partial(ra.zigzag_ring_attention, axis_name=axis,
                               scale=scale),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_rep=False)(qz, kz, vz)
+            check_vma=False)(qz, kz, vz)
     return jnp.take(out, inv, axis=1)

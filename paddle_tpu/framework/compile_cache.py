@@ -1,9 +1,8 @@
 """One configuration path for jax's persistent compilation cache.
 
-Every entry point that used to flip the four ``jax.config`` knobs by
-hand (the Predictor's ``set_optim_cache_dir``, the bench ladder's
-``_enable_persistent_cache``, bench_extra's serving rungs, the dryrun
-driver) now goes through :func:`configure`, which is idempotent across
+Every entry point that wants the cache (the Predictor's
+``set_optim_cache_dir``, bench.py, bench_extra.py, chip_smoke.py, the
+dryrun driver) goes through :func:`configure`, which is idempotent across
 repeated calls and callers — two Predictors in one process, or a
 Predictor plus the bench harness, configure the cache once.
 
@@ -16,18 +15,23 @@ from a real backend compile, and exports them as the
 ``perf_persistent_cache_hits_total`` / ``misses_total`` families.
 Bench rows surface the same tallies as ``compile_cache_hit_rate``.
 
-Directory resolution order: explicit argument >
-``PADDLE_TPU_COMPILE_CACHE_DIR`` > ``PADDLE_TPU_CACHE_DIR`` (the bench
-ladder's historical knob) > ``<repo>/.jax_cache``.
+Where the cache lives: ``JAX_COMPILATION_CACHE_DIR``, when the
+environment sets it — jax reads that variable itself, and this module
+then sets no directory in code, whatever a caller passes (a driver that
+places the cache must find it where it put it). Otherwise the explicit
+argument (the Predictor's ``set_optim_cache_dir``), else the fixed
+``<repo>/.jax_cache``. The path is part of jax's cache key, so it is
+never derived from a temp dir, a pid or the time.
 
 Stdlib-only at import time (jax loads inside :func:`configure`), so
 schema tooling can import the counters without touching a backend.
 """
+import contextlib
 import os
 import threading
 
-__all__ = ['configure', 'disable', 'enabled', 'cache_dir', 'default_dir',
-           'stats', 'hit_rate', 'thread_state', 'reset_stats']
+__all__ = ['configure', 'disable', 'suspended', 'enabled', 'cache_dir',
+           'default_dir', 'stats', 'hit_rate', 'thread_state', 'reset_stats']
 
 _HIT_EVENT = '/jax/compilation_cache/cache_hits'
 _MISS_EVENT = '/jax/compilation_cache/cache_misses'
@@ -40,12 +44,14 @@ _misses = 0
 _tls = threading.local()    # per-thread hit/miss tallies for watchdogs
 
 
+_ENV_DIR = 'JAX_COMPILATION_CACHE_DIR'
+
+
 def default_dir():
     """The cache dir :func:`configure` uses when none is given."""
-    return (os.environ.get('PADDLE_TPU_COMPILE_CACHE_DIR')
-            or os.environ.get('PADDLE_TPU_CACHE_DIR')
-            or os.path.join(os.path.dirname(os.path.dirname(
-                os.path.dirname(os.path.abspath(__file__)))), '.jax_cache'))
+    return os.environ.get(_ENV_DIR) or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), '.jax_cache')
 
 
 def _on_event(event, **kwargs):
@@ -66,38 +72,34 @@ def _install_listener():
     global _listener
     if _listener is not None:
         return
-    try:
-        from jax._src import monitoring as _mon
-        _mon.register_event_listener(_on_event)
-        _listener = _on_event
-    except Exception:
-        _listener = None    # jaxlib without jax.monitoring: counts stay 0
+    import jax
+    jax.monitoring.register_event_listener(_on_event)
+    _listener = _on_event
 
 
 def configure(path=None):
-    """Enable the persistent compile cache at `path` (resolution order
-    in the module docstring) and install the hit/miss listener.
+    """Enable the persistent compile cache (where: module docstring) and
+    install the hit/miss listener.
 
     Idempotent: repeat calls with the same effective dir are no-ops; a
     different dir re-points the live config (last caller wins, which is
-    what the reference's per-Predictor cache dirs did). Returns the
-    effective dir, or None when jax rejects every knob (older jaxlib:
-    the cache is best-effort, counters stay installed)."""
+    what the reference's per-Predictor cache dirs did) — except under
+    ``JAX_COMPILATION_CACHE_DIR``, which always wins and is never
+    overwritten. Returns the effective dir."""
     global _dir
-    path = path or default_dir()
+    env_dir = os.environ.get(_ENV_DIR)
+    path = env_dir or path or default_dir()
     with _lock:
         already = _dir == path
     _install_listener()
     if already:
         return path
     import jax
-    try:
-        jax.config.update('jax_enable_compilation_cache', True)
+    jax.config.update('jax_enable_compilation_cache', True)
+    if not env_dir:
         jax.config.update('jax_compilation_cache_dir', path)
-        jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)
-        jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)
-    except Exception:
-        return None
+    jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)
+    jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)
     _drop_cache_latch()
     with _lock:
         _dir = path
@@ -106,31 +108,47 @@ def configure(path=None):
 
 def _drop_cache_latch():
     """jax memoizes "is the cache used" at the FIRST compile of the
-    process (compilation_cache._cache_checked); any compile before
-    configure() would latch it off and make the config knobs dead.
-    reset_cache() drops the latch (and the in-memory handle — the disk
-    cache is untouched) so the next compile re-evaluates the config."""
-    try:
-        from jax._src import compilation_cache as _jcc
-        _jcc.reset_cache()
-    except Exception:
-        pass
+    process; any compile before configure() would latch it off and make
+    the config knobs dead. reset_cache() drops the latch (and the
+    in-memory handle — the disk cache is untouched) so the next compile
+    re-evaluates the config."""
+    from jax.experimental.compilation_cache import compilation_cache
+    compilation_cache.reset_cache()
 
 
 def disable():
-    """Turn the persistent cache back off (tests; audits use a scoped
-    disable instead — see auto_parallel.audit). Counters keep running."""
+    """Turn the persistent cache back off (tests; audits use the scoped
+    :func:`suspended` instead). Counters keep running."""
     global _dir
     with _lock:
         if _dir is None:
             return
         _dir = None
-    try:
-        import jax
-        jax.config.update('jax_enable_compilation_cache', False)
-    except Exception:
-        pass
+    import jax
+    jax.config.update('jax_enable_compilation_cache', False)
     _drop_cache_latch()
+
+
+@contextlib.contextmanager
+def suspended():
+    """Force the compiles inside the block through XLA even when the
+    process has a persistent cache in use (restored on exit) — for
+    audits that read what only a real compile emits, and for compiles
+    for a described, unattached device, whose cache entries cannot be
+    read back. The config flip alone is not enough: jax memoizes
+    cache-in-use at the first compile, so the latch is dropped on BOTH
+    transitions."""
+    import jax
+    if not jax.config.jax_enable_compilation_cache:
+        yield
+        return
+    jax.config.update('jax_enable_compilation_cache', False)
+    _drop_cache_latch()
+    try:
+        yield
+    finally:
+        jax.config.update('jax_enable_compilation_cache', True)
+        _drop_cache_latch()
 
 
 def enabled():

@@ -23,23 +23,43 @@ def _backend_of(name):
 
 def resolve_device(device):
     """Any paddle device spec -> a jax.Device: 'tpu:3'/'cpu'/'cuda',
-    a Place object, or a jax.Device passthrough."""
+    a Place object, or a jax.Device passthrough.
+
+    A backend asked for BY NAME ('tpu', 'tpu:0', 'gpu') that this host
+    does not have raises — it never resolves to another backend's device.
+    The compute Place facades (CUDAPlace/XPUPlace/NPUPlace) are what
+    reference scripts write to mean "the accelerator, whatever it is",
+    so they resolve to the default backend's devices."""
     if isinstance(device, jax.Device):
         return device
     if isinstance(device, _Place):
-        backend = 'cpu' if isinstance(device, (CPUPlace, CUDAPinnedPlace)) \
-            else 'tpu'
-        idx = device.device_id
-    else:
-        name, _, idx_s = str(device).partition(':')
-        backend = _backend_of(name)
-        idx = int(idx_s) if idx_s else 0
+        host = isinstance(device, (CPUPlace, CUDAPinnedPlace))
+        devs = jax.devices('cpu') if host else jax.devices()
+        return devs[device.device_id]
+    name, _, idx_s = str(device).partition(':')
+    backend = _backend_of(name)
     try:
         devs = jax.devices(backend)
-    except RuntimeError:
-        # graceful fallback (e.g. asking for tpu on a cpu-only host)
-        devs = jax.devices()
-    return devs[idx]  # explicit out-of-range index raises, like set_device
+    except RuntimeError as e:
+        raise RuntimeError(
+            'device %r was asked for by name but this process has no %r '
+            'backend (default backend: %s)'
+            % (device, backend, jax.default_backend())) from e
+    return devs[int(idx_s) if idx_s else 0]  # out-of-range index raises
+
+
+def process_holds_accelerator():
+    """True once THIS process has initialised a non-CPU jax backend.
+
+    A chip belongs to one process at a time: a process that has touched
+    jax on a TPU host holds the chip, and a child that needs it then
+    fails or hangs. Spawners (distributed.spawn, serving.fabric) ask this
+    before starting a child that is not pinned to the CPU backend. Never
+    initialises a backend itself (which is why it reads jax's private
+    xla_bridge: every public query initialises one)."""
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized() and \
+        jax.default_backend() != 'cpu'
 
 
 def set_device(device):
@@ -87,8 +107,9 @@ def device_count(backend=None):
 
 
 class _Place:
-    """Place facades (reference platform/place.h variants): on TPU all
-    compute places resolve to the accelerator; identities kept for API
+    """Place facades (reference platform/place.h variants): compute
+    places resolve to the default backend's devices (the accelerator
+    where there is one — see resolve_device); identities kept for API
     parity and isinstance checks."""
 
     def __init__(self, device_id=0):

@@ -216,7 +216,9 @@ class Predictor:
         a restarted server skips compilation entirely). Routed through
         framework.compile_cache — the one repo-wide configuration path —
         so several Predictors (or a Predictor plus the bench harness) in
-        one process configure the cache once, idempotently."""
+        one process configure the cache once, idempotently, and a cache
+        placed from outside (JAX_COMPILATION_CACHE_DIR) wins over this
+        directory."""
         cache_dir = self._config._cache_dir
         if not cache_dir:
             return

@@ -2,8 +2,7 @@
 
 XLA's compiled executables carry their own cost model
 (``compiled.cost_analysis()``: flops and bytes accessed of the
-optimized program). This module turns that into the numbers VERDICT
-keeps asking benches for:
+optimized program). This module turns that into:
 
   arithmetic intensity  — flops / bytes accessed;
   roofline bound        — 'compute' when intensity clears the ridge
@@ -13,52 +12,67 @@ keeps asking benches for:
   mfu_est               — analytic flops / measured step time / peak,
                           given a measured wall time.
 
-Peaks follow the repo's existing conventions (bench.py,
-tools/profile_analysis.py): v5e bf16 197 TFLOP/s + 819 GB/s HBM; the
-CPU numbers are nominal comparators so degraded smoke rows stay
-self-consistent, not real hardware specs.
+This is the repo's ONE table of device peaks (bench.py, bench_extra.py
+and chip_smoke.py read it). A TPU is keyed by the ``device_kind`` jax
+reports for it, never by the bare platform name — 'tpu' is not one
+chip — and a TPU kind that is not in the table raises: a utilization
+against another chip's peak is a wrong number, not an estimate. The
+``cpu``/``gpu`` rows are nominal comparators for the CPU-side callers
+(the sharding tuner's ranking, Model.fit telemetry), not hardware specs.
 
 All jax imports are deferred — the module stays stdlib-importable for
 the schema tooling.
 """
 
-__all__ = ['PEAKS', 'platform_peaks', 'cost_of', 'roofline', 'estimate',
-           'record']
+__all__ = ['TPU_PEAKS', 'PEAKS', 'platform_peaks', 'cost_of', 'roofline',
+           'estimate', 'record']
 
-# backend -> (peak FLOP/s, peak bytes/s)
+# jax device_kind -> (peak bf16 FLOP/s, peak HBM bytes/s) per chip
+TPU_PEAKS = {
+    # TPU v5e. Source: Google Cloud documentation, "TPU v5e" system
+    # architecture — 197 TFLOP/s bf16, 16 GB HBM2e at 819 GB/s per chip.
+    # Kind string as the chip reports it (chip_smoke.py, 2026-09-26).
+    'TPU v5 lite': (197e12, 819e9),
+}
+
+# non-TPU platform -> (peak FLOP/s, peak bytes/s): comparators only
 PEAKS = {
-    'tpu': (197e12, 819e9),     # v5e bf16 / HBM (bench.py convention)
     'gpu': (312e12, 2039e9),    # A100 bf16 / HBM2e nominal
-    'cpu': (1e12, 50e9),        # nominal comparator (bench.py uses 1e12)
+    'cpu': (1e12, 50e9),        # nominal comparator, not a hardware spec
 }
 
 
 def platform_peaks(platform=None, peak_flops=None, peak_bandwidth=None):
-    """(platform, peak_flops, peak_bytes_per_s) with overrides applied;
-    platform defaults to the active jax backend ('cpu' without jax)."""
+    """(name, peak_flops, peak_bytes_per_s) with overrides applied.
+
+    `platform` is a TPU device_kind or a non-TPU platform name; None
+    reads it off ``jax.devices()[0]``. Raises KeyError for a device the
+    tables do not know unless both peaks are given."""
     if platform is None:
-        try:
-            import jax
-            platform = jax.default_backend()
-        except Exception:
-            platform = 'cpu'
-    pf, pb = PEAKS.get(platform, PEAKS['cpu'])
+        import jax
+        dev = jax.devices()[0]
+        platform = dev.device_kind if dev.platform == 'tpu' \
+            else dev.platform
+    if peak_flops and peak_bandwidth:
+        return platform, float(peak_flops), float(peak_bandwidth)
+    table = TPU_PEAKS if platform in TPU_PEAKS else PEAKS
+    if platform not in table:
+        raise KeyError(
+            'no peaks recorded for device %r: add its published peak '
+            'FLOP/s and bytes/s, with their source, to '
+            'monitor/perf/costmodel.py (known: %s)'
+            % (platform, sorted(TPU_PEAKS) + sorted(PEAKS)))
+    pf, pb = table[platform]
     return (platform,
             float(peak_flops) if peak_flops else pf,
             float(peak_bandwidth) if peak_bandwidth else pb)
 
 
 def cost_of(compiled):
-    """{'flops', 'bytes_accessed'} from a jax Compiled's cost analysis;
-    None when the backend exposes none. Tolerates both the dict and the
-    [dict] return shapes across jax versions."""
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:
-        return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    if not isinstance(ca, dict):
+    """{'flops', 'bytes_accessed'} from a jax Compiled's cost analysis
+    (a dict); None when the program carries no cost."""
+    ca = compiled.cost_analysis()
+    if not ca:
         return None
     flops = float(ca.get('flops', 0.0) or 0.0)
     nbytes = float(ca.get('bytes accessed', 0.0) or 0.0)

@@ -120,9 +120,7 @@ class CompileWatchdog:
 
     ``enabled`` is a plain attribute checked first in the listener (the
     registry's one-load+branch discipline); ``close()`` unregisters the
-    listener — always pair construction with close() in tests. When
-    jax.monitoring is unavailable the watchdog constructs fine and
-    ``active`` stays False.
+    listener — always pair construction with close() in tests.
     """
 
     def __init__(self, registry=None, tracer=None, strict=None,
@@ -164,21 +162,14 @@ class CompileWatchdog:
     # ---- listener lifecycle -------------------------------------------
 
     def _install(self):
-        try:
-            from jax._src import monitoring as _mon
-            register = _mon.register_event_duration_secs_listener
-        except Exception:
-            return              # jaxlib without jax.monitoring: no-op
+        import jax
 
         def _listen(event, duration, **kw):
             if self.enabled:
                 self._on_event(event, duration)
 
-        try:
-            register(_listen)
-            self._listener = _listen
-        except Exception:
-            self._listener = None
+        jax.monitoring.register_event_duration_secs_listener(_listen)
+        self._listener = _listen
 
     @property
     def active(self):
@@ -191,11 +182,8 @@ class CompileWatchdog:
         listener, self._listener = self._listener, None
         if listener is None:
             return
-        try:
-            from jax._src import monitoring as _mon
-            _mon._unregister_event_duration_listener_by_callback(listener)
-        except Exception:
-            pass
+        import jax
+        jax.monitoring.unregister_event_duration_listener(listener)
 
     def __enter__(self):
         return self

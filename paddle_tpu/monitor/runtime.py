@@ -39,31 +39,24 @@ def read_rss_bytes():
 
 
 def jax_cache_entries():
-    """Total entries across jax's weakref-LRU tracing caches plus the
+    """Total entries across jax's registered tracing caches plus the
     C++ pjit executable caches — a flat number means no retrace churn.
 
-    The infer-params cache is already a member of the weakref-LRU list,
-    so it must not be added again; the C++ fast-path caches
-    (PjitFunctionCache) are NOT in that list, and without them this
-    probe under-reports jax.jit churn on current jaxlib — every
-    steady-state jit call resolves through them."""
-    total = 0
-    try:
-        import jax._src.util as _u
-        for c in list(_u._weakref_lru_caches):
-            try:
-                total += c.cache_info().currsize
-            except Exception:
-                continue
-    except Exception:
-        return None
-    try:
-        import jax._src.pjit as _pjit
-        for cache in (_pjit._cpp_pjit_cache_fun_only,
-                      _pjit._cpp_pjit_cache_explicit_attributes):
-            total += cache.size()
-    except Exception:
-        pass
+    jax keeps every weakref-LRU/lru cache it creates in
+    ``jax._src.util._caches`` (what ``jax.clear_caches`` walks); the C++
+    fast-path caches (PjitFunctionCache) are NOT in that registry, and
+    without them this probe under-reports jax.jit churn — every
+    steady-state jit call resolves through them. Private jax API with no
+    public spelling: a jax that moves it fails here loudly rather than
+    reporting nothing."""
+    import jax._src.pjit as _pjit
+    import jax._src.util as _u
+    # two of the registered memoizers expose cache_clear only
+    total = sum(c.cache_info().currsize for c in list(_u._caches)
+                if hasattr(c, 'cache_info'))
+    for cache in (_pjit._cpp_pjit_cache_fun_only,
+                  _pjit._cpp_pjit_cache_explicit_attributes):
+        total += cache.size()
     return total
 
 
@@ -121,9 +114,7 @@ class RuntimeSampler:
                 self._devices.set(len(jax.devices()))
             except Exception:
                 pass
-            entries = jax_cache_entries()
-            if entries is not None:
-                self._caches.set(entries)
+            self._caches.set(jax_cache_entries())
         for fn in list(self._sources):
             try:
                 fn(self.registry)

@@ -2,8 +2,11 @@
 
 The jnp reference path always works (and XLA fuses it well); the Pallas flash
 kernel (ops/flash_attention.py) kicks in on TPU for long sequences where HBM
-traffic of the naive path dominates. Reference parity:
-paddle incubate sparse_attention / nn.MultiHeadAttention core.
+traffic of the naive path dominates. Which implementation runs is decided
+here, before the call, and shows in the op name (`flash_attention`,
+`blockwise_attention`, `sdpa`); a chosen kernel that raises is not caught.
+Reference parity: paddle incubate sparse_attention / nn.MultiHeadAttention
+core.
 """
 import math
 import os
@@ -12,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from ...framework.core import run_op
+from ...ops import flash_attention as fa
 from ...tensor._helpers import ensure_tensor
 
 
@@ -47,6 +51,12 @@ def _blockwise_block(seq_len):
                 'PADDLE_TPU_BLOCKWISE_BLOCK=%d does not tile seq len %d '
                 '(pick a divisor)' % (blk, seq_len))
     return blk
+
+
+def _bhnd(t):
+    """Shape and dtype of a [B, N, H, D] tensor in the kernels' layout."""
+    b, n, h, d = t.shape
+    return jax.ShapeDtypeStruct((b, h, n, d), t._data.dtype)
 
 
 def _sdpa_ref(q, k, v, mask, dropout_p, causal, scale, drop_key=None):
@@ -115,30 +125,26 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         return run_op('sp_attention', fn, q, k, v)
 
     impl = _attn_impl()
-    use_flash = False
-    if impl in ('auto', 'flash'):
-        try:
-            from ...ops import flash_attention as fa
-            if q._data.ndim == 4 and q.shape[1] >= 512 and q.shape[-1] <= 256:
-                use_flash = fa.is_available()
-        except Exception:
-            use_flash = False
-
     mask_arr = ensure_tensor(attn_mask)._data if attn_mask is not None else None
+    plain = q._data.ndim == 4 and mask_arr is None and dropout_p == 0.0
 
-    if use_flash and mask_arr is None and dropout_p == 0.0:
-        from ...ops import flash_attention as fa
+    flash_miss = False
+    if impl in ('auto', 'flash') and plain and q.shape[1] >= 512 \
+            and q.shape[-1] <= 256 and fa.is_available():
+        # shape eligibility is routing: an ineligible shape takes the
+        # blockwise op below under its own name (strict mode raises
+        # inside the flash op instead)
+        flash_miss = fa.unsupported_reason(
+            _bhnd(q), _bhnd(k), _bhnd(v), causal=is_causal) is not None \
+            and not fa.strict_mode()
+        if not flash_miss:
+            def fn(qq, kk, vv):
+                return fa.flash_attention_bnhd(qq, kk, vv, causal=is_causal,
+                                               scale=scale)
+            return run_op('flash_attention', fn, q, k, v)
 
-        def fn(qq, kk, vv):
-            return fa.flash_attention_bnhd(qq, kk, vv, causal=is_causal,
-                                           scale=scale)
-        return run_op('flash_attention', fn, q, k, v)
-
-    use_blockwise = (impl == 'blockwise' or
-                     (impl == 'auto' and q._data.ndim == 4 and
-                      q.shape[1] >= _blockwise_min_seq()))
-    if use_blockwise and q._data.ndim == 4 and mask_arr is None and \
-            dropout_p == 0.0:
+    if plain and (impl == 'blockwise' or flash_miss or
+                  (impl == 'auto' and q.shape[1] >= _blockwise_min_seq())):
         from ...ops import blockwise_attention as bw
         # smaller blocks widen the causal-skip window (tq = N/block must
         # be > 1 for any future block to exist); tunable for benchmarking

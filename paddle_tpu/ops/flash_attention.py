@@ -7,13 +7,18 @@ reference's fused attention CUDA ops (operators/fused/).
 
 head_dim needs only %64 == 0 (BERT/GPT-base d=64 runs the kernel; the MXU
 contracts 64-wide fine, Mosaic pads lanes). Sequence lengths must divide
-the block sizes; anything else falls back to the jnp reference — loudly
-under PADDLE_TPU_FLASH_STRICT=1, where a silent fallback would invalidate
-a reported TPU number.
+the block sizes. A shape the kernels cannot take is a ROUTING decision made
+before any kernel is called (`unsupported_reason`): it goes to the
+blockwise XLA path (ops/blockwise_attention.py), or raises under
+PADDLE_TPU_FLASH_STRICT=1. An exception out of a kernel that WAS chosen
+(a Mosaic compile error included) always propagates — nothing here
+catches it and substitutes another implementation.
 
 PADDLE_TPU_FLASH_INTERPRET=1 runs the kernels through the Pallas
 interpreter on CPU — the hardware-free correctness path for tests.
 """
+import contextlib
+import contextvars
 import functools
 import math
 import os
@@ -38,25 +43,36 @@ _NEG_INF = -1e30
 
 
 def is_available():
+    """True when the kernels can run here: on a TPU, or anywhere under
+    the Pallas interpreter. A backend that fails to initialise raises —
+    it is not read as "no TPU"."""
     if os.environ.get('PADDLE_TPU_FLASH_DISABLE', '0') == '1':
-        return False  # explicit off-switch (bench retry safety valve)
+        return False  # explicit off-switch
     if interpret_mode():
         return True
-    try:
-        return jax.devices()[0].platform == 'tpu'
-    except Exception:
-        return False
+    return jax.devices()[0].platform == 'tpu'
 
 
 def strict_mode():
-    """PADDLE_TPU_FLASH_STRICT=1 (set by bench/TPU tests): ANY fallback to
-    the jnp reference — including a shape-based one — must raise, not
-    silently return; a fallback would invalidate any reported TPU number."""
+    """PADDLE_TPU_FLASH_STRICT=1: a shape the kernels cannot take raises
+    instead of routing to the blockwise path."""
     return os.environ.get('PADDLE_TPU_FLASH_STRICT', '0') == '1'
 
 
 def interpret_mode():
     return os.environ.get('PADDLE_TPU_FLASH_INTERPRET', '0') == '1'
+
+
+def unsupported_reason(q, k, v, causal=False):
+    """None if the Pallas kernels take these [B, H, N, D] operands, else
+    why not — the routing decision callers make BEFORE choosing flash."""
+    if causal and q.shape[2] != k.shape[2]:
+        # the kernels' causal block bounds assume self-attention (q_pos =
+        # global q index); KV-cache decode and chunked prefill need the
+        # bottom-right-aligned mask the blockwise path implements
+        return 'cross-length causal (%d queries, %d keys)' % (
+            q.shape[2], k.shape[2])
+    return _supported(q, k, v)
 
 
 def _supported(q, k, v):
@@ -91,6 +107,8 @@ def _supported(q, k, v):
 
 
 def _ref_bhnd(q, k, v, causal, scale):
+    """Quadratic jnp reference the tests compare the kernels against; no
+    dispatch path reaches it."""
     s = jnp.einsum('bhqd,bhkd->bhqk', q, k) * scale
     if causal:
         # bottom-right aligned: query i is at absolute position m-n+i
@@ -280,20 +298,26 @@ def _std_bwd_blocks(n, m):
 
 # -- scoped-VMEM footprint gate ----------------------------------------------
 #
-# The block clamp above only guarantees DIVISIBILITY; it happily launched
-# configs whose working set Mosaic cannot hold. The in-window failure it
-# must refuse: seq 4096 on the STANDARD kernels at 512/1024 blocks died
-# compiling with "kernel-vmem-stack-oom" (docs/bench_inwindow_r5.jsonl
-# 09:32:35Z), while 2048 at the same blocks and 4096 at 256/512 both ran.
-# The discriminating cost in those captures is the sequential walk: each
-# fori_loop step's f32 score tile [block_q, block_k] lands on the scoped
-# stack, so the standard kernels' footprint grows with steps x tile while
-# the long kernels (grid-walked, one tile per cell) stay O(block). The
-# estimate below — walk steps x score-tile bytes plus the double-buffered
-# staged operand windows — reproduces every observed pass/fail with >2 MiB
-# margin against a 12 MiB budget (VMEM is ~16 MiB/core; the margin leaves
-# room for Mosaic's own buffers). Rejection routes through _supported, so
-# strict mode raises and non-strict falls back to the reference.
+# The block clamp above only guarantees DIVISIBILITY; it happily picks
+# configs whose working set Mosaic cannot hold, and the compiler then fails
+# the whole program with "Ran out of memory in memory space vmem". Which
+# shapes take the kernels is a routing decision made before the call, so
+# the footprint is estimated here: per pass, every BlockSpec window in and
+# out (double-buffered by the pipeline), the f32 accumulator scratch, and
+# one f32 [block_q, block_k] score tile. The loop walk adds nothing: its
+# tiles are reused across iterations.
+#
+# The estimate is held to the compiler, not to memory of old failures:
+# docs/flash_vmem_grid_v5e.jsonl records what libtpu 0.0.34 compiling for a
+# described v5e accepted and refused over 252 (path, seq, head_dim, dtype,
+# blocks) configs. Against a 12 MiB budget (v5e's scoped limit is 16 MiB;
+# the margin covers Mosaic's own temporaries) the gate admits none of the
+# 63 the compiler refused and refuses 23 of the 189 it accepted —
+# tests/test_flash_vmem_clamp.py pins both counts. (The analytic gate this
+# replaces charged one tile per loop step: it refused 69 configs the
+# compiler accepts — the 4096-at-512/1024 capture it was fitted to among
+# them — and admitted 3 it refuses.) Rejection routes through _supported,
+# so strict mode raises and otherwise the shape takes the blockwise path.
 
 _VMEM_BUDGET_MB_DEFAULT = 12
 
@@ -306,37 +330,36 @@ def _vmem_budget_bytes():
 def _vmem_reason(n, m, d, itemsize):
     """None if every dispatched pass fits the scoped-VMEM budget, else a
     reason naming the worst pass, its estimate, and the knobs to turn."""
+    row = d * itemsize      # bytes of one [1, d] operand row
+    acc = d * 4             # ... and of one f32 accumulator row
+    # (pass, block_q, block_k, window rows in+out, f32 scratch rows)
     if _use_long_path(n, m):
         bq, bk = _long_blocks(n, m)
-        tiles = (bq + 2 * bk) * d * itemsize      # q + k/v tiles per cell
-        passes = [('long fwd', 1, bq, bk, tiles + bq * d * 4),
-                  ('long dq', 1, bq, bk, tiles + bq * d * 4),
-                  ('long dk/dv', 1, bq, bk, tiles + 2 * bk * d * 4)]
+        passes = [('long fwd', bq, bk, 2 * bq + 2 * bk, bq),
+                  ('long dq', bq, bk, 3 * bq + 2 * bk, bq),
+                  ('long dk/dv', bq, bk, 2 * bq + 4 * bk, 2 * bk)]
     else:
         bq, bk = _std_blocks(n, m)
         bqb, bkb = _std_bwd_blocks(n, m)
-        passes = [('fwd', m // bk, bq, bk, (2 * m + 2 * bq) * d * itemsize)]
+        passes = [('fwd', bq, bk, 2 * m + 2 * bq, 0)]
         if bqb == n and bkb == m and _fused_bwd_enabled():
-            passes.append(('fused bwd', 1, n, m, 4 * n * d * itemsize))
+            passes.append(('fused bwd', n, m, 3 * n + 4 * m, 0))
         else:
-            passes.append(('dq', m // bkb, bqb, bkb,
-                           (2 * m + 2 * bqb) * d * itemsize))
-            passes.append(('dk/dv', n // bqb, bqb, bkb,
-                           (2 * n + 2 * bkb) * d * itemsize))
+            passes.append(('dq', bqb, bkb, 2 * m + 3 * bqb, 0))
+            passes.append(('dk/dv', bqb, bkb, 2 * n + 4 * bkb, 0))
     budget = _vmem_budget_bytes()
-    for name, steps, pbq, pbk, staged in passes:
-        est = steps * pbq * pbk * 4 + 2 * staged
+    for name, pbq, pbk, window_rows, scratch_rows in passes:
+        est = 2 * window_rows * row + scratch_rows * acc + pbq * pbk * 4
         if est > budget:
             return ('blocks (%d, %d) at seq (%d, %d) cannot fit: the %s '
-                    'pass needs ~%.1f MiB scoped VMEM (%d sequential '
-                    'score tile(s) of %dx%d f32 plus staged operands) '
-                    'but the budget is %d MiB '
-                    '(PADDLE_TPU_FLASH_VMEM_BUDGET_MB); shrink the '
-                    'PADDLE_TPU_FLASH_BLOCK_* knobs or lower '
+                    'pass needs ~%.1f MiB scoped VMEM (double-buffered '
+                    'operand windows plus a %dx%d f32 score tile) but the '
+                    'budget is %d MiB (PADDLE_TPU_FLASH_VMEM_BUDGET_MB); '
+                    'shrink the PADDLE_TPU_FLASH_BLOCK_* knobs or lower '
                     'PADDLE_TPU_FLASH_LONG_SEQ to take the long-kernel '
                     'path'
-                    % (pbq, pbk, n, m, name, est / 2 ** 20, steps, pbq,
-                       pbk, _vmem_budget_bytes() // 2 ** 20))
+                    % (pbq, pbk, n, m, name, est / 2 ** 20, pbq, pbk,
+                       budget // 2 ** 20))
     return None
 
 
@@ -789,32 +812,25 @@ def _flash_bhnd(q, k, v, causal, scale):
 
 
 def _dispatch_fwd(q, k, v, causal, scale):
-    """Returns (o, lse_or_None); lse None means the jnp path ran."""
-    if causal and q.shape[2] != k.shape[2]:
-        # the Pallas kernels' causal block bounds assume self-attention
-        # (q_pos = global q index); cross-length causal (KV-cache decode,
-        # chunked prefill) takes the bottom-right-aligned blockwise path,
-        # which keeps memory O(N*D) for a long cache. This is a semantics
-        # contract, not a capability fallback — strict mode (a bench-
-        # honesty guard for the n == m training shape) does not apply.
+    """Returns (o, lse_or_None); lse None means the blockwise path ran.
+
+    Cross-length causal is a semantics contract, not a capability gap, so
+    strict mode does not apply to it; every other ineligible shape raises
+    under strict mode. A chosen kernel is called bare: what it raises,
+    the caller sees."""
+    cross_causal = causal and q.shape[2] != k.shape[2]
+    reason = None if cross_causal else _supported(q, k, v)
+    if reason is not None and strict_mode():
+        raise RuntimeError(
+            'PADDLE_TPU_FLASH_STRICT=1 but the Pallas flash kernel '
+            'cannot run: ' + reason)
+    if cross_causal or reason is not None:
         from .blockwise_attention import blockwise_attention_bnhd
-        return blockwise_attention_bnhd(q, k, v, causal=True,
+        return blockwise_attention_bnhd(q, k, v, causal=causal,
                                         scale=scale), None
-    reason = _supported(q, k, v)
-    if reason is not None:
-        if strict_mode():
-            raise RuntimeError(
-                'PADDLE_TPU_FLASH_STRICT=1 but the Pallas flash kernel '
-                'cannot run: ' + reason)
-        return _ref_bhnd(q, k, v, causal, scale), None
     impl = _fwd_impl_long if _use_long_path(q.shape[2], k.shape[2]) \
         else _fwd_impl
-    if strict_mode():
-        return impl(q, k, v, causal, scale)
-    try:
-        return impl(q, k, v, causal, scale)
-    except Exception:
-        return _ref_bhnd(q, k, v, causal, scale), None
+    return impl(q, k, v, causal, scale)
 
 
 def _fwd_rule(q, k, v, causal, scale):
@@ -824,24 +840,15 @@ def _fwd_rule(q, k, v, causal, scale):
 
 def _bwd_rule(causal, scale, res, do):
     q, k, v, o, lse = res
-    if causal and q.shape[2] != k.shape[2]:
+    if lse is None:
+        # the forward routed to blockwise: differentiate that same path
         from .blockwise_attention import blockwise_attention_bnhd
         _, vjp = jax.vjp(lambda a, b, c: blockwise_attention_bnhd(
-            a, b, c, causal=True, scale=scale), q, k, v)
+            a, b, c, causal=causal, scale=scale), q, k, v)
         return vjp(do)
-    if lse is not None:
-        impl = _bwd_impl_long if _use_long_path(q.shape[2], k.shape[2]) \
-            else _bwd_impl
-        if strict_mode():
-            return impl(q, k, v, o, lse, do, causal, scale)
-        try:
-            return impl(q, k, v, o, lse, do, causal, scale)
-        except Exception:
-            pass
-    # jnp fallback: recomputed reference backward (numerically exact)
-    _, vjp = jax.vjp(lambda a, b, c: _ref_bhnd(a, b, c, causal, scale),
-                     q, k, v)
-    return vjp(do)
+    impl = _bwd_impl_long if _use_long_path(q.shape[2], k.shape[2]) \
+        else _bwd_impl
+    return impl(q, k, v, o, lse, do, causal, scale)
 
 
 _flash_bhnd.defvjp(_fwd_rule, _bwd_rule)
@@ -849,15 +856,43 @@ _flash_bhnd.defvjp(_fwd_rule, _bwd_rule)
 
 # -- public API --------------------------------------------------------------
 
+# GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+# automatically partitioned. Please wrap the call in a shard_map"), so a
+# step jitted over a mesh names the layout its [B, N, H, D] attention
+# operands have — batch over the data axes, heads over the tensor-parallel
+# axis — and inside that scope every device runs the kernels on its own
+# shard. A ContextVar for the reason fused_ce.logits_sharding is one:
+# concurrent traces must not see each other's mesh.
+_PARTITION = contextvars.ContextVar('flash_attention_partition',
+                                    default=None)
+
+
+@contextlib.contextmanager
+def partitioned(sharding):
+    """Inside this scope the kernels run under jax.shard_map over
+    `sharding`, the NamedSharding of the [B, N, H, D] operands."""
+    token = _PARTITION.set(sharding)
+    try:
+        yield
+    finally:
+        _PARTITION.reset(token)
+
+
 def flash_attention_bnhd(q, k, v, causal=False, scale=None):
     """Paddle layout [B, N, H, D] in/out."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    o = _flash_bhnd(qt, kt, vt, causal, scale)
-    return jnp.swapaxes(o, 1, 2)
+
+    def local(q, k, v):
+        o = _flash_bhnd(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+                        jnp.swapaxes(v, 1, 2), causal, scale)
+        return jnp.swapaxes(o, 1, 2)
+
+    part = _PARTITION.get()
+    if part is None:
+        return local(q, k, v)
+    return jax.shard_map(local, mesh=part.mesh, in_specs=(part.spec,) * 3,
+                         out_specs=part.spec, check_vma=False)(q, k, v)
 
 
 def flash_attention_bhnd(q, k, v, causal=False, scale=None):

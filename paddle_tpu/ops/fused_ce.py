@@ -6,7 +6,7 @@ straight path (head matmul -> cross_entropy) materializes in HBM, copies
 to f32 for the stable logsumexp, and materializes AGAIN as softmax probs
 in the backward. On the BERT-base bench config that is ~2 GB of f32
 logits + ~1 GB of probs per step — measured at ~13 ms/step of pure HBM
-traffic on v5e (docs/PERF_NOTES_r4.md, profile analysis).
+traffic on v5e (builder profile, 2026-08-01: docs/profile_summary_r5.txt).
 
 This op computes mean softmax-CE of `x @ w (+bias)` against integer
 labels WITHOUT ever materializing the full [rows, vocab] logits:

@@ -17,14 +17,6 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def _axis_size(axis_name):
-    """lax.axis_size where available; psum-of-1 (constant-folded to the
-    static axis extent) on jax lines that predate it."""
-    fn = getattr(lax, 'axis_size', None)
-    if fn is not None:
-        return fn(axis_name)
-    return lax.psum(1, axis_name)
-
 __all__ = ['ring_attention', 'ulysses_attention', 'ring_attention_sharded',
            'ulysses_attention_sharded', 'ring_flash_attention',
            'ring_flash_attention_sharded', 'zigzag_ring_attention',
@@ -87,7 +79,7 @@ def ring_attention(q, k, v, axis_name='sp', causal=False, scale=None,
     """
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    n_dev = _axis_size(axis_name)
+    n_dev = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     b, n_loc, h, d = q.shape
 
@@ -152,7 +144,7 @@ def zigzag_ring_attention(q, k, v, axis_name='sp', scale=None,
     assert causal, 'zigzag_ring_attention is causal-only; use ring_attention'
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    n_dev = _axis_size(axis_name)
+    n_dev = lax.axis_size(axis_name)
     r = lax.axis_index(axis_name)
     b, n_loc, h, d = q.shape
     assert n_loc % 2 == 0, 'zigzag needs an even local row count'
@@ -242,7 +234,7 @@ def ulysses_attention(q, k, v, axis_name='sp', causal=False, scale=None,
     """Ulysses (DeepSpeed) sequence parallelism: all_to_all swaps the
     sequence shard for a head shard, runs full-sequence attention on H/sp
     heads locally, and swaps back. Heads must divide the axis size."""
-    n_dev = _axis_size(axis_name)
+    n_dev = lax.axis_size(axis_name)
     b, n_loc, h, d = q.shape
     assert h % n_dev == 0, 'ulysses needs heads %% sp == 0'
 
@@ -300,11 +292,10 @@ def ulysses_attention(q, k, v, axis_name='sp', causal=False, scale=None,
 
 def _sharded(fn, mesh, axis_name, q, k, v, **kw):
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     spec = P(None, axis_name, None, None)
-    wrapped = shard_map(
+    wrapped = jax.shard_map(
         functools.partial(fn, axis_name=axis_name, **kw), mesh=mesh,
-        in_specs=(spec, spec, spec), out_specs=spec, check_rep=False)
+        in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
     return wrapped(q, k, v)
 
 
@@ -370,7 +361,7 @@ def ring_flash_attention(q, k, v, axis_name='sp', causal=False, scale=None,
         return ring_attention(q, k, v, axis_name=axis_name, causal=causal,
                               scale=scale)
 
-    n_dev = _axis_size(axis_name)
+    n_dev = lax.axis_size(axis_name)
     perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
 
     @jax.custom_vjp

@@ -35,6 +35,16 @@ Design rules inherited from the PS services (embedding_service.py):
 Lifecycle: /readyz on the worker's MetricsServer flips 503 the moment
 a 'drain' op lands (state -> draining) while /healthz stays 200 — the
 same drain-must-not-restart-the-pod split the in-proc replica has.
+
+One process per chip. A chip belongs to the process that first touched
+jax on it, and a second process that needs it fails or hangs. So on a
+host with an accelerator either the worker holds the chip and the
+gateway process stays off jax (it only needs sockets), or the gateway
+process holds it and its workers are pinned to the CPU backend
+(JAX_PLATFORMS=cpu in their env — what the tests and CPU rehearsals
+run). spawn_worker refuses the third combination — a parent that has
+initialised jax on the accelerator starting a worker that would reach
+for the same chip.
 """
 import argparse
 import json
@@ -498,9 +508,20 @@ def spawn_worker(preset=None, artifacts=None, cache=None, model=None,
     Engine bring-up (imports + first trace) dominates; `timeout` bounds
     the wait for the port file. Raises RuntimeError if the process
     exits first (its stderr goes to the parent's, so the failure is
-    visible in test output)."""
+    visible in test output), or before starting one that would need a
+    chip this process already holds (module docstring)."""
     import subprocess
     import tempfile
+    env = dict(os.environ)
+    env.update(extra_env or {})
+    from ...framework.device import process_holds_accelerator
+    if env.get('JAX_PLATFORMS') != 'cpu' and process_holds_accelerator():
+        raise RuntimeError(
+            'one process per chip: this process has initialised jax on '
+            'the accelerator, so a worker started from it cannot have '
+            'the chip too. Keep the gateway process off jax and let the '
+            'worker hold the chip, or pin the worker to the CPU backend '
+            "(extra_env={'JAX_PLATFORMS': 'cpu'}).")
     fd, port_file = tempfile.mkstemp(prefix='fabric-worker-',
                                      suffix='.json')
     os.close(fd)
@@ -515,8 +536,6 @@ def spawn_worker(preset=None, artifacts=None, cache=None, model=None,
                 '--model', model, '--version', version]
         if fingerprint:
             cmd += ['--fingerprint', fingerprint]
-    env = dict(os.environ)
-    env.update(extra_env or {})
     proc = subprocess.Popen(cmd, env=env)
     deadline = time.monotonic() + timeout
     while not os.path.exists(port_file):

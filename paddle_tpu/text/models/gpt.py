@@ -661,8 +661,7 @@ class GPTForCausalLM(nn.Layer):
             _bufs = _fm.extract_buffers(self)
 
             # prefill: ONE jitted pass over the prompt seeds the caches
-            # (eager prefill would dispatch every op separately — dozens
-            # of round-trips on a relayed accelerator)
+            # (eager prefill would dispatch every op separately)
             pre_cache = getattr(self, '_prefill_cache', None)
             if pre_cache is None:
                 pre_cache = self._prefill_cache = {}
@@ -681,9 +680,7 @@ class GPTForCausalLM(nn.Layer):
             # body is the static-shape cached step (params/buffers/caches
             # are pytree args; GPTStaticCache is a registered node). The
             # host dispatches once per generate() call, not once per
-            # token — on a relayed/tunneled accelerator the per-token
-            # dispatch toll dominates cached decode, the same lesson as
-            # TrainStep.multi_step for training.
+            # token — the same lever as TrainStep.multi_step for training.
             func_mod = _fm
             params, bufs = _params, _bufs
 

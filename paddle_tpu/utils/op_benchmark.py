@@ -12,7 +12,7 @@ jitted once, timed over `repeat` runs with block_until_ready — the XLA
 replacement for op_tester's per-op timing loop. The default suite covers
 the ops the bench model leans on (matmul/flash-attention/layernorm/CE),
 so a kernel regression is localizable without rerunning the full model
-bench (VERDICT r2 missing #4).
+bench.
 """
 import argparse
 import json

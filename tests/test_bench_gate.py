@@ -250,8 +250,8 @@ def test_cli_exit_codes(tmp_path):
 
 def test_repo_cache_rows_pin_cold_start_win():
     """The committed CPU cache demonstration (docs/bench_cache_cpu.jsonl,
-    measured cold-process via PADDLE_TPU_BENCH_CHILD=1 with
-    PADDLE_TPU_CACHE_DIR at a fresh dir, then again at the warmed dir):
+    measured in a cold process with the compile cache at a fresh dir,
+    then again at the warmed dir):
     the warm run compiles >=3x faster at full persistent-cache hit rate
     on both measured configs, the rows are invisible to the default
     (TPU-only) gate, and the file self-gates under --trust-degraded."""

@@ -1,0 +1,203 @@
+"""The main path's kernels and programs compile for a TPU v5e — without one.
+
+The TPU compiler is installed here and compiles for a chip that is DESCRIBED
+(jax.experimental.topologies), not attached, so what Mosaic or XLA:TPU would
+refuse on the chip — a misaligned slice, a kernel over its VMEM, a program
+over 16 GB of HBM — is refused here, at no chip time. Nothing runs: a pass
+says nothing about results or speed (chip_smoke.py does, on the chip).
+
+Code that asks the backend still sees the CPU under such a compile and would
+take its CPU branch, so THIS FILE steers it: `flash_attention.is_available`
+is patched to say yes and the engines get their existing `donate=True`. The
+persistent compile cache is off around every compile: an entry written for
+an unattached device cannot be read back.
+
+Tier-1 compiles the kernels at the benchmark's shapes, the train step at full
+WIDTH with the depth cut to two layers (what the compiler checks per layer
+does not change with depth) and the one serving program that compiles in
+seconds; the 12-layer train step, whose memory is read against the chip's
+16 GB, and the serving programs that sort the vocabulary are marked slow.
+"""
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+import paddle_tpu as paddle
+from paddle_tpu.framework import compile_cache
+from paddle_tpu.framework import functional as func_mod
+from paddle_tpu.framework import random as rng_mod
+from paddle_tpu.ops import flash_attention as fa
+from paddle_tpu.text.models import GPTConfig, GPTForCausalLM
+
+os.environ.setdefault('TPU_LOG_DIR', 'disabled')   # or the compiler logs
+
+HBM_BYTES = 16e9
+WIDTHS = dict(vocab_size=30528, hidden_size=768, num_heads=12, dropout=0.0)
+
+
+@pytest.fixture(scope='module')
+def chip():
+    """Sharding on one described v5e chip; skip where it can't be described."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:   # no TPU compiler in this installation
+        pytest.skip('cannot describe a v5e topology here: %r' % (e,))
+    assert topo.devices[0].device_kind == 'TPU v5 lite'
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_chip_dispatch(monkeypatch):
+    """The dispatch the chip takes: flash available, strict (an ineligible
+    shape raises instead of routing to blockwise), cache off."""
+    monkeypatch.setattr(fa, 'is_available', lambda: True)
+    monkeypatch.setenv('PADDLE_TPU_FLASH_STRICT', '1')
+    with compile_cache.suspended():
+        yield
+
+
+def _abstract(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), jnp.result_type(a),
+                                       sharding=sharding), tree)
+
+
+def _hbm_bytes(compiled):
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes
+            + m.generated_code_size_in_bytes)
+
+
+# ---- flash kernels at the benchmark's shapes --------------------------------
+
+# seq 512 is one 512x512 tile: forward 1 kernel, fused backward 1. Longer
+# sequences run dq and dk/dv as two kernels; >= 4096 takes the long path.
+@pytest.mark.parametrize('seq,grad,kernels', [
+    (512, False, 1), (512, True, 2), (2048, False, 1), (2048, True, 3),
+    (4096, False, 1), (4096, True, 3), (8192, False, 1), (8192, True, 3)])
+def test_flash_kernel_compiles_for_v5e(chip, on_chip_dispatch, seq, grad,
+                                       kernels):
+    x = jax.ShapeDtypeStruct((32, 12, seq, 64), jnp.bfloat16, sharding=chip)
+
+    def fwd(q, k, v):
+        return fa.flash_attention_bhnd(q, k, v, causal=True)
+
+    fn = fwd
+    if grad:
+        fn = jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+                      argnums=(0, 1, 2))
+    compiled = jax.jit(fn).lower(x, x, x).compile()
+    assert compiled.as_text().count('tpu_custom_call') == kernels
+    assert _hbm_bytes(compiled) < HBM_BYTES
+
+
+# ---- the whole train step ---------------------------------------------------
+
+def _compile_train_step(chip, layers, batch=32, seq=512):
+    paddle.seed(0)
+    model = GPTForCausalLM(GPTConfig(
+        num_layers=layers, max_position_embeddings=seq, fused_loss=True,
+        **WIDTHS))
+    model.bfloat16()
+    opt = paddle.optimizer.AdamW(learning_rate=1e-4,
+                                 parameters=model.parameters())
+    step = func_mod.TrainStep(model, lambda o, l: model.loss(o, l), opt)
+    ids = np.zeros((batch, seq), np.int32)
+    batch_arrays = step._step_args(ids, ids)
+    step._build(batch_arrays)
+    # the argument tree TrainStep.__call__ / trace_jaxpr assemble
+    args = (func_mod.extract_params(model), func_mod.extract_buffers(model),
+            step._opt_state(), batch_arrays, step._lr_array(),
+            rng_mod.default_generator()._key)
+    return jax.jit(step._pure_step, donate_argnums=(0, 2)).lower(
+        *_abstract(args, chip)).compile()
+
+
+def test_train_step_compiles_for_v5e(chip, on_chip_dispatch):
+    compiled = _compile_train_step(chip, layers=2)
+    # the Pallas flash forward and the fused backward, once per layer
+    assert compiled.as_text().count('tpu_custom_call') == 4
+    assert _hbm_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.slow
+def test_bert_base_train_step_fits_v5e(chip, on_chip_dispatch):
+    compiled = _compile_train_step(chip, layers=12)
+    assert compiled.as_text().count('tpu_custom_call') == 24
+    assert _hbm_bytes(compiled) < HBM_BYTES
+
+
+# ---- the serving engines' programs ------------------------------------------
+
+def _serving_model(layers):
+    paddle.seed(0)
+    model = GPTForCausalLM(GPTConfig(
+        num_layers=layers, max_position_embeddings=1024, **WIDTHS))
+    model.bfloat16()
+    model.eval()
+    return model
+
+
+def _engine_programs(layers):
+    """{name: (jitted program, the argument tuple its dispatch site builds)}
+    for both engines at chip_smoke.py's serving shape."""
+    from paddle_tpu.serving import (ContinuousBatchingEngine,
+                                    PagedContinuousBatchingEngine)
+    model = _serving_model(layers)
+    key = np.zeros((2,), np.uint32)
+    ids = np.zeros((1, 32), np.int32)
+    sampling = (key, np.float32(1.0), np.int32(0), np.asarray(False))
+    slot = ContinuousBatchingEngine(model, num_slots=8, max_len=256,
+                                    prefill_chunk=32, decode_block=8,
+                                    donate=True)
+    paged = PagedContinuousBatchingEngine(
+        model, num_seqs=8, max_len=256, page_size=16, num_pages=65,
+        prefill_chunk=32, decode_block=8, spec_k=4, donate=True)
+    state = (slot._last, slot._gen, slot._budgets, slot._active, slot._keys,
+             slot._temps, slot._topks, slot._sample)
+    tables = paged.scheduler.block_tables
+    return {
+        # serving/engine.py _prefill_step / _decode_step
+        'slot_prefill': (slot._prefill_jit, (
+            slot._params, slot._bufs, slot._caches, np.int32(0), ids,
+            np.int32(0), np.int32(32)) + sampling),
+        'slot_decode': (slot._decode_jit, (
+            slot._params, slot._bufs, slot._caches) + state),
+        # serving/paged_engine.py _prefill_step / _decode_step / _spec_step
+        'paged_prefill': (paged._prefill_jit, (
+            paged._params, paged._bufs, paged._pools, tables[0:1],
+            np.zeros((1,), np.int32), ids, np.int32(32)) + sampling),
+        'paged_decode': (paged._decode_jit, (
+            paged._params, paged._bufs, paged._pools, tables, paged._lens)
+            + state),
+        'paged_verify': (paged._verify_jit, (
+            paged._params, paged._bufs, paged._pools, tables, paged._lens,
+            np.zeros((8, 5), np.int32))),
+    }
+
+
+# every program that picks a token sorts the 30528-wide vocabulary row
+# (serving/engine.py _pick_token), and that sort alone takes ~25 s to compile
+# for the chip whatever the depth — so tier-1 compiles the one program
+# without it and the rest are marked slow
+@pytest.mark.parametrize('name', [
+    'paged_verify',
+    pytest.param('slot_prefill', marks=pytest.mark.slow),
+    pytest.param('slot_decode', marks=pytest.mark.slow),
+    pytest.param('paged_prefill', marks=pytest.mark.slow),
+    pytest.param('paged_decode', marks=pytest.mark.slow)])
+def test_engine_program_compiles_for_v5e(chip, on_chip_dispatch, name):
+    jitted, args = _engine_programs(layers=12)[name]
+    compiled = jitted.lower(*_abstract(args, chip)).compile()
+    # serving never reaches the flash kernel (chunks and decode rows are
+    # far under its 512-row floor): these are pure XLA programs
+    assert compiled.as_text().count('tpu_custom_call') == 0
+    assert _hbm_bytes(compiled) < HBM_BYTES

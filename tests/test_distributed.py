@@ -34,7 +34,7 @@ def test_topology_hcg():
 
 
 def test_psum_inside_shard_map():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     mesh = _mesh((8,), ('dp',))
     x = jnp.arange(8.0)
 

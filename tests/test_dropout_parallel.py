@@ -234,7 +234,7 @@ def test_ring_attention_dropout_unbiased():
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from paddle_tpu.ops import ring_attention as ra
 
     mesh = Mesh(np.array(jax.devices()[:2]), ('sp',))
@@ -251,12 +251,12 @@ def test_ring_attention_dropout_unbiased():
 
     dropped = jax.jit(shard_map(body, mesh=mesh,
                                 in_specs=(spec, spec, spec, P()),
-                                out_specs=spec, check_rep=False))
+                                out_specs=spec, check_vma=False))
 
     def ref_body(qq, kk, vv):
         return ra.ring_attention(qq, kk, vv, axis_name='sp', causal=True)
     ref = shard_map(ref_body, mesh=mesh, in_specs=(spec, spec, spec),
-                    out_specs=spec, check_rep=False)(q, k, v)
+                    out_specs=spec, check_vma=False)(q, k, v)
 
     n = 400
     acc = np.zeros(q.shape, np.float32)

@@ -457,11 +457,13 @@ def test_yolo_detection_ops_serve(tmp_path):
     np.testing.assert_array_equal(rois_n, rois_ref.numpy())
 
 
-def test_optim_cache_dir_persists_executables(tmp_path):
+def test_optim_cache_dir_persists_executables(tmp_path, monkeypatch):
     """Config.set_optim_cache_dir -> jax persistent compilation cache:
     running the predictor populates the directory with compiled
     executables (restart-warm serving)."""
     import jax
+    # a cache placed from outside would win over the Predictor's dir
+    monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
     d, w, b = _fit_a_line_dir(tmp_path, combined=False)
     cache = tmp_path / 'optim_cache'
     cfg = Config(str(d))

@@ -400,12 +400,13 @@ def test_cost_model_exact_flops_on_known_matmul():
 
 
 def test_cost_model_roofline_classification():
-    # intensity 1000 on a ridge of 197e12/819e9 ~ 240 -> compute-bound
-    r = costmodel.roofline(1000.0e9, 1.0e9, platform='tpu')
+    # intensity 1000 on v5e's ridge of 197e12/819e9 ~ 240 -> compute-bound
+    # (a TPU is named by its device_kind: bare 'tpu' is not one chip)
+    r = costmodel.roofline(1000.0e9, 1.0e9, platform='TPU v5 lite')
     assert r['roofline_bound'] == 'compute'
     assert r['ridge_intensity'] == pytest.approx(197e12 / 819e9)
     # intensity 1 -> far under any ridge -> bandwidth-bound
-    r = costmodel.roofline(1.0e9, 1.0e9, platform='tpu')
+    r = costmodel.roofline(1.0e9, 1.0e9, platform='TPU v5 lite')
     assert r['roofline_bound'] == 'bandwidth'
     assert r['ideal_step_s'] == pytest.approx(1.0e9 / 819e9)
     # overrides beat the table

@@ -7,7 +7,6 @@ schedule), fleet/meta_parallel/pipeline_parallel.py:109 (train_batch),
 parallel_layers/pp_layers.py:62 (SharedLayerDesc tied weights).
 """
 import numpy as np
-import pytest
 
 import paddle_tpu as paddle
 import paddle_tpu.nn as nn
@@ -51,7 +50,6 @@ def _fleet_step(model, strategy):
         model, lambda lg, lb: model.loss(lg, lb), opt, strategy=strategy)
 
 
-@pytest.mark.partial_auto
 def test_gpt_pp4_uneven_layers_matches_dp():
     """pp=4 over 6 layers (not divisible): ghost identity padding keeps
     loss parity with dp (reference uneven seg_method, pp_layers.py:76)."""
@@ -64,7 +62,6 @@ def test_gpt_pp4_uneven_layers_matches_dp():
     np.testing.assert_allclose(losses, ref_losses, rtol=2e-4, atol=2e-5)
 
 
-@pytest.mark.partial_auto
 def test_gpt_pp4_matches_dp():
     """pp=4 GPT fleet step: same losses as the plain dp run."""
     ids, lbl = _batch()
@@ -83,7 +80,6 @@ def test_gpt_pp4_matches_dp():
     assert pipeline_state() is None
 
 
-@pytest.mark.partial_auto
 def test_gpt_pp2_with_recompute_and_bf16():
     """pp composes with recompute (remat inside the stage scan) and amp."""
     ids, lbl = _batch()
@@ -97,7 +93,6 @@ def test_gpt_pp2_with_recompute_and_bf16():
     assert np.isfinite(l0) and np.isfinite(l1) and l1 < l0
 
 
-@pytest.mark.partial_auto
 def test_pipeline_layer_engine_trains():
     """Declarative PipelineLayer through PipelineEngine: heterogeneous
     stage fns via lax.switch, loss decreases, parity vs sequential."""

@@ -13,9 +13,6 @@ import paddle_tpu as paddle
 from paddle_tpu.distributed import fleet
 from paddle_tpu.text.models import GPTConfig, GPTForCausalLM
 
-# dp x pp meshes take the legacy partial-auto shard_map path
-pytestmark = pytest.mark.partial_auto
-
 
 def _model(seed=0, layers=4, tie=True):
     paddle.seed(seed)

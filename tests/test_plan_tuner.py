@@ -137,7 +137,8 @@ def test_resolve_plan_loads_matching_artifact(tmp_path, monkeypatch):
     assert isinstance(plan, tuner.TunedPlan)
     assert plan.key == art['key']
     micro = plan.micro_spec((2, 4, 64, 128))
-    assert micro[0] is None and micro[1] == ('sharding',)
+    # jax 0.9 PartitionSpec canonicalises a one-axis tuple to the bare name
+    assert micro[0] is None and micro[1] == 'sharding'
     # the planner's shape guards survive the artifact
     assert plan.micro_spec((2, 3, 64)) is None
     # the engines' call-site helper resolves the same artifact
@@ -218,7 +219,7 @@ def test_tuned_plan_probe_compiles_clean(cfg5_artifact):
 
 # -------- persistent cache x watchdog composition (satellite fix) -------
 
-def test_cache_hit_after_warmup_is_not_a_recompile(tmp_path):
+def test_cache_hit_after_warmup_is_not_a_recompile(tmp_path, monkeypatch):
     """The satellite-6 regression pin: jax fires the backend-compile
     duration event even when the persistent cache served the
     executable, so a cache-hit reload after declare_warmup() used to
@@ -229,8 +230,9 @@ def test_cache_hit_after_warmup_is_not_a_recompile(tmp_path):
 
     x = jnp.arange(8.0)
     jnp.multiply(x, 1.0).block_until_ready()   # aux compiles out of the way
-    if compile_cache.configure(str(tmp_path / 'cc')) is None:
-        pytest.skip('jaxlib rejects the compilation-cache knobs')
+    # a cache placed from outside would win over the test's own dir
+    monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
+    compile_cache.configure(str(tmp_path / 'cc'))
     reg = monitor.MetricRegistry()
     wd = monitor.CompileWatchdog(registry=reg, strict=True, name='cc')
     try:
@@ -248,13 +250,12 @@ def test_cache_hit_after_warmup_is_not_a_recompile(tmp_path):
         compile_cache.disable()
 
 
-def test_compile_cache_configure_idempotent(tmp_path):
+def test_compile_cache_configure_idempotent(tmp_path, monkeypatch):
     from paddle_tpu.framework import compile_cache
+    monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
     d = str(tmp_path / 'cc2')
     try:
         got = compile_cache.configure(d)
-        if got is None:
-            pytest.skip('jaxlib rejects the compilation-cache knobs')
         assert got == d and compile_cache.enabled()
         assert compile_cache.cache_dir() == d
         assert compile_cache.configure(d) == d     # repeat: no-op

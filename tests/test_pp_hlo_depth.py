@@ -11,14 +11,10 @@ the 1F1B program-transform assertions
 import re
 
 import numpy as np
-import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.distributed import fleet
 from paddle_tpu.text.models import GPTConfig, GPTForCausalLM
-
-# dp2 x pp4 takes the legacy partial-auto shard_map path
-pytestmark = pytest.mark.partial_auto
 
 HIDDEN, HEADS, VOCAB, SEQ = 768, 12, 30522, 256
 LAYERS, PP, MICRO = 8, 4, 8

@@ -4,9 +4,9 @@ Two tiers:
 - a synthetic trace fixture (always runs, hardware-free): exercises
   load_trace / device_ops / aggregate end-to-end on the exact
   trace-viewer JSON shape jax.profiler writes;
-- a captured on-TPU profile, when one exists locally (docs/tpu_profile_r5
-  is written by the warmer's auto-profile pass; the raw blobs are
-  gitignored per the r4 advisor, so CI machines skip this tier).
+- a captured on-TPU profile, when one exists locally (docs/tpu_profile_*
+  is what a bench.py run under PADDLE_TPU_BENCH_PROFILE writes; the raw
+  blobs are gitignored, so CI machines skip this tier).
 """
 import glob
 import gzip
@@ -122,7 +122,7 @@ def test_synthetic_trace_roundtrip(tmp_path):
 
 @pytest.mark.skipif(_CAPTURED_DIR is None,
                     reason='no locally captured profile (raw blobs are '
-                           'gitignored; the warmer writes them in-window)')
+                           'gitignored; a profiled bench run writes them)')
 def test_parses_captured_profile():
     trace, _ = pa.load_trace(_CAPTURED_DIR)
     ops, _ = pa.device_ops(trace)
@@ -131,7 +131,7 @@ def test_parses_captured_profile():
     import collections
     steps = collections.Counter(r['n'] for r in rows.values()).most_common(
         1)[0][0]
-    # the warmer profiles multiple steps: step inference must detect the
+    # a profiled run covers multiple steps: step inference must detect the
     # repetition, not collapse to 1 (which would inflate every per-step
     # total this tool reports)
     assert steps >= 2

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 import paddle_tpu  # noqa: F401  (forces the 8-device CPU mesh via conftest)
 from paddle_tpu.distributed import sp as sp_mod
@@ -89,7 +89,7 @@ def test_zigzag_flops_are_lower_triangle():
         import functools
         wrapped = shard_map(
             functools.partial(fn, axis_name='sp', **kw), mesh=mesh,
-            in_specs=(spec, spec, spec), out_specs=spec, check_rep=False)
+            in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
         return _weighted_dot_flops(jax.make_jaxpr(wrapped)(x, x, x).jaxpr)
 
     ring = count(ra.ring_attention, causal=True)
@@ -165,7 +165,7 @@ def test_ulysses_long_causal_uses_blockwise_skip():
         functools.partial(ra.ulysses_attention, axis_name='sp',
                           causal=True, scale=scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
     out = wrapped(q, q, q)
     ref = _ref_causal(q, q, q, scale)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -177,7 +177,7 @@ def test_ulysses_long_causal_uses_blockwise_skip():
         functools.partial(ra.ulysses_attention, axis_name='sp',
                           causal=False, scale=scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
     flops_full = _weighted_dot_flops(
         jax.make_jaxpr(wrapped_full)(q, q, q).jaxpr)
     assert flops_causal < 0.7 * flops_full, (flops_causal, flops_full)
@@ -201,7 +201,7 @@ def test_ulysses_long_causal_grads_match():
         functools.partial(ra.ulysses_attention, axis_name='sp',
                           causal=True, scale=scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
 
     def loss_u(q, k, v):
         return jnp.sum(wrapped(q, k, v) ** 2)

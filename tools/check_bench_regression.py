@@ -1,7 +1,7 @@
 """Perf-regression gate over bench capture logs.
 
-Compares a NEW capture log (JSONL rows as written by bench.py children,
-tools/tpu_warmer.py, or bench_extra.py) against the stored best and
+Compares a NEW capture log (JSONL rows as written by bench.py or
+bench_extra.py) against the stored best and
 FAILS (exit 1) when any same-config metric regresses more than the
 threshold (default 10%). Reference counterpart:
 tools/check_op_benchmark_result.py, which gates op microbenchmark PRs
@@ -15,7 +15,7 @@ point of those helpers), plus the auxiliary workload fields
 (num_slots/new_tokens/... for the serving and decode rungs).
 
 Only trustworthy rows participate: real-TPU, non-degraded, non-suspect,
-no error field — the same eligibility rule as bench._best_capture.
+no error field.
 
 Usage:
     python tools/check_bench_regression.py --new NEW.jsonl \
@@ -64,7 +64,7 @@ _DERIVED_UNITS = {'compile_cache_hit_rate': 'ratio'}
 
 
 def eligible(row, trust_degraded=False):
-    """bench._best_capture's trust rule: real-TPU, clean, measured.
+    """The trust rule: real-TPU, clean, measured.
     `trust_degraded` relaxes the platform/degraded half — the
     compile-cache rungs are measured on CPU (XLA compile + persistent
     cache behave identically there) and gate via an explicit

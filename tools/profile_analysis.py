@@ -4,16 +4,15 @@
 Usage: python tools/profile_analysis.py [docs/tpu_profile_r4] [--top N]
 
 Reads the newest `*.trace.json.gz` under the given profile dir (written
-by jax.profiler.start_trace via PADDLE_TPU_BENCH_PROFILE / the warmer's
-auto-profile pass) and prints, per XLA op aggregated over steps:
+by jax.profiler.start_trace via PADDLE_TPU_BENCH_PROFILE) and prints, per XLA op aggregated over steps:
 
   - time/step, roofline-ideal time (max of flops/peak, bytes/bw), and
     the achieved fraction;
   - totals: program flops vs the 6N model, program HBM bytes, and
     whether the step is compute- or bandwidth-bound;
   - the top byte movers — the list that names the next fusion target
-    (this is how the round-4 fused-CE and native-dtype-matmul levers
-    were found; see docs/PERF_NOTES_r4.md).
+    (this is how the fused-CE and native-dtype-matmul levers were
+    found; docs/profile_summary_r5.txt is such a report).
 
 Peak numbers default to v5e (197 TFLOP/s bf16, 819 GB/s HBM); override
 with --peak-tflops / --hbm-gbs for other TPU generations.

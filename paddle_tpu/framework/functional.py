@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from .core import Tensor
 from . import random as rng_mod
+from ..monitor import tracing as _tracing
 
 __all__ = ['extract_params', 'extract_buffers', 'functional_call',
            'make_loss_post', 'TrainStep']
@@ -175,6 +176,7 @@ class TrainStep:
         self.loss_fn = loss_fn
         self.optimizer = optimizer
         self._jitted = None
+        self._step_index = 0
         self._mesh = mesh
         # vocab-parallel fused-CE constraint (ops/fused_ce.logits_sharding),
         # entered around every trace/step by _sp_scope
@@ -283,6 +285,7 @@ class TrainStep:
                         if jnp.issubdtype(v.dtype, jnp.floating) else v)
                     for k, v in tree.items()}
 
+        opt_scope = 'optimizer.' + type(opt).__name__.lower()
         pp_state = self._pp_state
         use_1f1b = False
         if pp_state is not None and pp_state.get('schedule') == '1f1b':
@@ -370,6 +373,9 @@ class TrainStep:
             # mirror Optimizer.step()'s full semantics in pure form:
             # grad clip -> (coupled) weight decay / regularizer ->
             # per-param lr -> update rule -> decoupled decay (AdamW)
+            # (the scope is metadata on the update's device ops: a trace
+            # reads `optimizer.adamw` where it read `fusion`)
+            @jax.named_scope(opt_scope)
             def apply_updates(gdict):
                 if opt._grad_clip is not None:
                     names = list(gdict.keys())
@@ -653,7 +659,19 @@ class TrainStep:
         return hlo, pshard
 
     def __call__(self, inputs, labels):
-        """One step; returns the loss as a Tensor."""
+        """One step; returns the loss as a Tensor. The `train.step` span
+        (host tracer + device-trace annotation) covers what the host
+        does for a step — batch placement, trace-cache lookup, dispatch
+        — and ends when the dispatch returns, not when the device is
+        done: against the step's wall it reads the host's head-room."""
+        self._step_index += 1
+        with _tracing.default_tracer().start_span(
+                'train.step', annotate=True) as span:
+            if span:
+                span.set_tag('step', self._step_index)
+            return self._step(inputs, labels)
+
+    def _step(self, inputs, labels):
         in_arrays, lab_arrays = self._step_args(inputs, labels)
         if self._batch_sharding is not None:
             in_arrays = tuple(jax.device_put(a, self._batch_sharding)

@@ -16,7 +16,10 @@ with trace-exemplar links into the active tracer span) and a rolling
 window that serves percentiles and straggler detection: a step slower
 than ``straggler_factor`` x the rolling median bumps
 ``perf_stragglers_total`` and drops a ``perf.straggler`` span into the
-flight ring.
+flight ring, carrying whatever `detail` the caller handed ``end_step``
+(the serving engines: the step's phase seconds, CPU seconds, compiles
+and index), then asks for a throttled flight dump — the road a
+``perf.recompile`` record takes.
 
 The clock is injectable (tests drive a fake), and ``enabled=False``
 reduces every call to one attribute load + branch — the registry's
@@ -118,9 +121,11 @@ class StepTimeline:
         which belongs to no step."""
         self._cur = {}
 
-    def end_step(self, wall_seconds=None, exemplar=None):
+    def end_step(self, wall_seconds=None, exemplar=None, detail=None):
         """Finalize the step. With `wall_seconds`, the gap between the
-        recorded phases and the wall lands in 'other'. Returns the
+        recorded phases and the wall lands in 'other'. `detail` (a dict)
+        rides a flagged step's ``perf.straggler`` record as tags: what
+        the caller knows about WHERE the step went. Returns the
         per-phase dict (plus 'total'/'straggler') or None when nothing
         was recorded."""
         if not self.enabled:
@@ -156,10 +161,12 @@ class StepTimeline:
             self.stragglers += 1
             self._m_stragglers.inc()
             if tracer.enabled:
-                tracer.start_span('perf.straggler',
-                                  tags={'total_s': round(total, 6),
-                                        'median_s': round(median, 6),
-                                        'step': self.steps}).finish()
+                tags = {'total_s': round(total, 6),
+                        'median_s': round(median, 6), 'step': self.steps}
+                if detail:
+                    tags.update(detail)
+                tracer.start_span('perf.straggler', tags=tags).finish()
+                tracer.recorder.maybe_dump('straggler')
         out = dict(cur)
         out['total'] = total
         out['straggler'] = straggler

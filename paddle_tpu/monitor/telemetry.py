@@ -194,7 +194,8 @@ def record_tracing_schema(registry):
 # retrace canary). Same single-source rule: ServingMetrics and the
 # schema baseline both register through record_serving_request_schema.
 # Label budget: program is the engine's closed program set (prefill/
-# decode/verify).
+# decode/verify); cause is why an admit pass left its head queued
+# (slots/pages).
 SERVING_REQUEST_FAMILIES = (
     ('counter', 'serving_requests_total',
      'requests submitted to the engine', ()),
@@ -217,6 +218,11 @@ SERVING_REQUEST_FAMILIES = (
     ('gauge', 'serving_trace_count',
      'times each serving program has been traced '
      '(flat == zero retrace)', ('program',)),
+    ('counter', 'serving_prefill_calls_total',
+     'jitted prefill calls dispatched (one prompt chunk each)', ()),
+    ('counter', 'serving_admit_blocked_total',
+     'admit passes that left their head request queued, by cause',
+     ('cause',)),
 )
 
 

@@ -25,8 +25,17 @@ TIME WENT for one request. Three consumers ride on it:
 Cost discipline matches the registry: a disabled tracer's
 ``start_span`` is one attribute load + branch returning the shared
 ``NULL_SPAN`` — no allocation, no clock read, no contextvar touch.
-Span timestamps use ``time.time`` (epoch) by default so spans from
-different processes align on one timeline without clock negotiation.
+
+Two stamps per span, one clock each. ``start``/``end`` are epoch
+(``time.time``) so spans from different processes align on one timeline
+without clock negotiation; ``start_mono``/``end_mono`` are
+``time.monotonic``, the clock ``ServingMetrics.now``, ``StepTimeline``
+and the benchmark's windows read, so a reader windows spans against any
+of those with no offset estimate. ``start_span(..., annotate=True)``
+also enters a ``jax.profiler.TraceAnnotation`` of the span's name: with
+a profiler session running the span lands in the xplane's host plane on
+the device trace's clock, under the program's own name (the ONE
+dual-sink path; ``profiler.RecordEvent`` is a thin caller of it).
 """
 import collections
 import contextvars
@@ -90,6 +99,23 @@ def _new_id(bits):
     return '%0*x' % (bits // 4, random.getrandbits(bits))
 
 
+_TraceAnnotation = None      # lazily imported class; False: no jax here
+
+
+def _annotation(name):
+    """A jax.profiler.TraceAnnotation of `name` (a no-op object without
+    a profiler session), or None where jax cannot be imported — this
+    module stays importable in processes that must not load it."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name) if _TraceAnnotation else None
+
+
 class _NullSpan:
     """Shared do-nothing span: the disabled tracer's return value.
     Falsy, so call sites can guard optional work with ``if span:``."""
@@ -112,7 +138,7 @@ class _NullSpan:
     def set_tag(self, key, value):
         return self
 
-    def add_event(self, name, **attrs):
+    def add_event(self, name, mono=None, **attrs):
         return self
 
     def set_error(self, exc):
@@ -121,7 +147,7 @@ class _NullSpan:
     def ctx(self):
         return None
 
-    def finish(self):
+    def finish(self, mono=None):
         pass
 
     def to_dict(self):
@@ -143,23 +169,30 @@ class Span:
     injection) pick it up as parent. ``finish()`` is idempotent."""
 
     __slots__ = ('name', 'trace_id', 'span_id', 'parent_id', 'start',
-                 'end', 'tags', 'events', 'status', 'error', 'tid',
-                 '_tracer', '_token')
+                 'end', 'start_mono', 'end_mono', 'tags', 'events',
+                 'status', 'error', 'tid', '_tracer', '_token', '_ann')
 
-    def __init__(self, tracer, name, trace_id, parent_id, tags):
+    def __init__(self, tracer, name, trace_id, parent_id, tags,
+                 annotate=False, mono=None):
         self._tracer = tracer
         self.name = name
         self.trace_id = trace_id
         self.span_id = _new_id(64)
         self.parent_id = parent_id
-        self.start = tracer.clock()
+        self.start, self.start_mono = tracer.stamps(mono)
         self.end = None
+        self.end_mono = None
         self.tags = dict(tags) if tags else {}
-        self.events = []          # [(ts, name, attrs)]
+        self.events = []          # [(ts, mono, name, attrs)]
         self.status = 'ok'
         self.error = None
         self.tid = threading.get_ident()
         self._token = None
+        # the second sink: a TraceAnnotation lives on the opening
+        # thread until finish() — annotate only spans that nest
+        self._ann = _annotation(name) if annotate else None
+        if self._ann is not None:
+            self._ann.__enter__()
 
     def __bool__(self):
         return True
@@ -168,8 +201,11 @@ class Span:
         self.tags[key] = value
         return self
 
-    def add_event(self, name, **attrs):
-        self.events.append((self._tracer.clock(), name, attrs))
+    def add_event(self, name, mono=None, **attrs):
+        """`mono` hands in a monotonic stamp the caller already took
+        (the engine's _admit_t / _first_token_t) instead of a second
+        read of the same instant."""
+        self.events.append(self._tracer.stamps(mono) + (name, attrs))
         return self
 
     def set_error(self, exc):
@@ -181,10 +217,16 @@ class Span:
         """The wire form: what a client injects under TRACE_KEY."""
         return {'trace_id': self.trace_id, 'span_id': self.span_id}
 
-    def finish(self):
+    def finish(self, mono=None):
+        """Idempotent. `mono` closes the span at a monotonic stamp the
+        caller already read (the decode burst feeds the timeline and
+        its span from one pair of reads)."""
         if self.end is not None:
             return
-        self.end = self._tracer.clock()
+        self.end, self.end_mono = self._tracer.stamps(mono)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         self._tracer._on_finish(self)
 
     def __enter__(self):
@@ -205,10 +247,14 @@ class Span:
                 'span_id': self.span_id, 'parent_id': self.parent_id,
                 'start': self.start,
                 'end': self.end if self.end is not None else self.start,
+                'start_mono': self.start_mono,
+                'end_mono': self.end_mono if self.end_mono is not None
+                else self.start_mono,
                 'tid': self.tid, 'status': self.status,
                 'error': self.error, 'tags': dict(self.tags),
-                'events': [{'ts': ts, 'name': n, 'args': dict(a)}
-                           for ts, n, a in self.events]}
+                'events': [{'ts': ts, 'mono': m, 'name': n,
+                            'args': dict(a)}
+                           for ts, m, n, a in self.events]}
 
     def __repr__(self):
         return ('Span(%s, trace=%s, span=%s, parent=%s, status=%s)'
@@ -515,14 +561,17 @@ class Tracer:
 
     ``enabled`` is a plain attribute so hot paths pay one load + branch
     when tracing is off (the registry's ~90 ns discipline); disabled
-    ``start_span`` returns the shared NULL_SPAN. The injectable clock
-    stamps span start/end/events — keep it epoch-based (time.time) in
-    production so cross-process spans share a timeline."""
+    ``start_span`` returns the shared NULL_SPAN. Spans carry two
+    stamps: `clock` (epoch, time.time) so cross-process spans share a
+    timeline, and time.monotonic so they share the engine's and the
+    timeline's clock. An injected `clock` (tests) is one fake timeline:
+    one read of it stamps both."""
 
     def __init__(self, enabled=True, clock=None, recorder=None,
                  registry=None, retention=None):
         self.enabled = bool(enabled)
         self.clock = clock or time.time
+        self._mono = None if clock else time.monotonic
         self.registry = registry if registry is not None \
             else default_registry()
         fams = register_metrics(self.registry)
@@ -533,6 +582,14 @@ class Tracer:
         # tail-based retention is opt-in: None costs one load + branch
         # per finished span (attach with tracer.retention = TraceRetention())
         self.retention = retention
+
+    def stamps(self, mono=None):
+        """(epoch, monotonic) of now; `mono` is a monotonic stamp the
+        caller already read for this instant."""
+        t = self.clock()
+        if mono is None:
+            mono = t if self._mono is None else self._mono()
+        return t, mono
 
     def enable(self):
         self.enabled = True
@@ -547,7 +604,7 @@ class Tracer:
         return _current.get()
 
     def start_span(self, name, parent=None, ctx=None, tags=None,
-                   root=False):
+                   root=False, annotate=False, mono=None):
         """Begin a span. Parent resolution: explicit `ctx` (a wire dict
         from a remote client) > explicit `parent` span > the contextvar
         current span > a fresh root. `root=True` skips the contextvar
@@ -558,7 +615,10 @@ class Tracer:
         trace_id resolves to exactly that request's tree). The returned
         span is NOT current until entered (``with``) — lifecycle spans
         held across calls (a serving request) just ``finish()``
-        manually."""
+        manually. `annotate=True` also enters a TraceAnnotation of the
+        same name until finish() (same thread, properly nested: use it
+        on `with` spans only); `mono` opens the span at a monotonic
+        stamp the caller already read."""
         if not self.enabled:
             return NULL_SPAN
         if root:
@@ -576,7 +636,7 @@ class Tracer:
             else:
                 trace_id, parent_id = _new_id(128), None
         self._m_started.inc()
-        return Span(self, name, trace_id, parent_id, tags)
+        return Span(self, name, trace_id, parent_id, tags, annotate, mono)
 
     def server_span(self, msg, prefix):
         """Server-side continuation: pop TRACE_KEY from an incoming

@@ -805,6 +805,11 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale):
 
 # -- custom-vjp wiring -------------------------------------------------------
 
+# `flash.fwd` / `flash.bwd` are jax.named_scope metadata on the kernels'
+# device ops (the caller's scope, `gpt.attn.core`, stays around them): a
+# device trace then tells the forward kernel, its recomputation and the
+# backward kernels apart by name, not by guess.
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _flash_bhnd(q, k, v, causal, scale):
     o, _ = _dispatch_fwd(q, k, v, causal, scale)
@@ -830,7 +835,8 @@ def _dispatch_fwd(q, k, v, causal, scale):
                                         scale=scale), None
     impl = _fwd_impl_long if _use_long_path(q.shape[2], k.shape[2]) \
         else _fwd_impl
-    return impl(q, k, v, causal, scale)
+    with jax.named_scope('flash.fwd'):
+        return impl(q, k, v, causal, scale)
 
 
 def _fwd_rule(q, k, v, causal, scale):
@@ -848,7 +854,8 @@ def _bwd_rule(causal, scale, res, do):
         return vjp(do)
     impl = _bwd_impl_long if _use_long_path(q.shape[2], k.shape[2]) \
         else _bwd_impl
-    return impl(q, k, v, o, lse, do, causal, scale)
+    with jax.named_scope('flash.bwd'):
+        return impl(q, k, v, o, lse, do, causal, scale)
 
 
 _flash_bhnd.defvjp(_fwd_rule, _bwd_rule)

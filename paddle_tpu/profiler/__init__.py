@@ -20,28 +20,27 @@ __all__ = ['RecordEvent', 'profiler', 'start_profiler', 'stop_profiler',
 class RecordEvent:
     """RAII trace annotation (platform/profiler.h:127 parity).
 
-    Dual-sink: the name lands in the device trace as a
-    jax.profiler.TraceAnnotation AND in the host tracer as a span, so
-    the same region shows up in Perfetto next to XLA ops and in the
-    flight recorder / /debug/traces view."""
+    Dual-sink through the tracer's one code path
+    (``start_span(annotate=True)``): the name lands in the device trace
+    as a jax.profiler.TraceAnnotation AND in the host tracer as a span,
+    so the same region shows up in Perfetto next to XLA ops and in the
+    flight recorder / /debug/traces view. With the tracer disabled it
+    records nothing in either sink."""
 
     def __init__(self, name, event_type=None):
         self.name = name
-        self._ctx = None
         self._span = None
 
     def __enter__(self):
-        self._span = _tracing.default_tracer().start_span(self.name)
+        self._span = _tracing.default_tracer().start_span(
+            self.name, annotate=True)
         self._span.__enter__()
-        self._ctx = jax.profiler.TraceAnnotation(self.name)
-        self._ctx.__enter__()
         return self
 
     def __exit__(self, *exc):
-        self._ctx.__exit__(*exc)
-        if self._span is not None:
-            self._span.__exit__(*(exc or (None, None, None)))
-            self._span = None
+        span, self._span = self._span, None
+        if span is not None:
+            span.__exit__(*(exc or (None, None, None)))
         return False
 
     def begin(self):

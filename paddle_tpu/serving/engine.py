@@ -30,6 +30,7 @@ only in its compiled programs and page bookkeeping.
 """
 import queue as _queue
 import threading
+import time
 
 import numpy as np
 
@@ -62,6 +63,7 @@ def _kv_row_bytes(model):
     return 2 * len(model.gpt.h) * config.num_heads * head_dim * itemsize
 
 
+@jax.named_scope('serving.pick_token')    # names the device ops, no more
 def _pick_token(lg, key, temp, topk, sample):
     """Next token for ONE row of logits — generate()'s pick, per slot.
 
@@ -89,8 +91,15 @@ class _EngineBase:
     serializes scheduler state and device dispatches while `Request.wait`
     and stream consumption stay lock-free. Subclasses own the compiled
     programs: they set `self.allocator` / `self.scheduler` and implement
-    `_prefill_step` / `_decode_step` (and may hook `_bind` /
+    `_prefill_call` / `_decode_step` (and may hook `_bind` /
     `_on_step_metrics`).
+
+    With the tracer on, a step explains itself: `serving.step` with
+    `serving.step.admit`, `serving.step.prefill` (one
+    `serving.prefill_call` per jitted call) and `serving.decode_burst`
+    as children, each also a TraceAnnotation, so the same names sit in
+    the flight ring on the engine's clock and in a device trace's host
+    plane. With it off, step() opens no span and reads no extra clock.
     """
 
     # traced-body counter keys, one per compiled program; the zero-
@@ -153,12 +162,14 @@ class _EngineBase:
         self.timeline = StepTimeline(registry=self.metrics.registry,
                                      tracer=self._tracer)
         self._decode_args = None
+        self._step_index = 0
 
     # ---- front door ---------------------------------------------------
 
     def add_request(self, prompt, max_new_tokens=32, temperature=1.0,
                     top_k=0, do_sample=False, seed=0, stream=False,
-                    tenant=None, priority=0, model=None, emit_event=True):
+                    tenant=None, priority=0, model=None, emit_event=True,
+                    arrival_t=None, on_token=None):
         """Queue a generation request; returns the Request handle.
 
         `tenant` is the attribution dimension: it rides the request into
@@ -170,12 +181,23 @@ class _EngineBase:
         `emit_event=False` suppresses this engine's wide event — the
         gateway sets it so a failed-over request still produces exactly
         ONE canonical record (the gateway's, which knows the failover
-        history)."""
+        history). `arrival_t` states when the request was DUE, on the
+        engine's clock (`engine.metrics.now()`, time.monotonic): an
+        open-loop caller that hands requests over late passes the due
+        time, and TTFT, queue wait and the `queued` event run from it;
+        the default is the clock on entry, before the engine's lock.
+        `on_token(token)` is called for every token delivered to this
+        request, in order, on the thread driving step() and under the
+        engine's lock — keep it short; tokens regenerated after a
+        preemption are not delivered twice."""
         req = Request(prompt, max_new_tokens=max_new_tokens,
                       temperature=temperature, top_k=top_k,
                       do_sample=do_sample, seed=seed, tenant=tenant,
                       priority=priority, model=model)
         req._emit_event = bool(emit_event)
+        if arrival_t is not None:
+            req._arrival_t = float(arrival_t)
+        req.on_token = on_token
         if stream:
             req._stream_q = _queue.Queue()
         return self.enqueue(req)
@@ -185,7 +207,12 @@ class _EngineBase:
         the ModelHost path: a multi-model host constructs the Request at
         submission (stamping its arrival time), parks it while weights
         load, then enqueues it here without re-timestamping. All
-        validation, metrics and tracing of add_request happen here."""
+        validation, metrics and tracing of add_request happen here.
+        A request without an arrival stamp gets the clock ON ENTRY:
+        step() holds the lock for a whole step, and the wait for it is
+        part of what the caller waited."""
+        if req._arrival_t is None:
+            req._arrival_t = self.metrics.now()
         req._emit_event = getattr(req, '_emit_event', True)
         req._tenant_label = self.metrics.tenant_label(req.tenant)
         req._model_label = self.metrics.model_label(
@@ -208,9 +235,6 @@ class _EngineBase:
                     'engine is shut down — it no longer admits requests')
             self._validate(req)
             self.scheduler.submit(req)
-            t = self.metrics.now()
-            if req._arrival_t is None:
-                req._arrival_t = t
             self.metrics.on_arrival(req.id, req._arrival_t)
             tr = self._tracer
             if tr.enabled:
@@ -226,8 +250,8 @@ class _EngineBase:
                 # tail retention decides at THIS span's finish, and the
                 # wide event's trace_id joins to exactly this tree
                 req._span = tr.start_span('serving.request', tags=tags,
-                                          root=True)
-                req._span.add_event('queued',
+                                          root=True, mono=req._arrival_t)
+                req._span.add_event('queued', mono=req._arrival_t,
                                     queue_depth=len(self.scheduler.queue))
         return req
 
@@ -246,20 +270,75 @@ class _EngineBase:
         """One scheduler iteration: admit → prefill chunks → decode
         burst → retire. Returns the number of requests still pending."""
         with self._lock, no_grad_guard():
-            self._admit()
-            self._prefill_step()
-            self._decode_step()
-            self.metrics.on_step(self.allocator.in_use, self.num_slots)
-            self.metrics.on_queue_depth(len(self.scheduler.queue))
-            self._on_step_metrics()
-            for prog, child in self._m_trace.items():
-                child.set(self.trace_counts[prog])
-            if not self.perf.armed and all(
-                    self.trace_counts[p] > 0
-                    for p in self._warm_programs()):
-                self.perf.declare_warmup(
-                    '%s steady state' % type(self).__name__)
-            return self.scheduler.pending
+            self._step_index += 1
+            tr, sched = self._tracer, self.scheduler
+            with tr.start_span('serving.step', annotate=True) as sp:
+                if sp:
+                    # wall >> CPU reads "waiting on the device", wall ==
+                    # CPU reads "the host was busy"
+                    cpu0 = time.process_time()
+                    compiles0 = self.perf.counts['compile']
+                    sp.tags.update(step=self._step_index,
+                                   residents=len(sched.resident),
+                                   queue_depth=len(sched.queue),
+                                   slots_in_use=self.allocator.in_use)
+                    self._tag_step(sp)
+                with tr.start_span('serving.step.admit',
+                                   annotate=True) as ph_admit:
+                    admitted = self._admit()
+                    if ph_admit:
+                        ph_admit.tags.update(admitted=admitted,
+                                             left=len(sched.queue),
+                                             head_left=sched.head_left)
+                with tr.start_span('serving.step.prefill',
+                                   annotate=True) as ph_prefill:
+                    calls, tokens = self._prefill_step()
+                    if ph_prefill:
+                        ph_prefill.tags.update(calls=calls, tokens=tokens)
+                if sched.head_left != 'none':
+                    self.metrics.on_admit_blocked(sched.head_left)
+                self.metrics.on_prefill_calls(calls)
+                burst = self._decode_step()
+                self.metrics.on_step(self.allocator.in_use, self.num_slots)
+                self.metrics.on_queue_depth(len(sched.queue))
+                self._on_step_metrics()
+                for prog, child in self._m_trace.items():
+                    child.set(self.trace_counts[prog])
+                if not self.perf.armed and all(
+                        self.trace_counts[p] > 0
+                        for p in self._warm_programs()):
+                    self.perf.declare_warmup(
+                        '%s steady state' % type(self).__name__)
+                detail = None
+                if sp:
+                    sp.set_tag('cpu_s', time.process_time() - cpu0)
+                    sp.finish()
+                    detail = self._step_detail(
+                        sp, ph_admit, ph_prefill, burst,
+                        self.perf.counts['compile'] - compiles0)
+                if burst is not None:
+                    # the timeline's step is the burst (its totals feed
+                    # the straggler rule and the burst percentiles); a
+                    # flagged one carries the whole step's phases
+                    self.timeline.end_step(detail=detail)
+            return sched.pending
+
+    def _tag_step(self, span):
+        """Subclass hook: more of the state at a step's entry."""
+
+    def _step_detail(self, sp, ph_admit, ph_prefill, burst, compiles):
+        """Where a step went, for a `perf.straggler` record: phase
+        seconds from the spans' own stamps (self = the step minus its
+        phases), CPU seconds, compiles counted during it, its index."""
+        admit = ph_admit.end_mono - ph_admit.start_mono
+        prefill = ph_prefill.end_mono - ph_prefill.start_mono
+        dispatch, block = burst or (0.0, 0.0)
+        wall = sp.end_mono - sp.start_mono
+        return {'engine_step': self._step_index, 'step_s': wall,
+                'admit_s': admit, 'prefill_s': prefill,
+                'burst_dispatch_s': dispatch, 'burst_block_s': block,
+                'self_s': wall - admit - prefill - dispatch - block,
+                'cpu_s': sp.tags['cpu_s'], 'compiles': compiles}
 
     def run(self):
         """Drive until every submitted request has finished."""
@@ -357,7 +436,8 @@ class _EngineBase:
     # ---- scheduler glue (lock held) -----------------------------------
 
     def _admit(self):
-        for slot, req in self.scheduler.admit():
+        admitted = self.scheduler.admit()
+        for slot, req in admitted:
             req._admit_t = self.metrics.now()
             self.metrics.on_admitted(req.id)
             if req._preempts:
@@ -368,7 +448,9 @@ class _EngineBase:
                 if req._span is not None:
                     req._span.add_event('resumed', preempts=req._preempts)
             if req._span is not None:
-                req._span.add_event('admitted', slot=slot)
+                req._span.add_event('admitted', mono=req._admit_t,
+                                    slot=slot,
+                                    blocked=dict(req._admit_waits))
                 req._phase = self._tracer.start_span(
                     'serving.prefill', parent=req._span,
                     tags={'slot': slot})
@@ -384,6 +466,7 @@ class _EngineBase:
             # the occupant's own offset and its write-back length
             # unreaches the previous occupant's rows
             self._bind(slot, req)
+        return len(admitted)
 
     def _bind(self, slot, req):
         """Subclass hook: extra per-admission state (lock held)."""
@@ -403,6 +486,54 @@ class _EngineBase:
                 'serving.decode', parent=req._span,
                 tags={'slot': req.slot})
 
+    def _prefill_step(self):
+        """One chunk per prefilling resident, each its own jitted call
+        (`_prefill_call`, the subclass's); returns (calls, prompt tokens
+        forwarded). A call's span ends after its host sync, so the host
+        time BETWEEN two calls is `serving.step.prefill`'s self time."""
+        tr = self._tracer
+        calls = tokens = 0
+        for req, start, ids, valid, final in self.scheduler.prefill_plan():
+            slot = req.slot
+            with tr.start_span('serving.prefill_call',
+                               annotate=True) as sp:
+                # mid chunks receive (and discard) the request key so
+                # only the final chunk's split advances the sampling
+                # stream
+                tok, key2 = self._prefill_call(req, start, ids, valid)
+                if final:
+                    tok = int(tok)           # the call's host sync
+                if sp:
+                    sp.tags.update(slot=slot, tokens=valid, final=final)
+            calls += 1
+            tokens += valid
+            self.metrics.on_prefill_tokens(valid)
+            self.scheduler.mark_prefilled(req, start + valid)
+            self._trace_prefill(req, start, valid, final)
+            if not final:
+                continue
+            self._last[slot, 0] = tok
+            self._gen[slot] = 1
+            self._keys[slot] = np.asarray(key2)
+            self._active[slot] = True
+            self._emit(req, [tok])
+            if len(req.tokens) >= req.max_new_tokens:
+                self._retire(req)
+        return calls, tokens
+
+    def _burst_done(self, span, t0, t1, t2):
+        """A burst's one set of clock reads — dispatch returned at t1,
+        results on the host at t2 — feeds the timeline's phases and the
+        `serving.decode_burst` span alike; returns (dispatch, block)
+        seconds, what `_decode_step` hands back to step()."""
+        dispatch, block = t1 - t0, t2 - t1
+        self.timeline.record('host_dispatch', dispatch)
+        self.timeline.record('device_block', block)
+        if span:
+            span.tags.update(dispatch_s=dispatch, block_s=block)
+            span.finish(mono=t2)
+        return dispatch, block
+
     def _emit(self, req, tokens):
         if req._replay:
             # post-preemption regeneration: the first _replay tokens
@@ -419,14 +550,20 @@ class _EngineBase:
         if req._stream_q is not None:
             for t in tokens:
                 req._stream_q.put(t)
+        if req.on_token is not None:
+            for t in tokens:
+                req.on_token(t)
+        now = self.metrics.now()     # one read stamps every sink below
         if req._first_token_t is None:
-            req._first_token_t = self.metrics.now()
+            req._first_token_t = now
             if req._arrival_t is not None:
                 self.metrics.on_tenant_ttft(
-                    req._tenant_label, req._first_token_t - req._arrival_t)
+                    req._tenant_label, now - req._arrival_t)
+            if req._span is not None:
+                req._span.add_event('first_token', mono=now)
         self.metrics.on_tenant_tokens(req._tenant_label, len(tokens))
         self.metrics.on_tokens(
-            req.id, len(tokens),
+            req.id, len(tokens), t=now,
             trace_id=None if req._span is None else req._span.trace_id)
 
     def _retire(self, req, outcome='ok'):
@@ -585,55 +722,42 @@ class ContinuousBatchingEngine(_EngineBase):
 
     # ---- per-step dispatches (lock held) ------------------------------
 
-    def _prefill_step(self):
-        for req, start, ids, valid, final in self.scheduler.prefill_plan():
-            slot = req.slot
-            # mid chunks receive (and discard) the request key so only
-            # the final chunk's split advances the sampling stream
-            self._caches, tok, key2 = self._prefill_jit(
-                self._params, self._bufs, self._caches,
-                np.int32(slot),
-                np.asarray(ids, np.int32)[None, :],
-                np.int32(start), np.int32(valid), req._key,
-                np.float32(req.temperature), np.int32(req.top_k),
-                np.asarray(req.do_sample))
-            self.metrics.on_prefill_tokens(valid)
-            self.scheduler.mark_prefilled(req, start + valid)
-            self._trace_prefill(req, start, valid, final)
-            if not final:
-                continue
-            tok = int(tok)
-            self._last[slot, 0] = tok
-            self._gen[slot] = 1
-            self._keys[slot] = np.asarray(key2)
-            self._active[slot] = True
-            self._emit(req, [tok])
-            if len(req.tokens) >= req.max_new_tokens:
-                self._retire(req)
+    def _prefill_call(self, req, start, ids, valid):
+        self._caches, tok, key2 = self._prefill_jit(
+            self._params, self._bufs, self._caches,
+            np.int32(req.slot),
+            np.asarray(ids, np.int32)[None, :],
+            np.int32(start), np.int32(valid), req._key,
+            np.float32(req.temperature), np.int32(req.top_k),
+            np.asarray(req.do_sample))
+        return tok, key2
 
     def _decode_step(self):
         slots = self.scheduler.decode_slots()
         if not slots:
             return
-        # span covers dispatch AND the device_get sync — the burst's
-        # actual wall time, not just the async enqueue. The timeline
-        # splits the same window: host_dispatch (enqueue returns) vs
-        # device_block (results ready). Dispatch args are stashed for
-        # perf_estimate's cost-model lowering (same avals, no retrace).
+        # the span covers dispatch AND the device_get sync — the burst's
+        # actual wall time, not just the async enqueue; `_burst_done`
+        # splits the same window into host_dispatch (enqueue returns)
+        # and device_block (results ready). Dispatch args are stashed
+        # for perf_estimate's cost-model lowering (same avals, no
+        # retrace).
         args = (self._params, self._bufs, self._caches, self._last,
                 self._gen, self._budgets, self._active, self._keys,
                 self._temps, self._topks, self._sample)
         self._decode_args = args
-        with self._tracer.start_span('serving.decode_burst',
-                                     tags={'rows': len(slots),
-                                           'block': self.decode_block}):
-            with self.timeline.phase('host_dispatch'):
-                (self._caches, last, gen, keys, toks,
-                 actives) = self._decode_jit(*args)
-            with self.timeline.phase('device_block'):
-                last, gen, keys, toks, actives = jax.device_get(
-                    (last, gen, keys, toks, actives))
-        self.timeline.end_step()
+        clock = self.metrics.now
+        t0 = clock()
+        with self._tracer.start_span(
+                'serving.decode_burst', annotate=True, mono=t0,
+                tags={'rows': len(slots),
+                      'block': self.decode_block}) as sp:
+            (self._caches, last, gen, keys, toks,
+             actives) = self._decode_jit(*args)
+            t1 = clock()
+            last, gen, keys, toks, actives = jax.device_get(
+                (last, gen, keys, toks, actives))
+            burst = self._burst_done(sp, t0, t1, clock())
         # device_get can hand back read-only views; these three are
         # mutated in place at prefill/retire
         self._last = np.array(last)
@@ -646,3 +770,4 @@ class ContinuousBatchingEngine(_EngineBase):
             self._emit(req, new)
             if len(req.tokens) >= req.max_new_tokens:
                 self._retire(req)
+        return burst

@@ -71,6 +71,8 @@ class ServingMetrics:
         self._m_queue = req['serving_queue_depth']
         self._m_occupancy = req['serving_occupancy']
         self._m_prefill = req['serving_prefill_tokens_total']
+        self._m_prefill_calls = req['serving_prefill_calls_total']
+        self._m_admit_blocked = req['serving_admit_blocked_total']
         # paged-engine families; registered unconditionally (zeros for
         # the slot engine) so the scrape schema does not depend on which
         # engine a process happens to run
@@ -170,6 +172,16 @@ class ServingMetrics:
         missing increments)."""
         self._prefill_tokens += count
         self._m_prefill.inc(count)
+
+    def on_prefill_calls(self, count):
+        """`count` jitted prefill calls were dispatched this step."""
+        if count:
+            self._m_prefill_calls.inc(count)
+
+    def on_admit_blocked(self, cause):
+        """An admit pass left its head queued for `cause` ('slots' or
+        'pages' — the scheduler's closed set)."""
+        self._m_admit_blocked.labels(cause).inc()
 
     def on_pages_in_use(self, pages):
         self._pages_in_use = pages

@@ -187,6 +187,9 @@ class PagedContinuousBatchingEngine(_EngineBase):
             req._span.add_event('prefix_cache_hit',
                                 tokens=req._prefix_hit)
 
+    def _tag_step(self, span):
+        span.set_tag('pages_in_use', self.pages.in_use)
+
     def _on_step_metrics(self):
         self.metrics.on_pages_in_use(self.pages.in_use)
         if self.prefix is not None:
@@ -307,31 +310,18 @@ class PagedContinuousBatchingEngine(_EngineBase):
 
     # ---- per-step dispatches (lock held) ------------------------------
 
-    def _prefill_step(self):
-        for req, start, ids, valid, final in self.scheduler.prefill_plan():
-            slot = req.slot
-            self._pools, tok, key2 = self._prefill_jit(
-                self._params, self._bufs, self._pools,
-                self.scheduler.block_tables[slot:slot + 1],
-                np.asarray([start], np.int32),
-                np.asarray(ids, np.int32)[None, :],
-                np.int32(valid), req._key,
-                np.float32(req.temperature), np.int32(req.top_k),
-                np.asarray(req.do_sample))
-            self.metrics.on_prefill_tokens(valid)
-            self._lens[slot] = start + valid
-            self.scheduler.mark_prefilled(req, start + valid)
-            self._trace_prefill(req, start, valid, final)
-            if not final:
-                continue
-            tok = int(tok)
-            self._last[slot, 0] = tok
-            self._gen[slot] = 1
-            self._keys[slot] = np.asarray(key2)
-            self._active[slot] = True
-            self._emit(req, [tok])
-            if len(req.tokens) >= req.max_new_tokens:
-                self._retire(req)
+    def _prefill_call(self, req, start, ids, valid):
+        slot = req.slot
+        self._pools, tok, key2 = self._prefill_jit(
+            self._params, self._bufs, self._pools,
+            self.scheduler.block_tables[slot:slot + 1],
+            np.asarray([start], np.int32),
+            np.asarray(ids, np.int32)[None, :],
+            np.int32(valid), req._key,
+            np.float32(req.temperature), np.int32(req.top_k),
+            np.asarray(req.do_sample))
+        self._lens[slot] = start + valid
+        return tok, key2
 
     def _decode_step(self):
         slots = self.scheduler.decode_slots()
@@ -339,8 +329,8 @@ class PagedContinuousBatchingEngine(_EngineBase):
             return
         if self.spec_k:
             return self._spec_step(slots)
-        # span covers dispatch AND the device_get sync — the burst's
-        # actual wall time, not just the async enqueue. The timeline
+        # the span covers dispatch AND the device_get sync — the burst's
+        # actual wall time, not just the async enqueue; `_burst_done`
         # splits the same window (host_dispatch vs device_block) and the
         # dispatch args are stashed for perf_estimate's cost-model
         # lowering (identical avals, so no retrace).
@@ -349,16 +339,18 @@ class PagedContinuousBatchingEngine(_EngineBase):
                 self._gen, self._budgets, self._active, self._keys,
                 self._temps, self._topks, self._sample)
         self._decode_args = args
-        with self._tracer.start_span('serving.decode_burst',
-                                     tags={'rows': len(slots),
-                                           'block': self.decode_block}):
-            with self.timeline.phase('host_dispatch'):
-                (self._pools, lens, last, gen, keys, toks,
-                 actives) = self._decode_jit(*args)
-            with self.timeline.phase('device_block'):
-                lens, last, gen, keys, toks, actives = jax.device_get(
-                    (lens, last, gen, keys, toks, actives))
-        self.timeline.end_step()
+        clock = self.metrics.now
+        t0 = clock()
+        with self._tracer.start_span(
+                'serving.decode_burst', annotate=True, mono=t0,
+                tags={'rows': len(slots),
+                      'block': self.decode_block}) as sp:
+            (self._pools, lens, last, gen, keys, toks,
+             actives) = self._decode_jit(*args)
+            t1 = clock()
+            lens, last, gen, keys, toks, actives = jax.device_get(
+                (lens, last, gen, keys, toks, actives))
+            burst = self._burst_done(sp, t0, t1, clock())
         self._lens = np.array(lens)
         self._last = np.array(last)
         self._gen = np.array(gen)
@@ -370,6 +362,7 @@ class PagedContinuousBatchingEngine(_EngineBase):
             self._emit(req, new)
             if len(req.tokens) >= req.max_new_tokens:
                 self._retire(req)
+        return burst
 
     def _spec_step(self, slots):
         """Draft K tokens per decoding row, verify all rows in ONE
@@ -390,14 +383,15 @@ class PagedContinuousBatchingEngine(_EngineBase):
         args = (self._params, self._bufs, self._pools,
                 self.scheduler.block_tables, self._lens, toks)
         self._verify_args = args
-        with self._tracer.start_span('serving.decode_burst',
-                                     tags={'rows': len(slots),
-                                           'spec_k': K}):
-            with self.timeline.phase('host_dispatch'):
-                self._pools, picks = self._verify_jit(*args)
-            with self.timeline.phase('device_block'):
-                picks = np.asarray(jax.device_get(picks))
-        self.timeline.end_step()
+        clock = self.metrics.now
+        t0 = clock()
+        with self._tracer.start_span(
+                'serving.decode_burst', annotate=True, mono=t0,
+                tags={'rows': len(slots), 'spec_k': K}) as sp:
+            self._pools, picks = self._verify_jit(*args)
+            t1 = clock()
+            picks = np.asarray(jax.device_get(picks))
+            burst = self._burst_done(sp, t0, t1, clock())
         for slot in slots:
             req = self._requests[slot]
             d, g = drafts[slot], picks[slot]
@@ -421,3 +415,4 @@ class PagedContinuousBatchingEngine(_EngineBase):
             self._emit(req, emit)
             if len(req.tokens) >= req.max_new_tokens:
                 self._retire(req)
+        return burst

@@ -82,12 +82,19 @@ class Request:
         #                           while regenerating after a preemption
         self._kv_acc = 0.0        # page·seconds from closed-out holding
         #                           windows (accumulated at preemption)
+        # admit() passes this request sat through unadmitted, by cause
+        # ('slots' / 'pages' as the blocked head, 'behind_head' queued
+        # behind it); written to its span at admission
+        self._admit_waits = {}
         self._span = None         # 'serving.request' lifecycle span
         self._phase = None        # current prefill/decode child span
         self._finished = threading.Event()
         # engine.stream() consumers read tokens from here; None until the
         # first stream() call so non-streamed requests pay nothing
         self._stream_q = None
+        # called with every token delivered to this request
+        # (engine.add_request(on_token=...)); None: nobody listens
+        self.on_token = None
 
     @property
     def done(self):
@@ -115,6 +122,9 @@ class Scheduler:
         self.queue = deque()
         self.resident = {}        # slot -> Request (PREFILL or DECODE)
         self._submit_seq = itertools.count()
+        # why the last admit() pass left its head queued: 'slots',
+        # 'pages', or 'none' when it emptied the queue
+        self.head_left = 'none'
 
     def submit(self, req):
         """Validate capacity and enqueue. Raises on impossible requests —
@@ -152,6 +162,15 @@ class Scheduler:
                 best = i
         return best
 
+    def _note_left(self, head, cause):
+        """The admit pass is over: count it against every request it
+        left queued — the head for `cause`, the rest for queueing behind
+        a blocked head (FIFO: nobody skips ahead)."""
+        self.head_left = cause if self.queue else 'none'
+        for r in self.queue:
+            key = cause if r is head else 'behind_head'
+            r._admit_waits[key] = r._admit_waits.get(key, 0) + 1
+
     def admit(self):
         """Bind queued requests to free slots; returns [(slot, req)]."""
         admitted = []
@@ -169,6 +188,9 @@ class Scheduler:
             req._kv_hold_t = self.allocator.held_since(slot)
             self.resident[slot] = req
             admitted.append((slot, req))
+        # the loop ends on an empty queue or on no free slot
+        self._note_left(self.queue[self._pick_index()] if self.queue
+                        else None, 'slots')
         return admitted
 
     def prefill_plan(self):
@@ -297,6 +319,7 @@ class PagedScheduler(Scheduler):
 
     def admit(self):
         admitted = []
+        head, cause = None, 'none'
         while self.queue:
             i = self._pick_index()
             req = self.queue[i]
@@ -305,6 +328,7 @@ class PagedScheduler(Scheduler):
                 # enter by evicting a strictly-lower-priority resident
                 # (which also returns its pages); otherwise stop
                 if not (self.preempt_enabled and self._preempt_for(req)):
+                    head, cause = req, 'slots'
                     break
             plan = self._reserve(req)
             if plan is None and self.preempt_enabled:
@@ -313,6 +337,7 @@ class PagedScheduler(Scheduler):
                 while plan is None and self._preempt_for(req):
                     plan = self._reserve(req)
             if plan is None:
+                head, cause = req, 'pages'
                 break                          # head blocked => stop: FIFO
             del self.queue[i]
             pages, hit_len = plan
@@ -331,6 +356,7 @@ class PagedScheduler(Scheduler):
             req._published = hit_len // self.page_size
             self.resident[slot] = req
             admitted.append((slot, req))
+        self._note_left(head, cause)
         return admitted
 
     def _reserve(self, req):

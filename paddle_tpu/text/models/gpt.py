@@ -17,6 +17,11 @@ from ...tensor import manipulation as M
 
 __all__ = ['GPTConfig', 'GPTModel', 'GPTForCausalLM']
 
+# jax.named_scope is metadata on the compiled ops and nothing else: a
+# device trace then reads `gpt.attn.core` where it read `fusion`. Names
+# from a closed set (docs/observability.md), no layer index in a name.
+_scope = jax.named_scope
+
 
 class GPTConfig:
     def __init__(self, vocab_size=50304, hidden_size=768, num_layers=12,
@@ -272,27 +277,28 @@ class GPTAttention(nn.Layer):
 
     def forward(self, x, cache=None):
         b, n = x.shape[0], x.shape[1]
-        qkv = self.qkv_proj(x)
-        if self._qkv_split_last:
-            # experimental A/B (bench rung): slice the packed minor axis
-            # at 128-aligned offsets instead of reshaping to 5-D and
-            # slicing the middle axis. The round-4 profile shows
-            # ~5 ms/step of [b,n,3,h,d] layout-copy traffic on the
-            # middle-axis path; whether last-axis slicing removes it is
-            # measured in-window, not assumed. NOT the default: under
-            # tensor parallelism the packed 2304 axis is mp-sharded and
-            # q/k/v offsets straddle shard boundaries (the [3, heads, d]
-            # head-axis slicing keeps each shard self-contained).
-            hs = self.hidden_size
-            hd = [b, n, self.num_heads, self.head_dim]
-            q = M.reshape(qkv[:, :, :hs], hd)
-            k = M.reshape(qkv[:, :, hs:2 * hs], hd)
-            v = M.reshape(qkv[:, :, 2 * hs:], hd)
-        else:
-            qkv = M.reshape(qkv, [b, n, 3, self.num_heads, self.head_dim])
-            q = qkv[:, :, 0]
-            k = qkv[:, :, 1]
-            v = qkv[:, :, 2]
+        with _scope('gpt.attn.qkv'):
+            qkv = self.qkv_proj(x)
+            if self._qkv_split_last:
+                # experimental A/B (bench rung): slice the packed minor axis
+                # at 128-aligned offsets instead of reshaping to 5-D and
+                # slicing the middle axis. The round-4 profile shows
+                # ~5 ms/step of [b,n,3,h,d] layout-copy traffic on the
+                # middle-axis path; whether last-axis slicing removes it is
+                # measured in-window, not assumed. NOT the default: under
+                # tensor parallelism the packed 2304 axis is mp-sharded and
+                # q/k/v offsets straddle shard boundaries (the [3, heads, d]
+                # head-axis slicing keeps each shard self-contained).
+                hs = self.hidden_size
+                hd = [b, n, self.num_heads, self.head_dim]
+                q = M.reshape(qkv[:, :, :hs], hd)
+                k = M.reshape(qkv[:, :, hs:2 * hs], hd)
+                v = M.reshape(qkv[:, :, 2 * hs:], hd)
+            else:
+                qkv = M.reshape(qkv, [b, n, 3, self.num_heads, self.head_dim])
+                q = qkv[:, :, 0]
+                k = qkv[:, :, 1]
+                v = qkv[:, :, 2]
         if isinstance(cache, GPTPagedCache):
             import jax
             from ...framework.core import is_grad_enabled
@@ -318,17 +324,18 @@ class GPTAttention(nn.Layer):
             # from frozen rows inside the pool (it lands on the scratch
             # page or the row's own dead rows — both unreachable, see
             # GPTPagedCache invariants)
-            pos = jnp.clip(t[:, None] + jnp.arange(n)[None, :], 0, L - 1)
-            rows = (jnp.take_along_axis(bt, pos // page, axis=1) * page
-                    + pos % page)                                # [B, n]
-            flat_shape = (num_pages * page,) + tuple(cache.k.shape[2:])
-            kf = cache.k._data.reshape(flat_shape)
-            vf = cache.v._data.reshape(flat_shape)
-            idx = rows.reshape(-1)
-            kf = kf.at[idx].set(k._data.astype(kf.dtype).reshape(
-                (b * n,) + flat_shape[1:]))
-            vf = vf.at[idx].set(v._data.astype(vf.dtype).reshape(
-                (b * n,) + flat_shape[1:]))
+            with _scope('gpt.attn.paged_write'):
+                pos = jnp.clip(t[:, None] + jnp.arange(n)[None, :], 0, L - 1)
+                rows = (jnp.take_along_axis(bt, pos // page, axis=1) * page
+                        + pos % page)                                # [B, n]
+                flat_shape = (num_pages * page,) + tuple(cache.k.shape[2:])
+                kf = cache.k._data.reshape(flat_shape)
+                vf = cache.v._data.reshape(flat_shape)
+                idx = rows.reshape(-1)
+                kf = kf.at[idx].set(k._data.astype(kf.dtype).reshape(
+                    (b * n,) + flat_shape[1:]))
+                vf = vf.at[idx].set(v._data.astype(vf.dtype).reshape(
+                    (b * n,) + flat_shape[1:]))
             new_cache = GPTPagedCache(
                 Tensor(kf.reshape(cache.k._data.shape)),
                 Tensor(vf.reshape(cache.v._data.shape)), bt, t)
@@ -337,21 +344,23 @@ class GPTAttention(nn.Layer):
             # same masked attention as the slot path. The gather
             # materializes [B, L, H, Dh] activations; persistent memory
             # stays page-granular, which is where the density win lives.
-            view = (bt[:, :, None] * page
-                    + jnp.arange(page)[None, None, :]).reshape(b, L)
-            kg = jnp.take(kf, view, axis=0)                # [B, L, H, Dh]
-            vg = jnp.take(vf, view, axis=0)
+            with _scope('gpt.attn.paged_gather'):
+                view = (bt[:, :, None] * page
+                        + jnp.arange(page)[None, None, :]).reshape(b, L)
+                kg = jnp.take(kf, view, axis=0)                # [B, L, H, Dh]
+                vg = jnp.take(vf, view, axis=0)
             # per-row validity mask: query row i of sequence s sits at
             # absolute position t[s]+i and sees logical positions <= it
-            qpos = t[:, None] + jnp.arange(n)[None, :]           # [B, n]
-            allow = qpos[:, :, None] >= jnp.arange(L)[None, None, :]
-            mask = Tensor(jnp.where(allow, 0.0, -1e9)[:, None].astype(
-                jnp.float32))                                # [B,1,n,L]
-            out = F.scaled_dot_product_attention(
-                q, Tensor(kg), Tensor(vg), attn_mask=mask,
-                is_causal=False, dropout_p=0.0)
-            out = M.reshape(out, [b, n, self.hidden_size])
-            return self.out_proj(out), new_cache
+            with _scope('gpt.attn.mask'):
+                qpos = t[:, None] + jnp.arange(n)[None, :]           # [B, n]
+                allow = qpos[:, :, None] >= jnp.arange(L)[None, None, :]
+                mask = Tensor(jnp.where(allow, 0.0, -1e9)[:, None].astype(
+                    jnp.float32))                                # [B,1,n,L]
+            with _scope('gpt.attn.core'):
+                out = F.scaled_dot_product_attention(
+                    q, Tensor(kg), Tensor(vg), attn_mask=mask,
+                    is_causal=False, dropout_p=0.0)
+            return self._out(out, b, n), new_cache
         if isinstance(cache, GPTSlotCache):
             import jax
             from ...framework.core import is_grad_enabled
@@ -386,16 +395,17 @@ class GPTAttention(nn.Layer):
             new_cache = GPTSlotCache(Tensor(k_buf), Tensor(v_buf), t)
             # per-slot validity mask: query row i of slot s sits at
             # absolute position t[s]+i and sees buffer slots j <= t[s]+i
-            qpos = t[:, None] + jnp.arange(n)[None, :]           # [S, n]
-            kpos = jnp.arange(max_len)                           # [m]
-            allow = qpos[:, :, None] >= kpos[None, None, :]      # [S, n, m]
-            mask = Tensor(jnp.where(allow, 0.0, -1e9)[:, None].astype(
-                jnp.float32))                                    # [S,1,n,m]
-            out = F.scaled_dot_product_attention(
-                q, Tensor(k_buf), Tensor(v_buf), attn_mask=mask,
-                is_causal=False, dropout_p=0.0)
-            out = M.reshape(out, [b, n, self.hidden_size])
-            return self.out_proj(out), new_cache
+            with _scope('gpt.attn.mask'):
+                qpos = t[:, None] + jnp.arange(n)[None, :]       # [S, n]
+                kpos = jnp.arange(max_len)                       # [m]
+                allow = qpos[:, :, None] >= kpos[None, None, :]  # [S, n, m]
+                mask = Tensor(jnp.where(allow, 0.0, -1e9)[:, None].astype(
+                    jnp.float32))                                # [S,1,n,m]
+            with _scope('gpt.attn.core'):
+                out = F.scaled_dot_product_attention(
+                    q, Tensor(k_buf), Tensor(v_buf), attn_mask=mask,
+                    is_causal=False, dropout_p=0.0)
+            return self._out(out, b, n), new_cache
         if isinstance(cache, GPTStaticCache):
             import jax
             from ...framework.core import is_grad_enabled
@@ -426,33 +436,38 @@ class GPTAttention(nn.Layer):
                 # over the chunk itself (flash/blockwise-eligible) — the
                 # masked full-buffer attention below would pay quadratic
                 # cost against max_len-n empty slots
-                out = F.scaled_dot_product_attention(
-                    q, k, v, is_causal=True, dropout_p=0.0)
-                out = M.reshape(out, [b, n, self.hidden_size])
-                return self.out_proj(out), new_cache
+                with _scope('gpt.attn.core'):
+                    out = F.scaled_dot_product_attention(
+                        q, k, v, is_causal=True, dropout_p=0.0)
+                return self._out(out, b, n), new_cache
             # validity mask over the fixed buffer: query row i (absolute
             # position t+i) sees buffer slots j <= t+i
-            qpos = t + jnp.arange(n)
-            kpos = jnp.arange(max_len)
-            allow = qpos[:, None] >= kpos[None, :]
-            mask = Tensor(jnp.where(allow, 0.0, -1e9)[None, None].astype(
-                jnp.float32))
-            out = F.scaled_dot_product_attention(
-                q, Tensor(k_buf), Tensor(v_buf), attn_mask=mask,
-                is_causal=False, dropout_p=0.0)
-            out = M.reshape(out, [b, n, self.hidden_size])
-            return self.out_proj(out), new_cache
+            with _scope('gpt.attn.mask'):
+                qpos = t + jnp.arange(n)
+                kpos = jnp.arange(max_len)
+                allow = qpos[:, None] >= kpos[None, :]
+                mask = Tensor(jnp.where(allow, 0.0, -1e9)[
+                    None, None].astype(jnp.float32))
+            with _scope('gpt.attn.core'):
+                out = F.scaled_dot_product_attention(
+                    q, Tensor(k_buf), Tensor(v_buf), attn_mask=mask,
+                    is_causal=False, dropout_p=0.0)
+            return self._out(out, b, n), new_cache
         if cache is not None:
             k = M.concat([cache[0], k], axis=1)
             v = M.concat([cache[1], v], axis=1)
-        out = F.scaled_dot_product_attention(
-            q, k, v, is_causal=True,
-            dropout_p=self.dropout if self.training else 0.0)
-        out = M.reshape(out, [b, n, self.hidden_size])
-        out = self.out_proj(out)
+        with _scope('gpt.attn.core'):
+            out = F.scaled_dot_product_attention(
+                q, k, v, is_causal=True,
+                dropout_p=self.dropout if self.training else 0.0)
+        out = self._out(out, b, n)
         if cache is not None:
             return out, (k, v)
         return out
+
+    @_scope('gpt.attn.out')
+    def _out(self, out, b, n):
+        return self.out_proj(M.reshape(out, [b, n, self.hidden_size]))
 
 
 class GPTMLP(nn.Layer):
@@ -465,6 +480,7 @@ class GPTMLP(nn.Layer):
         self.fc_in.bias.placement = ('mp',)
         self.fc_out.weight.placement = ('mp', None)
 
+    @_scope('gpt.mlp')
     def forward(self, x):
         return self.dropout(self.fc_out(F.gelu(self.fc_in(x),
                                                approximate=True)))
@@ -488,13 +504,18 @@ class GPTBlock(nn.Layer):
 
     def forward(self, x, cache=None):
         if cache is not None:
-            a, new_cache = self.attn(self.ln_1(x), cache=cache)
+            a, new_cache = self.attn(self._ln(self.ln_1, x), cache=cache)
             x = x + a
-            x = x + self.mlp(self.ln_2(x))
+            x = x + self.mlp(self._ln(self.ln_2, x))
             return x, new_cache
-        x = x + self.attn(self.ln_1(x))
-        x = x + self.mlp(self.ln_2(x))
+        x = x + self.attn(self._ln(self.ln_1, x))
+        x = x + self.mlp(self._ln(self.ln_2, x))
         return x
+
+    @staticmethod
+    @_scope('gpt.ln')
+    def _ln(norm, x):
+        return norm(x)
 
 
 class GPTModel(nn.Layer):
@@ -533,13 +554,14 @@ class GPTModel(nn.Layer):
             else:
                 position_ids = Tensor(
                     jnp.arange(n, dtype=jnp.int64)[None, :])
-        x = self.drop(self.wte(input_ids) + self.wpe(position_ids))
+        with _scope('gpt.embed'):
+            x = self.drop(self.wte(input_ids) + self.wpe(position_ids))
         if caches is not None:
             new_caches = []
             for block, c in zip(self.h, caches):
                 x, nc = block(x, cache=c)
                 new_caches.append(nc)
-            return self.ln_f(x), new_caches
+            return GPTBlock._ln(self.ln_f, x), new_caches
         from ...distributed import pipeline as pp_mod
         pp_state = pp_mod.pipeline_state()
         moe = getattr(self.config, 'num_experts', 0) > 0
@@ -572,7 +594,7 @@ class GPTModel(nn.Layer):
                     term = aux * block.mlp.aux_loss_weight
                     self._moe_aux = term if self._moe_aux is None \
                         else self._moe_aux + term
-        return self.ln_f(x)
+        return GPTBlock._ln(self.ln_f, x)
 
 
 class GPTForCausalLM(nn.Layer):
@@ -599,11 +621,12 @@ class GPTForCausalLM(nn.Layer):
                 # hidden here is what makes the fusion possible. Eval
                 # and decode forwards keep producing logits.
                 return hidden
-        if self.lm_head is None:
-            logits = F.linear(hidden,
-                              M.transpose(self.gpt.wte.weight, [1, 0]))
-        else:
-            logits = self.lm_head(hidden)
+        with _scope('gpt.lm_head'):
+            if self.lm_head is None:
+                logits = F.linear(hidden,
+                                  M.transpose(self.gpt.wte.weight, [1, 0]))
+            else:
+                logits = self.lm_head(hidden)
         if caches is not None:
             return logits, new_caches
         return logits
@@ -765,23 +788,24 @@ class GPTForCausalLM(nn.Layer):
         return pre, gpt.h, post
 
     def loss(self, logits, labels):
-        if getattr(self.config, 'fused_loss', False) and self.training and \
-                logits.shape[-1] == self.config.hidden_size:
-            # fused TRAINING contract: `logits` is the final HIDDEN state
-            # (forward's training gate); head matmul + CE fuse in one
-            # chunked op. Both gates mirror forward's, so eval-path real
-            # logits never misroute here even when vocab == hidden.
-            if self.lm_head is None:
-                ce = F.linear_cross_entropy(
-                    logits, self.gpt.wte.weight, labels,
-                    transpose_weight=True)
+        with _scope('gpt.loss'):
+            if getattr(self.config, 'fused_loss', False) and self.training and \
+                    logits.shape[-1] == self.config.hidden_size:
+                # fused TRAINING contract: `logits` is the final HIDDEN state
+                # (forward's training gate); head matmul + CE fuse in one
+                # chunked op. Both gates mirror forward's, so eval-path real
+                # logits never misroute here even when vocab == hidden.
+                if self.lm_head is None:
+                    ce = F.linear_cross_entropy(
+                        logits, self.gpt.wte.weight, labels,
+                        transpose_weight=True)
+                else:
+                    ce = F.linear_cross_entropy(
+                        logits, self.lm_head.weight, labels)
             else:
-                ce = F.linear_cross_entropy(
-                    logits, self.lm_head.weight, labels)
-        else:
-            b, n, v = logits.shape
-            ce = F.cross_entropy(M.reshape(logits, [b * n, v]),
-                                 M.reshape(labels, [b * n]))
+                b, n, v = logits.shape
+                ce = F.cross_entropy(M.reshape(logits, [b * n, v]),
+                                     M.reshape(labels, [b * n]))
         aux = getattr(self.gpt, '_moe_aux', None)
         self.gpt._moe_aux = None  # consume once — never stale across calls
         if aux is not None:

@@ -124,8 +124,14 @@ def _compile_train_step(chip, layers, batch=32, seq=512):
 def test_train_step_compiles_for_v5e(chip, on_chip_dispatch):
     compiled = _compile_train_step(chip, layers=2)
     # the Pallas flash forward and the fused backward, once per layer
-    assert compiled.as_text().count('tpu_custom_call') == 4
+    text = compiled.as_text()
+    assert text.count('tpu_custom_call') == 4
     assert _hbm_bytes(compiled) < HBM_BYTES
+    # the chip's compiler keeps the scope names on the ops it emits
+    for scope in ('gpt.embed', 'gpt.ln', 'gpt.attn.qkv', 'gpt.attn.core',
+                  'flash.fwd', 'flash.bwd', 'gpt.attn.out', 'gpt.mlp',
+                  'gpt.loss', 'optimizer.adamw'):
+        assert scope in text, scope
 
 
 @pytest.mark.slow
@@ -199,5 +205,12 @@ def test_engine_program_compiles_for_v5e(chip, on_chip_dispatch, name):
     compiled = jitted.lower(*_abstract(args, chip)).compile()
     # serving never reaches the flash kernel (chunks and decode rows are
     # far under its 512-row floor): these are pure XLA programs
-    assert compiled.as_text().count('tpu_custom_call') == 0
+    text = compiled.as_text()
+    assert text.count('tpu_custom_call') == 0
     assert _hbm_bytes(compiled) < HBM_BYTES
+    scopes = ['gpt.attn.paged_write', 'gpt.attn.paged_gather'] \
+        if name.startswith('paged') else []
+    if name != 'paged_verify':
+        scopes.append('serving.pick_token')
+    for scope in scopes + ['gpt.attn.mask', 'gpt.attn.core', 'gpt.lm_head']:
+        assert scope in text, scope

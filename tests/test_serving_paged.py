@@ -359,3 +359,308 @@ def test_paged_capacity_validation(model):
     req = eng.add_request([1, 2, 3], max_new_tokens=2)
     eng.run()
     assert len(req.tokens) == 2
+
+
+# ---- the step explains itself: spans, counters, front-door stamps ------
+
+
+from paddle_tpu.monitor import MetricRegistry                 # noqa: E402
+from paddle_tpu.monitor.registry import set_default_registry  # noqa: E402
+from paddle_tpu.monitor.tracing import (FlightRecorder,       # noqa: E402
+                                        Tracer, set_default_tracer)
+from paddle_tpu.serving.scheduler import Scheduler            # noqa: E402
+
+
+class _Ticks:
+    """A fake clock: every read is one second later than the last."""
+
+    def __init__(self):
+        self.t = 1000.0
+
+    def __call__(self):
+        self.t += 1.0
+        return self.t
+
+
+@pytest.fixture(params=[None])
+def traced(request, tmp_path):
+    """Fresh registry + tracer (on the fake clock a test asks for, or
+    the real ones), installed as the process defaults before the engine
+    under test is built."""
+    clock = request.param and request.param()
+    reg = MetricRegistry()
+    rec = FlightRecorder(capacity=1024, dump_dir=str(tmp_path / 'flight'),
+                         cooldown=3600.0, registry=reg, clock=clock)
+    tr = Tracer(registry=reg, recorder=rec, clock=clock)
+    prev_reg = set_default_registry(reg)
+    prev_tr = set_default_tracer(tr)
+    yield tr, clock
+    set_default_tracer(prev_tr)
+    set_default_registry(prev_reg)
+
+
+def _paged(model, **kw):
+    return PagedContinuousBatchingEngine(model, num_seqs=2, max_len=32,
+                                         page_size=8, prefill_chunk=8,
+                                         decode_block=2, **kw)
+
+
+def _dur(s):
+    return s['end_mono'] - s['start_mono']
+
+
+@pytest.mark.parametrize('make', [
+    lambda m: ContinuousBatchingEngine(m, num_slots=2, max_len=32,
+                                       prefill_chunk=8, decode_block=2),
+    _paged], ids=['slot', 'paged'])
+@pytest.mark.parametrize('traced', [_Ticks], indirect=True)
+def test_step_span_has_its_phases_as_children(model, traced, make):
+    tr, clock = traced
+    eng = make(model)
+    eng.metrics._clock = clock          # the engine on the same clock
+    reqs = [eng.add_request(list(range(1, 12)), max_new_tokens=4),
+            eng.add_request([5, 6, 7], max_new_tokens=4)]
+    eng.run()
+    assert all(len(r.tokens) == 4 for r in reqs)
+    spans = tr.recorder.spans()
+    steps = [s for s in spans if s['name'] == 'serving.step']
+    assert [s['tags']['step'] for s in steps] == list(
+        range(1, len(steps) + 1))
+    assert steps[0]['tags']['queue_depth'] == 2
+    assert steps[0]['tags']['residents'] == 0
+    assert all(s['tags']['cpu_s'] >= 0.0 for s in steps)
+    assert ('pages_in_use' in steps[0]['tags']) == hasattr(eng, 'pages')
+    kids = {}
+    for s in spans:
+        kids.setdefault(s['parent_id'], []).append(s)
+    bursts = calls = 0
+    for st in steps:
+        mine = kids[st['span_id']]
+        names = [k['name'] for k in mine if k['name'] != 'perf.straggler']
+        assert names[:2] == ['serving.step.admit', 'serving.step.prefill']
+        assert names[2:] in ([], ['serving.decode_burst'])
+        for k in mine:                  # inside the parent, in order
+            assert st['start_mono'] <= k['start_mono'] <= k['end_mono'] \
+                <= st['end_mono']
+        assert sum(_dur(k) for k in mine) <= _dur(st)
+        pre = mine[1]
+        pcs = kids.get(pre['span_id'], [])
+        assert [c['name'] for c in pcs] == ['serving.prefill_call'] * len(
+            pcs)
+        assert pre['tags']['calls'] == len(pcs)
+        assert pre['tags']['tokens'] == sum(c['tags']['tokens']
+                                            for c in pcs)
+        assert sum(_dur(c) for c in pcs) <= _dur(pre)
+        calls += len(pcs)
+        for b in mine[2:3]:
+            # ONE set of clock reads: the tags split the span exactly
+            assert b['tags']['dispatch_s'] + b['tags']['block_s'] == \
+                pytest.approx(_dur(b))
+            bursts += 1
+    assert calls == 3                   # 11 tokens: 2 chunks; 3 tokens: 1
+    assert bursts == eng.timeline.steps > 0
+    admit0 = kids[steps[0]['span_id']][0]['tags']
+    assert admit0 == {'admitted': 2, 'left': 0, 'head_left': 'none'}
+    reg = eng.metrics.registry
+    assert reg.get('serving_prefill_calls_total').value() == 3
+
+
+def test_decode_program_ops_carry_the_scope_names(model):
+    """Named scopes are metadata on the compiled ops: the programs,
+    their count and the outputs stay what they were."""
+    eng = _paged(model)
+    prompt = [2, 7, 1, 8, 2, 8, 1, 8, 2, 8, 4]
+    out = eng.generate([prompt], max_new_tokens=5)[0]
+    want = model.generate(paddle.to_tensor([prompt]), max_new_tokens=5)
+    assert out == [int(t) for t in want.numpy()[0][len(prompt):]]
+    text = eng._decode_jit.lower(*eng._decode_args).compile().as_text()
+    for scope in ('gpt.embed', 'gpt.ln', 'gpt.attn.qkv',
+                  'gpt.attn.paged_write', 'gpt.attn.paged_gather',
+                  'gpt.attn.mask', 'gpt.attn.core', 'gpt.attn.out',
+                  'gpt.mlp', 'gpt.lm_head', 'serving.pick_token'):
+        assert scope in text, scope
+    assert eng.compiled_sizes() == {'prefill': 1, 'decode': 1, 'verify': 0}
+
+
+def test_admit_pass_counts_and_causes_on_full_pool_and_full_slots():
+    # the POOL is full: the head waits for pages, the rest behind it
+    sched, pages = _mk_sched(num_seqs=3, num_pages=9)
+    hog = Request(list(range(12)), max_new_tokens=5)    # 4 of 8 pages
+    big = Request(list(range(20)), max_new_tokens=9)    # 7 pages
+    small = Request([1, 2], max_new_tokens=2)
+    sched.submit(hog)
+    assert len(sched.admit()) == 1 and sched.head_left == 'none'
+    assert hog._admit_waits == {}
+    sched.submit(big)
+    sched.submit(small)
+    for _ in range(2):
+        assert sched.admit() == [] and sched.head_left == 'pages'
+    assert big._admit_waits == {'pages': 2}
+    assert small._admit_waits == {'behind_head': 2}
+    sched.mark_prefilled(hog, 12)
+    sched.retire(hog)
+    assert len(sched.admit()) == 2 and sched.head_left == 'none'
+    assert big._admit_waits == {'pages': 2}             # kept, not reset
+    # the SLOTS are full (one slot, pages to spare), both schedulers
+    for sched in (_mk_sched(num_seqs=1, num_pages=30)[0],
+                  Scheduler(SlotAllocator(1), 32, 4)):
+        a, b, c = (Request([1, 2, 3], max_new_tokens=2) for _ in range(3))
+        for r in (a, b, c):
+            sched.submit(r)
+        assert [r for _, r in sched.admit()] == [a]
+        assert sched.head_left == 'slots'
+        assert (b._admit_waits, c._admit_waits) == (
+            {'slots': 1}, {'behind_head': 1})
+        sched.mark_prefilled(a, 3)
+        sched.retire(a)
+        assert [r for _, r in sched.admit()] == [b]
+        assert c._admit_waits == {'slots': 1, 'behind_head': 1}
+
+
+def test_blocked_passes_reach_the_span_and_the_registry(model, traced):
+    tr, clock = traced
+    eng = _paged(model)
+    reqs = [eng.add_request([1 + i, 2, 3], max_new_tokens=4)
+            for i in range(3)]          # two slots: the third waits
+    eng.run()
+    by_id = {s['tags']['request_id']: s for s in tr.recorder.spans()
+             if s['name'] == 'serving.request'}
+    blocked = [next(e for e in by_id[r.id]['events']
+                    if e['name'] == 'admitted')['args']['blocked']
+               for r in reqs]
+    assert blocked[:2] == [{}, {}]
+    assert set(blocked[2]) == {'slots'} and blocked[2]['slots'] >= 1
+    fam = eng.metrics.registry.get('serving_admit_blocked_total')
+    assert fam.labels('slots').value() == blocked[2]['slots']
+    assert fam.labels('pages').value() == 0
+    admits = [s['tags'] for s in tr.recorder.spans()
+              if s['name'] == 'serving.step.admit']
+    assert admits[0] == {'admitted': 2, 'left': 1, 'head_left': 'slots'}
+
+
+def test_arrival_is_stamped_at_the_front_door(model):
+    import threading
+    import time
+    eng = _paged(model)
+    # a stated due time is honoured: TTFT and queue wait run from it
+    due = eng.metrics.now() - 5.0
+    r0 = eng.add_request([1, 2, 3], max_new_tokens=2, arrival_t=due)
+    assert r0._arrival_t == due
+    eng.run()
+    assert r0._first_token_t - r0._arrival_t > 5.0
+    assert r0._admit_t - r0._arrival_t > 5.0
+    # the default stamp is taken BEFORE the lock: a caller that waits
+    # for a slow step() to release it arrived when it called
+    inside, release = threading.Event(), threading.Event()
+    admit = eng._admit
+
+    def slow_admit():
+        inside.set()
+        assert release.wait(10)
+        return admit()
+    eng._admit = slow_admit
+    eng.add_request([4, 5, 6], max_new_tokens=2)
+    stepper = threading.Thread(target=eng.step)
+    stepper.start()
+    assert inside.wait(10)              # step() now holds the lock
+    got = {}
+
+    def caller():
+        got['t_call'] = eng.metrics.now()
+        got['req'] = eng.add_request([7, 8, 9], max_new_tokens=2)
+        got['t_back'] = eng.metrics.now()
+    th = threading.Thread(target=caller)
+    th.start()
+    time.sleep(0.6)
+    assert th.is_alive()                # blocked on the engine's lock
+    release.set()
+    th.join(30)
+    stepper.join(30)
+    assert not th.is_alive() and not stepper.is_alive()
+    eng._admit = admit
+    req = got['req']
+    assert got['t_back'] - got['t_call'] >= 0.5
+    assert got['t_call'] <= req._arrival_t < got['t_call'] + 0.25
+    eng.run()
+    assert len(req.tokens) == 2
+
+
+def test_on_token_sees_each_delivered_token_once(model):
+    eng = _paged(model, preempt=True)
+    prompts = [[3, 1, 4, 1, 5], [9, 2, 6, 5, 3], [5, 8, 9, 7, 9]]
+    seen = {i: [] for i in range(3)}
+    order = []
+
+    def sink(i):
+        def put(tok):
+            seen[i].append(tok)
+            order.append(i)
+        return put
+    r0 = eng.add_request(prompts[0], max_new_tokens=8, on_token=sink(0))
+    r1 = eng.add_request(prompts[1], max_new_tokens=8, on_token=sink(1),
+                         stream=True)
+    while min(len(r0.tokens), len(r1.tokens)) < 2:
+        eng.step()                      # both residents mid-decode
+    r2 = eng.add_request(prompts[2], max_new_tokens=8, priority=1,
+                         on_token=sink(2))
+    streamed = list(eng.stream(r1))     # drives the engine to the end
+    eng.run()
+    assert eng.scheduler.preempted == 1
+    victim = r1 if r1._preempts else r0
+    assert victim._preempts == 1 and victim.outcome == 'ok'
+    # once per delivered token, none for the tokens regenerated after
+    # the preemption; `stream=True` is unchanged beside the hook
+    for i, r in enumerate((r0, r1, r2)):
+        assert seen[i] == r.tokens and len(r.tokens) == 8
+    assert streamed == r1.tokens
+    assert len(order) == 24
+
+
+def test_straggler_record_says_which_phase(model, traced, tmp_path):
+    import json
+    import os
+    import time
+    from paddle_tpu.monitor.perf import StepTimeline
+    tr, _ = traced
+    eng = _paged(model)
+    # 20x the median: a loaded test machine's jitter is not a straggler,
+    # the half second planted below is
+    eng.timeline = StepTimeline(registry=eng.metrics.registry, tracer=tr,
+                                min_history=3, straggler_factor=20.0)
+    eng.add_request([1, 2, 3], max_new_tokens=24)
+    for _ in range(5):
+        eng.step()
+    fast = eng._decode_jit
+
+    def slow(*args):
+        out = fast(*args)
+        time.sleep(0.5)                 # the host sits in the dispatch
+        return out
+    eng._decode_jit = slow
+    eng.step()
+    eng._decode_jit = fast
+    eng.run()
+    recs = [s for s in tr.recorder.spans() if s['name'] == 'perf.straggler'
+            and s['tags'].get('engine_step') == 6]
+    assert len(recs) == 1 and eng.timeline.stragglers >= 1
+    tags = recs[0]['tags']
+    assert set(tags) >= {'total_s', 'median_s', 'step', 'engine_step',
+                         'step_s', 'admit_s', 'prefill_s',
+                         'burst_dispatch_s', 'burst_block_s', 'self_s',
+                         'cpu_s', 'compiles'}
+    assert tags['compiles'] == 0
+    assert tags['burst_dispatch_s'] >= 0.5 > tags['burst_block_s']
+    assert tags['cpu_s'] < 0.5 <= tags['step_s']    # waiting, not busy
+    parts = sum(tags[k] for k in ('admit_s', 'prefill_s',
+                                  'burst_dispatch_s', 'burst_block_s',
+                                  'self_s'))
+    assert parts == pytest.approx(tags['step_s'])
+    step = next(s for s in tr.recorder.spans()
+                if s['span_id'] == recs[0]['parent_id'])
+    assert step['name'] == 'serving.step' and step['tags']['step'] == 6
+    # the road a recompile record takes: ring + one throttled dump
+    dumps = os.listdir(str(tmp_path / 'flight'))
+    assert dumps == ['flight_straggler_0001.json']      # throttled: one
+    with open(os.path.join(str(tmp_path / 'flight'), dumps[0])) as f:
+        assert any(s['name'] == 'perf.straggler'
+                   for s in json.load(f)['spans'])

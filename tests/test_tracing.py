@@ -558,3 +558,122 @@ def test_disabled_tracing_adds_no_measurable_decode_overhead(model,
         tr.enable()
     # generous absolute slack: CPU jit dispatch jitter dwarfs span cost
     assert disabled <= enabled * 1.5 + 0.05, (disabled, enabled)
+
+
+# -- one clock, two sinks, and the step's spans --------------------------------
+
+def test_every_span_carries_ordered_monotonic_stamps(model, traced):
+    """Beside the epoch stamps (cross-process alignment) every span and
+    event carries time.monotonic, the engine's and the timeline's
+    clock; the request's own stamps ARE the events' stamps."""
+    tr, reg, flight = traced
+    eng = PagedContinuousBatchingEngine(model, num_seqs=2, max_len=64,
+                                        page_size=8, prefill_chunk=8,
+                                        decode_block=4)
+    t_before = time.monotonic()
+    reqs = [eng.add_request([1, 2, 3, 4, 5, 6, 7, 8, 9], max_new_tokens=5),
+            eng.add_request([3, 4], max_new_tokens=5)]
+    eng.run()
+    t_after = time.monotonic()
+    spans = tr.recorder.spans()
+    assert {s['name'] for s in spans} >= {
+        'serving.request', 'serving.prefill', 'serving.decode',
+        'serving.step', 'serving.step.admit', 'serving.step.prefill',
+        'serving.prefill_call', 'serving.decode_burst'}
+    for s in spans:
+        assert t_before <= s['start_mono'] <= s['end_mono'] <= t_after
+        assert s['start'] <= s['end'] and abs(s['start'] - time.time()) < 600
+        monos = [e['mono'] for e in s['events']]
+        assert monos == sorted(monos)
+        assert all(s['start_mono'] <= m <= s['end_mono'] for m in monos)
+    by_id = {s['tags']['request_id']: s for s in spans
+             if s['name'] == 'serving.request'}
+    for r in reqs:
+        s = by_id[r.id]
+        ev = {e['name']: e for e in s['events']}
+        assert list(ev) == ['queued', 'admitted', 'first_token', 'retired']
+        assert s['start_mono'] == ev['queued']['mono'] == r._arrival_t
+        assert ev['admitted']['mono'] == r._admit_t
+        assert ev['first_token']['mono'] == r._first_token_t
+    # the burst is a child of its step now, not a root
+    steps = {s['span_id'] for s in spans if s['name'] == 'serving.step'}
+    assert all(s['parent_id'] in steps for s in spans
+               if s['name'] == 'serving.decode_burst')
+
+
+def test_annotated_span_is_the_one_dual_sink_path(traced, monkeypatch):
+    """start_span(annotate=True) enters a TraceAnnotation of the span's
+    name until finish(); RecordEvent is a thin caller of it; a disabled
+    tracer enters nothing."""
+    tr, reg, flight = traced
+    log = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(('enter', self.name))
+
+        def __exit__(self, *exc):
+            log.append(('exit', self.name))
+    monkeypatch.setattr(tracing, '_TraceAnnotation', Ann)
+    with tr.start_span('outer', annotate=True):
+        with profiler.RecordEvent('inner'):
+            pass
+        sp = tr.start_span('plain')
+        sp.finish()
+    assert log == [('enter', 'outer'), ('enter', 'inner'),
+                   ('exit', 'inner'), ('exit', 'outer')]
+    assert [s['name'] for s in tr.recorder.spans()] == ['inner', 'plain',
+                                                        'outer']
+    sp = tr.start_span('held', annotate=True)
+    sp.finish(mono=sp.start_mono + 2.5)
+    sp.finish()                                  # idempotent: one exit
+    assert log[-2:] == [('enter', 'held'), ('exit', 'held')]
+    assert tr.recorder.spans()[-1]['end_mono'] == sp.start_mono + 2.5
+    tr.disable()
+    try:
+        del log[:]
+        with tr.start_span('off', annotate=True) as off:
+            with profiler.RecordEvent('off_too'):
+                pass
+        assert off is NULL_SPAN and log == []
+    finally:
+        tr.enable()
+
+
+def test_disabled_tracing_opens_no_step_span(model, traced):
+    tr, reg, flight = traced
+    eng = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
+                                   prefill_chunk=8, decode_block=4)
+    tr.disable()
+    try:
+        out = eng.generate([[1, 2, 3]], max_new_tokens=6)
+        # a flagged burst still counts, it just leaves no record
+        assert eng.timeline.steps > 0
+    finally:
+        tr.enable()
+    assert len(out[0]) == 6
+    assert tr.recorder.spans() == []
+    snap = to_dict(reg)
+    assert snap['trace_spans_started_total']['samples'][0]['value'] == 0
+
+
+def test_train_step_span(traced):
+    from paddle_tpu.framework.functional import TrainStep
+    tr, reg, flight = traced
+    paddle.seed(3)
+    net = paddle.nn.Linear(4, 2)
+    opt = paddle.optimizer.AdamW(learning_rate=1e-2,
+                                 parameters=net.parameters())
+    step = TrainStep(net, lambda out, y: ((out - y) ** 2).mean(), opt)
+    x = paddle.to_tensor(np.ones((3, 4), np.float32))
+    y = paddle.to_tensor(np.zeros((3, 2), np.float32))
+    for _ in range(3):
+        step(x, y)
+    got = [s for s in tr.recorder.spans() if s['name'] == 'train.step']
+    assert [s['tags']['step'] for s in got] == [1, 2, 3]
+    assert all(s['start_mono'] <= s['end_mono'] for s in got)
+    # the optimizer's device ops are named after its rule
+    assert 'optimizer.adamw' in step.compiled_hlo(x, y)[0]

@@ -326,6 +326,9 @@ class _EngineBase:
     def _tag_step(self, span):
         """Subclass hook: more of the state at a step's entry."""
 
+    def _tag_prefill_call(self, span):
+        """Subclass hook: what the call's program says of itself."""
+
     def _step_detail(self, sp, ph_admit, ph_prefill, burst, compiles):
         """Where a step went, for a `perf.straggler` record: phase
         seconds from the spans' own stamps (self = the step minus its
@@ -505,6 +508,7 @@ class _EngineBase:
                     tok = int(tok)           # the call's host sync
                 if sp:
                     sp.tags.update(slot=slot, tokens=valid, final=final)
+                    self._tag_prefill_call(sp)
             calls += 1
             tokens += valid
             self.metrics.on_prefill_tokens(valid)
@@ -521,16 +525,17 @@ class _EngineBase:
                 self._retire(req)
         return calls, tokens
 
-    def _burst_done(self, span, t0, t1, t2):
+    def _burst_done(self, span, t0, t1, t2, **tags):
         """A burst's one set of clock reads — dispatch returned at t1,
         results on the host at t2 — feeds the timeline's phases and the
-        `serving.decode_burst` span alike; returns (dispatch, block)
-        seconds, what `_decode_step` hands back to step()."""
+        `serving.decode_burst` span alike (`tags`: what else the span
+        says of the burst); returns (dispatch, block) seconds, what
+        `_decode_step` hands back to step()."""
         dispatch, block = t1 - t0, t2 - t1
         self.timeline.record('host_dispatch', dispatch)
         self.timeline.record('device_block', block)
         if span:
-            span.tags.update(dispatch_s=dispatch, block_s=block)
+            span.tags.update(dispatch_s=dispatch, block_s=block, **tags)
             span.finish(mono=t2)
         return dispatch, block
 

@@ -145,6 +145,10 @@ class PagedContinuousBatchingEngine(_EngineBase):
         # on rows the next real pass overwrites anyway.
         self._lens = np.zeros((self.num_slots,), np.int32)
         self._prefix_seen = [0, 0]    # hit/miss totals already reported
+        # which K/V read each program took ('pool' | 'gather'), written
+        # where the program is traced (`_unpack`) from what attention
+        # recorded on the caches it returned; the spans' `kv_read` tag
+        self.kv_read = {}
         if donate is None:
             donate = jax.default_backend() in ('tpu', 'gpu')
         dn = (2,) if donate else ()
@@ -189,6 +193,9 @@ class PagedContinuousBatchingEngine(_EngineBase):
 
     def _tag_step(self, span):
         span.set_tag('pages_in_use', self.pages.in_use)
+
+    def _tag_prefill_call(self, span):
+        span.set_tag('kv_read', self.kv_read['prefill'])
 
     def _on_step_metrics(self):
         self.metrics.on_pages_in_use(self.pages.in_use)
@@ -240,8 +247,8 @@ class PagedContinuousBatchingEngine(_EngineBase):
         return [GPTPagedCache(Tensor(k), Tensor(v), bt, lens)
                 for k, v in pools]
 
-    @staticmethod
-    def _unpack(caches):
+    def _unpack(self, program, caches):
+        self.kv_read[program] = caches[0].kv_read
         return [(c.k._data, c.v._data) for c in caches]
 
     def _prefill_fn(self, params, bufs, pools, bt1, len1, ids, valid,
@@ -259,7 +266,7 @@ class PagedContinuousBatchingEngine(_EngineBase):
                                             keepdims=False)
         key2, sub = jax.random.split(key)
         tok = _pick_token(last, sub, temp, topk, sample)
-        return self._unpack(new_cs), tok, key2
+        return self._unpack('prefill', new_cs), tok, key2
 
     def _decode_fn(self, params, bufs, pools, bt, lens, tok, gen,
                    budgets, active, keys, temps, topks, sample):
@@ -282,8 +289,8 @@ class PagedContinuousBatchingEngine(_EngineBase):
             nxt = jax.vmap(_pick_token)(lg[:, -1], subs, temps, topks,
                                         sample)
             tok2 = jnp.where(step_active, nxt, tok[:, 0])[:, None]
-            return ((self._unpack(new_cs), lens + inc, tok2, gen + inc,
-                     keys2), (tok2[:, 0], step_active))
+            return ((self._unpack('decode', new_cs), lens + inc, tok2,
+                     gen + inc, keys2), (tok2[:, 0], step_active))
 
         carry, (toks, actives) = jax.lax.scan(
             body, (pools, lens, tok, gen, keys), None,
@@ -306,7 +313,7 @@ class PagedContinuousBatchingEngine(_EngineBase):
             kwargs={'caches': caches}, training=False)
         picks = jnp.argmax(lg.astype(jnp.float32), axis=-1).astype(
             jnp.int32)
-        return self._unpack(new_cs), picks
+        return self._unpack('verify', new_cs), picks
 
     # ---- per-step dispatches (lock held) ------------------------------
 
@@ -350,7 +357,8 @@ class PagedContinuousBatchingEngine(_EngineBase):
             t1 = clock()
             lens, last, gen, keys, toks, actives = jax.device_get(
                 (lens, last, gen, keys, toks, actives))
-            burst = self._burst_done(sp, t0, t1, clock())
+            burst = self._burst_done(sp, t0, t1, clock(),
+                                     kv_read=self.kv_read['decode'])
         self._lens = np.array(lens)
         self._last = np.array(last)
         self._gen = np.array(gen)
@@ -391,7 +399,8 @@ class PagedContinuousBatchingEngine(_EngineBase):
             self._pools, picks = self._verify_jit(*args)
             t1 = clock()
             picks = np.asarray(jax.device_get(picks))
-            burst = self._burst_done(sp, t0, t1, clock())
+            burst = self._burst_done(sp, t0, t1, clock(),
+                                     kv_read=self.kv_read['verify'])
         for slot in slots:
             req = self._requests[slot]
             d, g = drafts[slot], picks[slot]

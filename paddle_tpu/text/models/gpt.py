@@ -217,6 +217,10 @@ class GPTPagedCache:
         self.v = v_pool
         self.block_tables = block_tables  # [B, max_blocks] int32
         self.lengths = lengths            # [B] int32 (traced under jit)
+        # set by attention on the cache it RETURNS, at trace time: which
+        # read it took ('pool' | 'gather', see `paged_kv_read`). Not a
+        # pytree leaf: a cache rebuilt from leaves has forgotten it.
+        self.kv_read = None
 
     @staticmethod
     def empty(num_pages, page_size, max_blocks, batch, num_heads,
@@ -240,6 +244,27 @@ def _paged_cache_unflatten(_, children):
 
 jax.tree_util.register_pytree_node(GPTPagedCache, _paged_cache_flatten,
                                    _paged_cache_unflatten)
+
+
+def paged_kv_read(batch, capacity, pool_rows):
+    """Which read the `GPTPagedCache` branch of attention takes, from the
+    shapes alone: 'pool' attends over every pool row in place and masks
+    what a row does not hold; 'gather' first materializes each row's
+    `[capacity]` logical view. The pool is the smaller read once the
+    views together (`batch * capacity` token rows, K and V, per layer)
+    are at least the pool — a decode or verify batch; a one-row prefill
+    chunk keeps the gather (its scores would span the whole pool)."""
+    return 'pool' if batch * capacity >= pool_rows else 'gather'
+
+
+def _pool_attention(q, kf, vf, mask):
+    """q `[B, n, H, Dh]` against ALL pool rows kf / vf `[R, H, Dh]` under
+    an additive mask `[B, 1, n, R]`: `_sdpa_ref`'s arithmetic (products
+    in the operands' dtype, float32 softmax) with no batch axis on the
+    keys, so nothing of the pool is copied per row."""
+    s = jnp.einsum('bqhd,khd->bhqk', q, kf) * (1.0 / q.shape[-1] ** 0.5)
+    p = jax.nn.softmax((s + mask).astype(jnp.float32), axis=-1)
+    return jnp.einsum('bhqk,khd->bqhd', p.astype(q.dtype), vf)
 
 
 def _cache_get(cache, key, build, cap=8):
@@ -339,11 +364,36 @@ class GPTAttention(nn.Layer):
             new_cache = GPTPagedCache(
                 Tensor(kf.reshape(cache.k._data.shape)),
                 Tensor(vf.reshape(cache.v._data.shape)), bt, t)
-            # read: gather each row's logical [L] view through its block
-            # table (this step's rows included — written above), then the
-            # same masked attention as the slot path. The gather
-            # materializes [B, L, H, Dh] activations; persistent memory
-            # stays page-granular, which is where the density win lives.
+            # read, by shape at trace time (`paged_kv_read`): the pool's
+            # rows where they lie when every row's logical view together
+            # would be at least the pool, else the gathered view. Same
+            # arithmetic either way; keys only come in another order.
+            read = new_cache.kv_read = paged_kv_read(b, L, num_pages * page)
+            if read == 'pool':
+                # a pool row (p, r) is logical position j*page + r of the
+                # row whose FIRST block-table entry holding p is j (nb:
+                # none, past every query). A shared page is visible to
+                # each holder; scratch page 0 fills every unused entry,
+                # so its first j lies past the row's length, and an idle
+                # row (t = 0, all scratch) sees position 0 as below.
+                with _scope('gpt.attn.mask'):
+                    qpos = t[:, None] + jnp.arange(n)[None, :]       # [B, n]
+                    holds = bt[:, :, None] == jnp.arange(num_pages)
+                    first = jnp.min(jnp.where(
+                        holds, jnp.arange(nb)[None, :, None], nb), axis=1)
+                    kpos = (first[:, :, None] * page
+                            + jnp.arange(page)).reshape(b, num_pages * page)
+                    allow = qpos[:, :, None] >= kpos[:, None, :]
+                    mask = jnp.where(allow, 0.0, -1e9)[:, None].astype(
+                        jnp.float32)                   # [B, 1, n, pool rows]
+                with _scope('gpt.attn.core'):
+                    out = Tensor(_pool_attention(q._data, kf, vf, mask))
+                return self._out(out, b, n), new_cache
+            # gather each row's logical [L] view through its block table
+            # (this step's rows included — written above), then the same
+            # masked attention as the slot path. The gather materializes
+            # [B, L, H, Dh] activations; persistent memory stays
+            # page-granular, which is where the density win lives.
             with _scope('gpt.attn.paged_gather'):
                 view = (bt[:, :, None] * page
                         + jnp.arange(page)[None, None, :]).reshape(b, L)

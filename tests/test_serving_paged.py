@@ -17,6 +17,7 @@ from paddle_tpu.serving import (ContinuousBatchingEngine, NGramProposer,
 from paddle_tpu.serving.kv_cache import (SCRATCH_PAGE, PageAllocator,
                                          PrefixCache)
 from paddle_tpu.serving.scheduler import Request
+from paddle_tpu.text.models import gpt
 from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
 
 
@@ -467,19 +468,103 @@ def test_step_span_has_its_phases_as_children(model, traced, make):
 
 def test_decode_program_ops_carry_the_scope_names(model):
     """Named scopes are metadata on the compiled ops: the programs,
-    their count and the outputs stay what they were."""
-    eng = _paged(model)
+    their count and the outputs stay what they were. A decode program
+    that reads the pool in place has no `gpt.attn.paged_gather` op; the
+    one-row prefill program, which gathers, has."""
+    eng = _paged(model, num_pages=8)
     prompt = [2, 7, 1, 8, 2, 8, 1, 8, 2, 8, 4]
     out = eng.generate([prompt], max_new_tokens=5)[0]
     want = model.generate(paddle.to_tensor([prompt]), max_new_tokens=5)
     assert out == [int(t) for t in want.numpy()[0][len(prompt):]]
+    common = ('gpt.embed', 'gpt.ln', 'gpt.attn.qkv', 'gpt.attn.paged_write',
+              'gpt.attn.mask', 'gpt.attn.core', 'gpt.attn.out', 'gpt.mlp',
+              'gpt.lm_head', 'serving.pick_token')
     text = eng._decode_jit.lower(*eng._decode_args).compile().as_text()
-    for scope in ('gpt.embed', 'gpt.ln', 'gpt.attn.qkv',
-                  'gpt.attn.paged_write', 'gpt.attn.paged_gather',
-                  'gpt.attn.mask', 'gpt.attn.core', 'gpt.attn.out',
-                  'gpt.mlp', 'gpt.lm_head', 'serving.pick_token'):
+    for scope in common:
+        assert scope in text, scope
+    assert 'gpt.attn.paged_gather' not in text
+    key = np.zeros((2,), np.uint32)
+    text = eng._prefill_jit.lower(
+        eng._params, eng._bufs, eng._pools, eng.scheduler.block_tables[:1],
+        np.zeros((1,), np.int32), np.zeros((1, 8), np.int32), np.int32(8),
+        key, np.float32(1.0), np.int32(0), np.asarray(False)
+    ).compile().as_text()
+    for scope in common + ('gpt.attn.paged_gather',):
         assert scope in text, scope
     assert eng.compiled_sizes() == {'prefill': 1, 'decode': 1, 'verify': 0}
+
+
+# ---- the K/V read: the pool in place, or each row's gathered view ------
+
+
+@pytest.mark.parametrize('n', [1, 3])
+def test_pool_read_agrees_with_the_gathered_view(model, monkeypatch, n):
+    """Float32 logits and greedy picks of the two reads over one pool of
+    random rows: a page shared by two rows, scratch entries behind a
+    row's pages, an idle row (all scratch, length 0) and a row that this
+    call fills to capacity. Whatever lies past a row's length, on pages
+    it does not hold or on the scratch page, is garbage to both."""
+    page, nb, num_pages, layers, heads, dh = 8, 4, 12, 2, 4, 16
+    rng = np.random.RandomState(5)
+    tables = np.asarray([[1, 2, 0, 0],      # 13 rows, page 1 shared
+                         [1, 3, 4, 0],      # 17 rows, page 1 shared
+                         [0, 0, 0, 0],      # idle
+                         [5, 6, 7, 8]],     # full after this call
+                        np.int32)
+    lens = np.asarray([13, 17, 0, nb * page - n], np.int32)
+    ids = rng.randint(0, 211, (4, n)).astype(np.int32)
+    pools = [tuple(rng.randn(num_pages, page, heads, dh).astype(np.float32)
+                   for _ in 'kv') for _ in range(layers)]
+    assert gpt.paged_kv_read(4, nb * page, num_pages * page) == 'pool'
+
+    def run(read):
+        monkeypatch.setattr(gpt, 'paged_kv_read', lambda *shape: read)
+        caches = [gpt.GPTPagedCache(paddle.to_tensor(k), paddle.to_tensor(v),
+                                    tables, lens) for k, v in pools]
+        logits, new = model(paddle.to_tensor(ids), caches=caches)
+        assert [c.kv_read for c in new] == [read] * layers
+        return logits.numpy(), [(c.k.numpy(), c.v.numpy()) for c in new]
+
+    (pool, pool_kv), (gather, gather_kv) = run('pool'), run('gather')
+    assert pool.dtype == np.float32 and pool.shape == (4, n, 211)
+    np.testing.assert_allclose(pool, gather, rtol=0, atol=1e-5)
+    assert (pool.argmax(-1) == gather.argmax(-1)).all()
+    # the write is one code: the first layer's pools come out the same
+    # bit for bit, the next one's inputs already differ in the last digit
+    np.testing.assert_array_equal(pool_kv[0], gather_kv[0])
+    np.testing.assert_allclose(pool_kv[1], gather_kv[1], rtol=0, atol=1e-5)
+
+
+@pytest.mark.parametrize('spec_k', [0, 3])
+def test_shape_rule_and_the_spans_kv_read_tag(model, traced, spec_k):
+    """The rule reads shapes only: a decode (or verify) batch whose views
+    together are at least the pool reads the pool, a one-row chunk
+    gathers; the programs remember it, the spans carry it, and the
+    tokens are the ones a pool too large for the rule gives."""
+    assert gpt.paged_kv_read(24, 1024, 512 * 16) == 'pool'
+    assert gpt.paged_kv_read(1, 1024, 512 * 16) == 'gather'
+    tr, _ = traced
+    rng = np.random.RandomState(3)
+    system = [int(t) for t in rng.randint(0, 211, 8)]   # one shared page
+    prompts = [system + [int(t) for t in rng.randint(0, 211, k)]
+               for k in (3, 5, 2, 7)]
+    want = [[int(t) for t in model.generate(
+        paddle.to_tensor([p]), max_new_tokens=6).numpy()[0][len(p):]]
+        for p in prompts]
+    burst = 'verify' if spec_k else 'decode'
+    # 2 rows x 32 logical rows >= 8 pages x 8; the default 9 pages: not
+    eng = _paged(model, num_pages=8, spec_k=spec_k)
+    assert eng.generate(prompts, max_new_tokens=6) == want
+    assert eng.kv_read == {'prefill': 'gather', burst: 'pool'}
+    assert eng.metrics.report()['prefix_hits'] > 0      # the page WAS shared
+    spans = tr.recorder.spans()
+    for name, read in (('serving.decode_burst', 'pool'),
+                       ('serving.prefill_call', 'gather')):
+        tags = [s['tags']['kv_read'] for s in spans if s['name'] == name]
+        assert tags and set(tags) == {read}, name
+    roomy = _paged(model, spec_k=spec_k)
+    assert roomy.generate(prompts, max_new_tokens=6) == want
+    assert roomy.kv_read == {'prefill': 'gather', burst: 'gather'}
 
 
 def test_admit_pass_counts_and_causes_on_full_pool_and_full_slots():

@@ -52,6 +52,8 @@ def record_dryrun_step(registry, step_seconds, loss, batch=None):
 SERVING_PAGED_FAMILIES = (
     ('gauge', 'serving_kv_pages_in_use',
      'physical KV pages currently referenced (sequences + prefix cache)'),
+    ('gauge', 'serving_state_bytes',
+     'bytes of per-slot recurrent state that belong to a resident'),
     ('counter', 'serving_prefix_cache_hits_total',
      'full prompt blocks served from the prefix cache'),
     ('counter', 'serving_prefix_cache_misses_total',

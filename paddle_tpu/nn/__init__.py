@@ -2,7 +2,7 @@
 from . import functional  # noqa: F401
 from . import initializer  # noqa: F401
 from .layer import *  # noqa: F401,F403
-from .layer.layers import Layer, ParamAttr  # noqa: F401
+from .layer.layers import Layer, ParamAttr, skip_init  # noqa: F401
 from .clip import (ClipGradByValue, ClipGradByNorm,  # noqa: F401
                    ClipGradByGlobalNorm)
 from .utils_weight_norm import (weight_norm, remove_weight_norm,  # noqa: F401

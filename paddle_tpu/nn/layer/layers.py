@@ -6,15 +6,35 @@ pytree out, run forward under a jit trace with tracer-backed params bound in,
 and push updated arrays back — that is how the fast path compiles.
 """
 import collections
+import contextlib
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from ...framework.core import Tensor, Parameter, no_grad_guard
 from ...framework import dtype as dtype_mod
 from .. import initializer as init_mod
 
-__all__ = ['Layer', 'ParamAttr']
+__all__ = ['Layer', 'ParamAttr', 'skip_init']
+
+_SKIP_INIT = [False]
+
+
+@contextlib.contextmanager
+def skip_init():
+    """Build layers without initialising them: inside, `create_parameter`
+    gives a Parameter of the right shape and dtype with NO array behind
+    it (`_data` is a `jax.ShapeDtypeStruct`), for a caller that puts
+    every value in itself before the first use — a served model whose
+    weights come from elsewhere and would not fit the device beside a
+    random initialisation of the same size. Reading such a parameter's
+    values before they are put raises."""
+    before, _SKIP_INIT[0] = _SKIP_INIT[0], True
+    try:
+        yield
+    finally:
+        _SKIP_INIT[0] = before
 
 
 class ParamAttr:
@@ -82,8 +102,14 @@ class Layer:
         if initializer is None:
             initializer = (init_mod.Constant(0.0) if is_bias
                            else init_mod.XavierNormal())
-        data = initializer(shape, dtype)
-        p = Parameter(data, name=attr.name, trainable=attr.trainable)
+        if _SKIP_INIT[0]:
+            jdtype = dtype_mod.to_jax_dtype(dtype)
+            p = Parameter(jnp.zeros((), jdtype), name=attr.name,
+                          trainable=attr.trainable)
+            p._data = jax.ShapeDtypeStruct(tuple(shape), jdtype)
+        else:
+            p = Parameter(initializer(shape, dtype), name=attr.name,
+                          trainable=attr.trainable)
         p.optimize_attr['learning_rate'] = attr.learning_rate
         p.regularizer = attr.regularizer
         p.need_clip = attr.need_clip

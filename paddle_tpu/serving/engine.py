@@ -44,23 +44,12 @@ from ..monitor import tracing as _tracing
 from ..monitor.perf import CompileWatchdog, StepTimeline
 from ..monitor.perf import costmodel as _costmodel
 from ..text.models.gpt import GPTSlotCache
-from .kv_cache import SlotAllocator, build_slot_caches
+from .kv_cache import (SlotAllocator, build_slot_caches, cache_specs,
+                       kv_row_bytes)
 from .metrics import ServingMetrics
 from .scheduler import Request, Scheduler
 
 __all__ = ['ContinuousBatchingEngine']
-
-
-def _kv_row_bytes(model):
-    """Bytes one KV-cache row (all layers, K+V) costs for `model` —
-    the conversion factor between page·seconds and byte·seconds for
-    per-tenant billing."""
-    config = model.config
-    head_dim = config.hidden_size // config.num_heads
-    dtype = str(model.gpt.wte.weight.dtype).replace('paddle.', '')
-    itemsize = {'bfloat16': 2, 'float16': 2, 'int8': 1}.get(
-        dtype) or np.dtype(dtype).itemsize
-    return 2 * len(model.gpt.h) * config.num_heads * head_dim * itemsize
 
 
 @jax.named_scope('serving.pick_token')    # names the device ops, no more
@@ -507,7 +496,8 @@ class _EngineBase:
                 if final:
                     tok = int(tok)           # the call's host sync
                 if sp:
-                    sp.tags.update(slot=slot, tokens=valid, final=final)
+                    sp.tags.update(slot=slot, start=start, tokens=valid,
+                                   final=final)
                     self._tag_prefill_call(sp)
             calls += 1
             tokens += valid
@@ -643,7 +633,8 @@ class ContinuousBatchingEngine(_EngineBase):
         self.scheduler = Scheduler(self.allocator, self.max_len,
                                    prefill_chunk)
         # billing unit for kv_byte_seconds: a slot reserves max_len rows
-        self._kv_page_bytes = _kv_row_bytes(model) * self.max_len
+        self._kv_page_bytes = kv_row_bytes(
+            cache_specs(model)) * self.max_len
         if donate is None:
             # cache buffers dominate engine memory; donating them lets
             # XLA update in place. CPU donation is a no-op that warns.

@@ -1,19 +1,24 @@
-"""Slot and page bookkeeping for the serving KV caches.
+"""Slot and page bookkeeping for the serving caches, and the per-layer
+cache interface between the engines and a model.
 
-Two device layouts share this host module:
+Two K/V layouts share this host module:
 
 - `GPTSlotCache` (text/models/gpt.py): per layer, fixed
   [num_slots, max_len, H, Dh] buffers plus a per-slot valid-length
   vector — every slot reserves `max_len` rows. `SlotAllocator` owns
   which slots are free and who holds them.
-- `GPTPagedCache`: per layer, a pool of [num_pages, page_size, H, Dh]
-  pages addressed through per-sequence block tables — a sequence only
-  holds the pages it needs, and sequences sharing a prompt prefix map
-  their leading block-table entries to the SAME physical page.
-  `PageAllocator` (refcounted free list) and `PrefixCache` (block-hash
-  -> page, LRU) own the host side.
+- `PagedKVCache` (text/models/cache.py): per layer, a pool of
+  [num_pages, page_size, H, Dh] pages addressed through per-sequence
+  block tables — a sequence only holds the pages it needs, and
+  sequences sharing a prompt prefix map their leading block-table
+  entries to the SAME physical page. `PageAllocator` (refcounted free
+  list) and `PrefixCache` (block-hash -> page, LRU) own the host side.
 
-Neither layout needs buffer clearing on reuse: a new occupant's prefill
+Beside pages, the paged engine keeps a second kind of state for a layer
+that names it (`RecurrentSpec`): `[num_seqs, ...]` arrays that belong to
+a SLOT, not to pages — see "the per-layer cache interface" below.
+
+Neither K/V layout needs buffer clearing on reuse: a new occupant's prefill
 writes from its own offset 0 and the validity mask never lets a query
 see rows at/beyond the owning sequence's current length, so a previous
 occupant's rows are unreachable the moment the length resets (the
@@ -24,7 +29,9 @@ import time
 from collections import OrderedDict
 
 __all__ = ['SlotAllocator', 'build_slot_caches', 'PageAllocator',
-           'PrefixCache', 'build_paged_pools', 'SCRATCH_PAGE']
+           'PrefixCache', 'build_paged_pools', 'SCRATCH_PAGE',
+           'cache_specs', 'kv_row_bytes', 'state_bytes_per_seq',
+           'layer_caches', 'layer_state']
 
 
 class SlotAllocator:
@@ -316,35 +323,128 @@ class PrefixCache:
         return len(self._pages)
 
 
-def build_paged_pools(model, num_pages, page_size):
-    """One (k_pool, v_pool) jnp pair per transformer layer: the device
-    arrays behind GPTPagedCache. Block tables / lengths stay host-side
-    (the engine passes them per dispatch); only the pools are persistent
-    device state. dtype follows the token embedding, like
-    build_slot_caches."""
+# ---- the per-layer cache interface --------------------------------------
+#
+# A model says what each of its layers keeps (`model.cache_specs()`,
+# text/models/cache.py): rows of K and V in the page pool, or a fixed
+# set of arrays per sequence that every token rewrites. Everything the
+# engines hold on the device is built from those specs here, and so are
+# the cache objects a dispatch hands the model; no engine reads a
+# model's attributes.
+
+def cache_specs(model):
+    """The model's per-layer specs, a list with one entry per layer."""
+    specs = getattr(model, 'cache_specs', None)
+    if specs is None:
+        raise TypeError(
+            '%s names no per-layer caches: a served model defines '
+            'cache_specs() (text/models/cache.py)' % type(model).__name__)
+    return list(specs())
+
+
+def _is_paged(spec):
+    from ..text.models.cache import PagedKVSpec
+    return isinstance(spec, PagedKVSpec)
+
+
+def _nbytes(shape, dtype):
     import jax.numpy as jnp
-    config = model.config
-    dtype = str(model.gpt.wte.weight.dtype).replace('paddle.', '')
-    head_dim = config.hidden_size // config.num_heads
-    shape = (num_pages, page_size, config.num_heads, head_dim)
-    return [(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-            for _ in model.gpt.h]
+    n = jnp.dtype(dtype).itemsize
+    for d in shape:
+        n *= int(d)
+    return n
+
+
+def kv_row_bytes(specs):
+    """Bytes one held token costs over the layers that keep K/V rows —
+    the conversion factor between page·seconds and byte·seconds for
+    per-tenant billing."""
+    return sum(2 * _nbytes((s.num_heads, s.head_dim), s.dtype)
+               for s in specs if _is_paged(s))
+
+
+def state_bytes_per_seq(specs):
+    """Bytes one resident sequence keeps in the recurrent layers,
+    whatever its length."""
+    return sum(_nbytes(shape, dtype) for s in specs if not _is_paged(s)
+               for shape, dtype in s.arrays)
+
+
+def build_paged_pools(model, num_pages, page_size, num_seqs=0):
+    """The paged engine's persistent device state, one entry per layer:
+    a (k_pool, v_pool) pair `[num_pages, page_size, H, Dh]` for a layer
+    that keeps K/V rows, a tuple of `[num_seqs, ...]` arrays for a
+    recurrent one. Block tables / lengths stay host-side (the engine
+    passes them per dispatch)."""
+    import jax.numpy as jnp
+    state = []
+    for spec in cache_specs(model):
+        if _is_paged(spec):
+            shape = (num_pages, page_size, spec.num_heads, spec.head_dim)
+            state.append((jnp.zeros(shape, spec.dtype),
+                          jnp.zeros(shape, spec.dtype)))
+        else:
+            state.append(tuple(jnp.zeros((num_seqs,) + tuple(shape), dtype)
+                               for shape, dtype in spec.arrays))
+    return state
+
+
+def layer_caches(specs, state, block_tables, lengths, valid, slot=None):
+    """One cache object per layer for one dispatch over `state`.
+    `lengths` / `valid` `[B]`: tokens each row holds before the call and
+    how many of the call's tokens are real for it. With `slot` (a traced
+    scalar: the one-row prefill program) a recurrent layer's arrays are
+    that sequence's row alone."""
+    import jax
+    from ..framework.core import Tensor
+    from ..text.models.cache import PagedKVCache, RecurrentCache
+    caches = []
+    for spec, arrays in zip(specs, state):
+        if _is_paged(spec):
+            k, v = arrays
+            caches.append(PagedKVCache(Tensor(k), Tensor(v), block_tables,
+                                       lengths))
+            continue
+        if slot is not None:
+            arrays = [jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=0)
+                      for a in arrays]
+        caches.append(RecurrentCache(arrays, lengths, valid))
+    return caches
+
+
+def layer_state(state, caches, slot=None):
+    """The device state after a forward returned `caches`: the new pools,
+    and the recurrent arrays (with `slot`, written back into that row of
+    `state`'s)."""
+    import jax
+    from ..text.models.cache import PagedKVCache
+    out = []
+    for old, c in zip(state, caches):
+        if isinstance(c, PagedKVCache):
+            out.append((c.k._data, c.v._data))
+        elif slot is None:
+            out.append(tuple(c.arrays))
+        else:
+            out.append(tuple(
+                jax.lax.dynamic_update_slice_in_dim(
+                    a, new.astype(a.dtype), slot, axis=0)
+                for a, new in zip(old, c.arrays)))
+    return out
 
 
 def build_slot_caches(model, num_slots, max_len):
-    """One GPTSlotCache per transformer layer of a GPTForCausalLM.
-
-    dtype follows the token embedding (bf16 on TPU serving), matching
-    what GPTForCausalLM.generate() does for its static cache.
-    """
+    """One GPTSlotCache per transformer layer of a model whose layers
+    all keep K/V rows (a slot reserves `max_len` of them)."""
     from ..text.models.gpt import GPTSlotCache
     config = model.config
     if max_len > config.max_position_embeddings:
         raise ValueError(
             'slot capacity %d exceeds max_position_embeddings %d'
             % (max_len, config.max_position_embeddings))
-    dtype = str(model.gpt.wte.weight.dtype).replace('paddle.', '')
-    head_dim = config.hidden_size // config.num_heads
-    return [GPTSlotCache.empty(num_slots, max_len, config.num_heads,
-                               head_dim, dtype=dtype)
-            for _ in model.gpt.h]
+    specs = cache_specs(model)
+    if not all(_is_paged(s) for s in specs):
+        raise ValueError(
+            'the slot engine keeps K/V rows only: serve a model with '
+            'recurrent layers through PagedContinuousBatchingEngine')
+    return [GPTSlotCache.empty(num_slots, max_len, s.num_heads, s.head_dim,
+                               dtype=s.dtype) for s in specs]

@@ -78,6 +78,7 @@ class ServingMetrics:
         # engine a process happens to run
         paged = record_serving_schema(r)
         self._m_pages = paged['serving_kv_pages_in_use']
+        self._m_state_bytes = paged['serving_state_bytes']
         self._m_prefix_hits = paged['serving_prefix_cache_hits_total']
         self._m_prefix_misses = paged['serving_prefix_cache_misses_total']
         self._m_spec_proposed = paged['serving_spec_tokens_proposed_total']
@@ -182,6 +183,9 @@ class ServingMetrics:
         """An admit pass left its head queued for `cause` ('slots' or
         'pages' — the scheduler's closed set)."""
         self._m_admit_blocked.labels(cause).inc()
+
+    def on_state_bytes(self, nbytes):
+        self._m_state_bytes.set(nbytes)
 
     def on_pages_in_use(self, pages):
         self._pages_in_use = pages

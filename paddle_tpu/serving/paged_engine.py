@@ -26,8 +26,15 @@ bounded — trace-count gauges assert it):
   decode burst   — K cached steps for ALL sequences (spec off);
   verify pass    — [S, K+1] draft tokens for ALL sequences (spec on).
 
-Only the page pools live on device; block tables and lengths are host
-numpy handed to jit per dispatch (values change freely, shapes never).
+Only the layers' state lives on device — per layer, as the model names
+it (`cache_specs()`, serving/kv_cache.py): the page pools of a layer that
+keeps K/V rows, or `[num_seqs, ...]` arrays that belong to a slot for a
+recurrent layer (a linear-attention state). Block tables and lengths are
+host numpy handed to jit per dispatch (values change freely, shapes
+never). A recurrent layer's state cannot be shared by prefix nor taken
+back after a rejected draft, so a model with one runs with
+`prefix_cache=False` and `spec_k=0` (the constructor says so); a
+preempted request recomputes from position 0, state and all.
 """
 import numpy as np
 
@@ -36,10 +43,10 @@ import jax.numpy as jnp
 
 from ..framework import functional as _fm
 from ..framework.core import Tensor
-from ..text.models.gpt import GPTPagedCache
-from .engine import _EngineBase, _kv_row_bytes, _pick_token
+from .engine import _EngineBase, _pick_token
 from .kv_cache import (PageAllocator, PrefixCache, SlotAllocator,
-                       build_paged_pools)
+                       build_paged_pools, cache_specs, kv_row_bytes,
+                       layer_caches, layer_state, state_bytes_per_seq)
 from .scheduler import PagedScheduler
 
 __all__ = ['PagedContinuousBatchingEngine', 'NGramProposer']
@@ -80,7 +87,9 @@ class NGramProposer:
 
 
 class PagedContinuousBatchingEngine(_EngineBase):
-    """Page-granular continuous batching over a GPTForCausalLM.
+    """Page-granular continuous batching over a decoder that names its
+    per-layer caches (`cache_specs()`): GPTForCausalLM,
+    OlmoHybridForCausalLM.
 
     Same front door and scheduling policy as ContinuousBatchingEngine;
     differs in the KV layout (page pool + block tables), prefix-cache
@@ -118,8 +127,26 @@ class PagedContinuousBatchingEngine(_EngineBase):
         if self.spec_k < 0:
             raise ValueError('spec_k must be >= 0')
         self._proposer = NGramProposer(ngram) if self.spec_k else None
+        # what each layer keeps (the model names it): K/V rows in the
+        # page pool, or per-SLOT arrays that every token rewrites
+        self._specs = cache_specs(model)
+        self._state_seq_bytes = state_bytes_per_seq(self._specs)
+        if self._state_seq_bytes and prefix_cache:
+            raise ValueError(
+                'prefix_cache=True with a recurrent layer: shared pages '
+                'hold K/V rows only, so a prefix hit would start the '
+                "recurrent layers' state from zeros at the hit's end "
+                'instead of from the prefix. Pass prefix_cache=False '
+                '(snapshots of state per cached block are not built yet).')
+        if self._state_seq_bytes and self.spec_k:
+            raise ValueError(
+                'spec_k=%d with a recurrent layer: a verify pass advances '
+                'the state over every draft and cannot take back the ones '
+                'the accept rule rejects (rejected K/V rows are simply '
+                'overwritten; a state has no dead rows). Pass spec_k=0.'
+                % self.spec_k)
         self._pools = build_paged_pools(model, self.num_pages,
-                                        self.page_size)
+                                        self.page_size, self.num_slots)
         self.pages = PageAllocator(self.num_pages)
         self.prefix = (PrefixCache(self.page_size, self.pages)
                        if prefix_cache else None)
@@ -138,7 +165,7 @@ class PagedContinuousBatchingEngine(_EngineBase):
                                        else int(max_preempts))
         self.scheduler.on_preempt = self._on_preempt
         # billing unit for kv_byte_seconds: one physical page
-        self._kv_page_bytes = _kv_row_bytes(model) * self.page_size
+        self._kv_page_bytes = kv_row_bytes(self._specs) * self.page_size
         # per-row KV length (rows written), the block-table companion to
         # the base class's host control arrays. Mid-prefill rows track
         # consumed so in-program garbage writes from frozen lanes land
@@ -191,14 +218,23 @@ class PagedContinuousBatchingEngine(_EngineBase):
             req._span.add_event('prefix_cache_hit',
                                 tokens=req._prefix_hit)
 
+    def _state_in_use(self):
+        """(slots whose recurrent state belongs to a resident, its
+        bytes): zeros for a model that keeps K/V rows only."""
+        slots = self.allocator.in_use if self._state_seq_bytes else 0
+        return slots, slots * self._state_seq_bytes
+
     def _tag_step(self, span):
-        span.set_tag('pages_in_use', self.pages.in_use)
+        slots, nbytes = self._state_in_use()
+        span.tags.update(pages_in_use=self.pages.in_use,
+                         state_slots_in_use=slots, state_bytes=nbytes)
 
     def _tag_prefill_call(self, span):
         span.set_tag('kv_read', self.kv_read['prefill'])
 
     def _on_step_metrics(self):
         self.metrics.on_pages_in_use(self.pages.in_use)
+        self.metrics.on_state_bytes(self._state_in_use()[1])
         if self.prefix is not None:
             h, m = self.prefix.hits, self.prefix.misses
             self.metrics.on_prefix_lookup(h - self._prefix_seen[0],
@@ -243,22 +279,23 @@ class PagedContinuousBatchingEngine(_EngineBase):
 
     # ---- the three compiled programs ----------------------------------
 
-    def _caches(self, pools, bt, lens):
-        return [GPTPagedCache(Tensor(k), Tensor(v), bt, lens)
-                for k, v in pools]
-
-    def _unpack(self, program, caches):
-        self.kv_read[program] = caches[0].kv_read
-        return [(c.k._data, c.v._data) for c in caches]
+    def _unpack(self, program, pools, caches, slot=None):
+        self.kv_read[program] = next(
+            (c.kv_read for c in caches if hasattr(c, 'block_tables')), None)
+        return layer_state(pools, caches, slot)
 
     def _prefill_fn(self, params, bufs, pools, bt1, len1, ids, valid,
-                    key, temp, topk, sample):
+                    key, temp, topk, sample, slot=None):
         """One [1, C] prompt chunk through block-table row `bt1` at
-        offset len1. Same contract as the slot prefill: only `valid`
-        tokens are real, padded-tail writes are garbage the next pass
-        overwrites, and the returned pick matters on the final chunk."""
+        offset len1. Same contract as the slot prefill for K/V: only
+        `valid` tokens are real, padded-tail writes are garbage the next
+        pass overwrites, and the returned pick matters on the final
+        chunk. A recurrent layer has no dead rows: it works on row
+        `slot` of its state (passed when the model has such a layer),
+        starts from zeros when len1 is 0 and takes `valid` tokens."""
         self.trace_counts['prefill'] += 1
-        caches = self._caches(pools, bt1, len1)
+        caches = layer_caches(self._specs, pools, bt1, len1,
+                              jnp.reshape(valid, (1,)), slot)
         (lg, new_cs), _ = _fm.functional_call(
             self._model, params, bufs, args=(Tensor(ids),),
             kwargs={'caches': caches}, training=False)
@@ -266,31 +303,33 @@ class PagedContinuousBatchingEngine(_EngineBase):
                                             keepdims=False)
         key2, sub = jax.random.split(key)
         tok = _pick_token(last, sub, temp, topk, sample)
-        return self._unpack('prefill', new_cs), tok, key2
+        return self._unpack('prefill', pools, new_cs, slot), tok, key2
 
     def _decode_fn(self, params, bufs, pools, bt, lens, tok, gen,
                    budgets, active, keys, temps, topks, sample):
         """K cached decode steps for all rows — the slot engine's burst
         with lengths carried through the scan instead of living inside
-        the cache pytree (block tables are per-dispatch constants)."""
+        the cache pytree (block tables are per-dispatch constants). A
+        lane that is frozen or past its budget writes K/V garbage where
+        nobody reads and keeps its recurrent state bit for bit."""
         self.trace_counts['decode'] += 1
 
         def body(carry, _):
             pools, lens, tok, gen, keys = carry
             step_active = active & (gen < budgets)
-            caches = self._caches(pools, bt, lens)
+            inc = step_active.astype(jnp.int32)
+            caches = layer_caches(self._specs, pools, bt, lens, inc)
             (lg, new_cs), _ = _fm.functional_call(
                 self._model, params, bufs, args=(Tensor(tok),),
                 kwargs={'caches': caches}, training=False)
-            inc = step_active.astype(jnp.int32)
             ks = jax.vmap(jax.random.split)(keys)
             subs = ks[:, 1]
             keys2 = jnp.where(step_active[:, None], ks[:, 0], keys)
             nxt = jax.vmap(_pick_token)(lg[:, -1], subs, temps, topks,
                                         sample)
             tok2 = jnp.where(step_active, nxt, tok[:, 0])[:, None]
-            return ((self._unpack('decode', new_cs), lens + inc, tok2,
-                     gen + inc, keys2), (tok2[:, 0], step_active))
+            return ((self._unpack('decode', pools, new_cs), lens + inc,
+                     tok2, gen + inc, keys2), (tok2[:, 0], step_active))
 
         carry, (toks, actives) = jax.lax.scan(
             body, (pools, lens, tok, gen, keys), None,
@@ -307,18 +346,22 @@ class PagedContinuousBatchingEngine(_EngineBase):
         lens+K; rows past what acceptance advances are garbage the next
         pass overwrites (or scratch-mapped, past the reservation)."""
         self.trace_counts['verify'] += 1
-        caches = self._caches(pools, bt, lens)
+        # (no `valid`: a model with a recurrent layer never gets here)
+        caches = layer_caches(self._specs, pools, bt, lens, None)
         (lg, new_cs), _ = _fm.functional_call(
             self._model, params, bufs, args=(Tensor(toks),),
             kwargs={'caches': caches}, training=False)
         picks = jnp.argmax(lg.astype(jnp.float32), axis=-1).astype(
             jnp.int32)
-        return self._unpack('verify', new_cs), picks
+        return self._unpack('verify', pools, new_cs), picks
 
     # ---- per-step dispatches (lock held) ------------------------------
 
     def _prefill_call(self, req, start, ids, valid):
         slot = req.slot
+        # the program learns its slot only where a layer's state lives
+        # per slot; a model of K/V rows alone is addressed by `bt1`
+        where = (np.int32(slot),) if self._state_seq_bytes else ()
         self._pools, tok, key2 = self._prefill_jit(
             self._params, self._bufs, self._pools,
             self.scheduler.block_tables[slot:slot + 1],
@@ -326,7 +369,7 @@ class PagedContinuousBatchingEngine(_EngineBase):
             np.asarray(ids, np.int32)[None, :],
             np.int32(valid), req._key,
             np.float32(req.temperature), np.int32(req.top_k),
-            np.asarray(req.do_sample))
+            np.asarray(req.do_sample), *where)
         self._lens[slot] = start + valid
         return tok, key2
 

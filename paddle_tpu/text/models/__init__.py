@@ -3,3 +3,5 @@ from .bert import (BertModel, BertForSequenceClassification,  # noqa: F401
                    ErnieForSequenceClassification, ErnieForPretraining,
                    ernie_1_0)
 from .gpt import GPTModel, GPTForCausalLM, GPTConfig  # noqa: F401
+from .olmo_hybrid import (OlmoHybridConfig, OlmoHybridModel,  # noqa: F401
+                          OlmoHybridForCausalLM)

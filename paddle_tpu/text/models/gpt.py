@@ -14,6 +14,9 @@ from ... import nn
 from ...framework.core import Tensor, no_grad_guard
 from ...nn import functional as F
 from ...tensor import manipulation as M
+from .cache import PagedKVCache as GPTPagedCache
+from .cache import (PagedKVSpec, _raw_leaf, _tensor_leaf, paged_attention,
+                    paged_kv_read)
 
 __all__ = ['GPTConfig', 'GPTModel', 'GPTForCausalLM']
 
@@ -112,17 +115,6 @@ def _cache_flatten(c):
     return (_raw_leaf(c.k), _raw_leaf(c.v), c.length), c.fresh
 
 
-def _tensor_leaf(x):
-    # flatten/unflatten must round-trip jax's internal placeholder
-    # leaves (e.g. ArgInfo during lower()/AOT) untouched; only real
-    # arrays and tracers get the Tensor wrapper back
-    return Tensor(x) if isinstance(x, jnp.ndarray) else x
-
-
-def _raw_leaf(x):
-    return getattr(x, '_data', x)
-
-
 def _cache_unflatten(fresh, children):
     k, v, length = children
     return GPTStaticCache(_tensor_leaf(k), _tensor_leaf(v), length,
@@ -180,91 +172,6 @@ def _slot_cache_unflatten(_, children):
 
 jax.tree_util.register_pytree_node(GPTSlotCache, _slot_cache_flatten,
                                    _slot_cache_unflatten)
-
-
-class GPTPagedCache:
-    """Block/page-granular KV cache for the paged serving engine
-    (paddle_tpu/serving/paged_engine.py): per layer, a physical pool of
-    `[num_pages, page_size, H, Dh]` K/V pages plus a per-sequence
-    BLOCK TABLE `[B, max_blocks]` (int32 page ids) and per-sequence
-    valid lengths `[B]`. A sequence's logical row j lives in pool row
-    `block_tables[s, j // page_size] * page_size + j % page_size`, so
-    sequences of different lengths occupy only the pages they need and
-    several sequences may map leading blocks to the SAME physical page
-    (prefix sharing).
-
-    Invariants (owned by the serving engine / PagedScheduler):
-      - block-table entry 0 is the reserved SCRATCH page: never handed
-        to a real block, so garbage writes from frozen/retired rows land
-        there (or on the row's own dead rows past its length) and are
-        unreachable — shared pages are only ever FULL, immutable blocks
-        strictly below every writer's length, so no real write can touch
-        them;
-      - like GPTSlotCache, attention writes this step's K/V at each
-        row's current length but does NOT advance `lengths`; the engine
-        advances them host-side after the full forward;
-      - pool rows at/beyond a sequence's length are garbage and never
-        attended (the validity mask allows logical positions <= the
-        query's absolute position only);
-      - capacity/ownership is guarded host-side at admission: a traced
-        block table cannot be range-checked in-program (writes are
-        clipped to the pool as a memory-safety net; a clipped write is
-        by construction a garbage write).
-    """
-
-    def __init__(self, k_pool, v_pool, block_tables, lengths):
-        self.k = k_pool          # [num_pages, page_size, H, Dh]
-        self.v = v_pool
-        self.block_tables = block_tables  # [B, max_blocks] int32
-        self.lengths = lengths            # [B] int32 (traced under jit)
-        # set by attention on the cache it RETURNS, at trace time: which
-        # read it took ('pool' | 'gather', see `paged_kv_read`). Not a
-        # pytree leaf: a cache rebuilt from leaves has forgotten it.
-        self.kv_read = None
-
-    @staticmethod
-    def empty(num_pages, page_size, max_blocks, batch, num_heads,
-              head_dim, dtype='float32'):
-        import paddle_tpu as paddle
-        k = paddle.zeros([num_pages, page_size, num_heads, head_dim], dtype)
-        v = paddle.zeros([num_pages, page_size, num_heads, head_dim], dtype)
-        return GPTPagedCache(k, v,
-                             jnp.zeros((batch, max_blocks), jnp.int32),
-                             jnp.zeros((batch,), jnp.int32))
-
-
-def _paged_cache_flatten(c):
-    return (_raw_leaf(c.k), _raw_leaf(c.v), c.block_tables, c.lengths), None
-
-
-def _paged_cache_unflatten(_, children):
-    k, v, bt, lengths = children
-    return GPTPagedCache(_tensor_leaf(k), _tensor_leaf(v), bt, lengths)
-
-
-jax.tree_util.register_pytree_node(GPTPagedCache, _paged_cache_flatten,
-                                   _paged_cache_unflatten)
-
-
-def paged_kv_read(batch, capacity, pool_rows):
-    """Which read the `GPTPagedCache` branch of attention takes, from the
-    shapes alone: 'pool' attends over every pool row in place and masks
-    what a row does not hold; 'gather' first materializes each row's
-    `[capacity]` logical view. The pool is the smaller read once the
-    views together (`batch * capacity` token rows, K and V, per layer)
-    are at least the pool — a decode or verify batch; a one-row prefill
-    chunk keeps the gather (its scores would span the whole pool)."""
-    return 'pool' if batch * capacity >= pool_rows else 'gather'
-
-
-def _pool_attention(q, kf, vf, mask):
-    """q `[B, n, H, Dh]` against ALL pool rows kf / vf `[R, H, Dh]` under
-    an additive mask `[B, 1, n, R]`: `_sdpa_ref`'s arithmetic (products
-    in the operands' dtype, float32 softmax) with no batch axis on the
-    keys, so nothing of the pool is copied per row."""
-    s = jnp.einsum('bqhd,khd->bhqk', q, kf) * (1.0 / q.shape[-1] ** 0.5)
-    p = jax.nn.softmax((s + mask).astype(jnp.float32), axis=-1)
-    return jnp.einsum('bhqk,khd->bqhd', p.astype(q.dtype), vf)
 
 
 def _cache_get(cache, key, build, cap=8):
@@ -325,91 +232,15 @@ class GPTAttention(nn.Layer):
                 k = qkv[:, :, 1]
                 v = qkv[:, :, 2]
         if isinstance(cache, GPTPagedCache):
-            import jax
             from ...framework.core import is_grad_enabled
             if self.training and is_grad_enabled():
                 raise RuntimeError(
                     'GPTPagedCache is an inference-only serving path — '
                     'call model.eval() / no_grad')
-            num_pages, page = cache.k.shape[0], cache.k.shape[1]
-            nb = cache.block_tables.shape[1]
-            L = nb * page                       # logical capacity per row
-            t = cache.lengths                   # [B] per-row write offsets
-            bt = cache.block_tables             # [B, nb] physical page ids
-            if not isinstance(t, jax.core.Tracer) and \
-                    int(jnp.max(t)) + n > L:
-                # (under jit lengths are traced; the serving engine guards
-                # capacity at admission instead)
-                raise ValueError(
-                    'paged cache overflow: max row length %d + %d new '
-                    'tokens > capacity %d' % (int(jnp.max(t)), n, L))
-            # write: token i of row s sits at absolute position t[s]+i;
-            # its pool row is bt[s, pos // page] * page + pos % page.
-            # ONE flat scatter covers all rows; clipping keeps garbage
-            # from frozen rows inside the pool (it lands on the scratch
-            # page or the row's own dead rows — both unreachable, see
-            # GPTPagedCache invariants)
-            with _scope('gpt.attn.paged_write'):
-                pos = jnp.clip(t[:, None] + jnp.arange(n)[None, :], 0, L - 1)
-                rows = (jnp.take_along_axis(bt, pos // page, axis=1) * page
-                        + pos % page)                                # [B, n]
-                flat_shape = (num_pages * page,) + tuple(cache.k.shape[2:])
-                kf = cache.k._data.reshape(flat_shape)
-                vf = cache.v._data.reshape(flat_shape)
-                idx = rows.reshape(-1)
-                kf = kf.at[idx].set(k._data.astype(kf.dtype).reshape(
-                    (b * n,) + flat_shape[1:]))
-                vf = vf.at[idx].set(v._data.astype(vf.dtype).reshape(
-                    (b * n,) + flat_shape[1:]))
-            new_cache = GPTPagedCache(
-                Tensor(kf.reshape(cache.k._data.shape)),
-                Tensor(vf.reshape(cache.v._data.shape)), bt, t)
-            # read, by shape at trace time (`paged_kv_read`): the pool's
-            # rows where they lie when every row's logical view together
-            # would be at least the pool, else the gathered view. Same
-            # arithmetic either way; keys only come in another order.
-            read = new_cache.kv_read = paged_kv_read(b, L, num_pages * page)
-            if read == 'pool':
-                # a pool row (p, r) is logical position j*page + r of the
-                # row whose FIRST block-table entry holding p is j (nb:
-                # none, past every query). A shared page is visible to
-                # each holder; scratch page 0 fills every unused entry,
-                # so its first j lies past the row's length, and an idle
-                # row (t = 0, all scratch) sees position 0 as below.
-                with _scope('gpt.attn.mask'):
-                    qpos = t[:, None] + jnp.arange(n)[None, :]       # [B, n]
-                    holds = bt[:, :, None] == jnp.arange(num_pages)
-                    first = jnp.min(jnp.where(
-                        holds, jnp.arange(nb)[None, :, None], nb), axis=1)
-                    kpos = (first[:, :, None] * page
-                            + jnp.arange(page)).reshape(b, num_pages * page)
-                    allow = qpos[:, :, None] >= kpos[:, None, :]
-                    mask = jnp.where(allow, 0.0, -1e9)[:, None].astype(
-                        jnp.float32)                   # [B, 1, n, pool rows]
-                with _scope('gpt.attn.core'):
-                    out = Tensor(_pool_attention(q._data, kf, vf, mask))
-                return self._out(out, b, n), new_cache
-            # gather each row's logical [L] view through its block table
-            # (this step's rows included — written above), then the same
-            # masked attention as the slot path. The gather materializes
-            # [B, L, H, Dh] activations; persistent memory stays
-            # page-granular, which is where the density win lives.
-            with _scope('gpt.attn.paged_gather'):
-                view = (bt[:, :, None] * page
-                        + jnp.arange(page)[None, None, :]).reshape(b, L)
-                kg = jnp.take(kf, view, axis=0)                # [B, L, H, Dh]
-                vg = jnp.take(vf, view, axis=0)
-            # per-row validity mask: query row i of sequence s sits at
-            # absolute position t[s]+i and sees logical positions <= it
-            with _scope('gpt.attn.mask'):
-                qpos = t[:, None] + jnp.arange(n)[None, :]           # [B, n]
-                allow = qpos[:, :, None] >= jnp.arange(L)[None, None, :]
-                mask = Tensor(jnp.where(allow, 0.0, -1e9)[:, None].astype(
-                    jnp.float32))                                # [B,1,n,L]
-            with _scope('gpt.attn.core'):
-                out = F.scaled_dot_product_attention(
-                    q, Tensor(kg), Tensor(vg), attn_mask=mask,
-                    is_causal=False, dropout_p=0.0)
+            # the paged write and read are every model's (cache.py); the
+            # read is chosen through this module's `paged_kv_read`
+            out, new_cache = paged_attention(q, k, v, cache, 'gpt.attn',
+                                             paged_kv_read)
             return self._out(out, b, n), new_cache
         if isinstance(cache, GPTSlotCache):
             import jax
@@ -658,6 +489,15 @@ class GPTForCausalLM(nn.Layer):
         else:
             self.lm_head = nn.Linear(config.hidden_size, config.vocab_size,
                                      bias_attr=False)
+
+    def cache_specs(self):
+        """What each layer keeps while it serves (text/models/cache.py):
+        K and V rows of every head, in the token embedding's dtype."""
+        config = self.config
+        dtype = str(self.gpt.wte.weight.dtype).replace('paddle.', '')
+        return [PagedKVSpec(config.num_heads,
+                            config.hidden_size // config.num_heads, dtype)
+                for _ in self.gpt.h]
 
     def forward(self, input_ids, position_ids=None, caches=None):
         if caches is not None:

@@ -7,12 +7,16 @@ Two K/V layouts share this host module:
   [num_slots, max_len, H, Dh] buffers plus a per-slot valid-length
   vector — every slot reserves `max_len` rows. `SlotAllocator` owns
   which slots are free and who holds them.
-- `PagedKVCache` (text/models/cache.py): per layer, a pool of
-  [num_pages, page_size, H, Dh] pages addressed through per-sequence
-  block tables — a sequence only holds the pages it needs, and
-  sequences sharing a prompt prefix map their leading block-table
-  entries to the SAME physical page. `PageAllocator` (refcounted free
-  list) and `PrefixCache` (block-hash -> page, LRU) own the host side.
+- `PagedKVCache` (text/models/cache.py): per layer, a K pool and a V
+  pool of `num_pages * page_size` token rows, `[G, rows, W]` — a row is
+  `W` lanes wide (one head of 128, or the narrower heads that fill 128
+  side by side), the `G` groups outermost: the one layout the write and
+  the read both take as it lies, so no program copies a pool — addressed
+  through per-sequence block tables: a sequence only holds the pages it
+  needs, and sequences sharing a prompt prefix map their leading
+  block-table entries to the SAME physical page. `PageAllocator`
+  (refcounted free list) and `PrefixCache` (block-hash -> page, LRU) own
+  the host side.
 
 Beside pages, the paged engine keeps a second kind of state for a layer
 that names it (`RecurrentSpec`): `[num_seqs, ...]` arrays that belong to
@@ -372,15 +376,18 @@ def state_bytes_per_seq(specs):
 
 def build_paged_pools(model, num_pages, page_size, num_seqs=0):
     """The paged engine's persistent device state, one entry per layer:
-    a (k_pool, v_pool) pair `[num_pages, page_size, H, Dh]` for a layer
-    that keeps K/V rows, a tuple of `[num_seqs, ...]` arrays for a
-    recurrent one. Block tables / lengths stay host-side (the engine
-    passes them per dispatch)."""
+    a (k_pool, v_pool) pair `[G, num_pages * page_size, W]` (pool row
+    `page * page_size + r`; `cache.paged_pool_shape`) for a layer that
+    keeps K/V rows, a tuple of `[num_seqs, ...]` arrays for a recurrent
+    one. Block tables / lengths stay host-side (the engine passes them
+    per dispatch)."""
     import jax.numpy as jnp
+    from ..text.models.cache import paged_pool_shape
     state = []
     for spec in cache_specs(model):
         if _is_paged(spec):
-            shape = (num_pages, page_size, spec.num_heads, spec.head_dim)
+            shape = paged_pool_shape(spec.num_heads, spec.head_dim,
+                                     num_pages, page_size)
             state.append((jnp.zeros(shape, spec.dtype),
                           jnp.zeros(shape, spec.dtype)))
         else:
@@ -389,12 +396,14 @@ def build_paged_pools(model, num_pages, page_size, num_seqs=0):
     return state
 
 
-def layer_caches(specs, state, block_tables, lengths, valid, slot=None):
+def layer_caches(specs, state, block_tables, lengths, valid, page_size,
+                 slot=None):
     """One cache object per layer for one dispatch over `state`.
     `lengths` / `valid` `[B]`: tokens each row holds before the call and
-    how many of the call's tokens are real for it. With `slot` (a traced
-    scalar: the one-row prefill program) a recurrent layer's arrays are
-    that sequence's row alone."""
+    how many of the call's tokens are real for it; `page_size`: rows a
+    page of the K/V pools holds (their shape does not say). With `slot`
+    (a traced scalar: the one-row prefill program) a recurrent layer's
+    arrays are that sequence's row alone."""
     import jax
     from ..framework.core import Tensor
     from ..text.models.cache import PagedKVCache, RecurrentCache
@@ -403,7 +412,7 @@ def layer_caches(specs, state, block_tables, lengths, valid, slot=None):
         if _is_paged(spec):
             k, v = arrays
             caches.append(PagedKVCache(Tensor(k), Tensor(v), block_tables,
-                                       lengths))
+                                       lengths, page_size))
             continue
         if slot is not None:
             arrays = [jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=0)

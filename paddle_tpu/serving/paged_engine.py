@@ -295,7 +295,8 @@ class PagedContinuousBatchingEngine(_EngineBase):
         starts from zeros when len1 is 0 and takes `valid` tokens."""
         self.trace_counts['prefill'] += 1
         caches = layer_caches(self._specs, pools, bt1, len1,
-                              jnp.reshape(valid, (1,)), slot)
+                              jnp.reshape(valid, (1,)), self.page_size,
+                              slot)
         (lg, new_cs), _ = _fm.functional_call(
             self._model, params, bufs, args=(Tensor(ids),),
             kwargs={'caches': caches}, training=False)
@@ -318,7 +319,8 @@ class PagedContinuousBatchingEngine(_EngineBase):
             pools, lens, tok, gen, keys = carry
             step_active = active & (gen < budgets)
             inc = step_active.astype(jnp.int32)
-            caches = layer_caches(self._specs, pools, bt, lens, inc)
+            caches = layer_caches(self._specs, pools, bt, lens, inc,
+                                  self.page_size)
             (lg, new_cs), _ = _fm.functional_call(
                 self._model, params, bufs, args=(Tensor(tok),),
                 kwargs={'caches': caches}, training=False)
@@ -347,7 +349,8 @@ class PagedContinuousBatchingEngine(_EngineBase):
         pass overwrites (or scratch-mapped, past the reservation)."""
         self.trace_counts['verify'] += 1
         # (no `valid`: a model with a recurrent layer never gets here)
-        caches = layer_caches(self._specs, pools, bt, lens, None)
+        caches = layer_caches(self._specs, pools, bt, lens, None,
+                              self.page_size)
         (lg, new_cs), _ = _fm.functional_call(
             self._model, params, bufs, args=(Tensor(toks),),
             kwargs={'caches': caches}, training=False)

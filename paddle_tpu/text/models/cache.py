@@ -18,6 +18,7 @@ bit for bit where `valid` is 0 and take nothing from the positions at or
 past it; `lengths == 0` means the row starts a sequence, from zeros.
 """
 import collections
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +26,7 @@ import jax.numpy as jnp
 from ...framework.core import Tensor
 
 __all__ = ['PagedKVSpec', 'RecurrentSpec', 'PagedKVCache', 'RecurrentCache',
-           'paged_kv_read', 'paged_attention']
+           'paged_pool_shape', 'paged_kv_read', 'paged_attention']
 
 _scope = jax.named_scope
 
@@ -49,14 +50,31 @@ def _raw_leaf(x):
 
 class PagedKVCache:
     """Block/page-granular KV cache for the paged serving engine
-    (paddle_tpu/serving/paged_engine.py): per layer, a physical pool of
-    `[num_pages, page_size, H, Dh]` K/V pages plus a per-sequence
-    BLOCK TABLE `[B, max_blocks]` (int32 page ids) and per-sequence
-    valid lengths `[B]`. A sequence's logical row j lives in pool row
-    `block_tables[s, j // page_size] * page_size + j % page_size`, so
-    sequences of different lengths occupy only the pages they need and
-    several sequences may map leading blocks to the SAME physical page
-    (prefix sharing).
+    (paddle_tpu/serving/paged_engine.py): per layer, a physical K pool
+    and V pool `[G, num_pages * page_size, W]` (`paged_pool_shape`) plus
+    a per-sequence BLOCK TABLE `[B, max_blocks]` (int32 page ids) and
+    per-sequence valid lengths `[B]`. A sequence's logical row j lives in
+    pool row `block_tables[s, j // page_size] * page_size + j %
+    page_size`, so sequences of different lengths occupy only the pages
+    they need and several sequences may map leading blocks to the SAME
+    physical page (prefix sharing). `page_size` is static (pytree aux
+    data): the pool's shape does not hold it.
+
+    The layout is the one the write and the read both take as it lies.
+    A token's row is `W` lanes wide: one head of 128 or more, or as many
+    narrower heads side by side as fill 128 lanes (GPT-2's 64: two), the
+    `G` groups outermost. A token then lands in `G` contiguous pieces, a
+    page of 16 rows in `G` whole tiles, and a page is a window of the
+    free view `[G, num_pages, page_size, W]`, so XLA:TPU takes and
+    scatters pages of that view, and contracts over the lanes or over the
+    rows, WITHOUT re-laying the pool: it has one layout, the default of
+    its shape, from a program's parameters through its scan to its
+    outputs, is updated in place and never copied. (Rows last, `[H, Dh,
+    rows]`, also keeps one layout, but spreads a token over `H * Dh`
+    separate words: a column write measured 7 us. Heads narrower than the
+    lanes and not packed make the device pick a rows-last layout by
+    itself. `page_size` should be a multiple of the dtype's sublane tile,
+    16 for bfloat16, or the page view is not free.)
 
     Invariants (owned by the serving engine / PagedScheduler):
       - block-table entry 0 is the reserved SCRATCH page: never handed
@@ -72,16 +90,17 @@ class PagedKVCache:
         attended (the validity mask allows logical positions <= the
         query's absolute position only);
       - capacity/ownership is guarded host-side at admission: a traced
-        block table cannot be range-checked in-program (writes are
-        clipped to the pool as a memory-safety net; a clipped write is
-        by construction a garbage write).
+        block table cannot be range-checked in-program (writes past a
+        row's last block go to the scratch page as a memory-safety net;
+        such a write is by construction a garbage write).
     """
 
-    def __init__(self, k_pool, v_pool, block_tables, lengths):
-        self.k = k_pool          # [num_pages, page_size, H, Dh]
+    def __init__(self, k_pool, v_pool, block_tables, lengths, page_size):
+        self.k = k_pool          # [G, num_pages * page_size, W]
         self.v = v_pool
         self.block_tables = block_tables  # [B, max_blocks] int32
         self.lengths = lengths            # [B] int32 (traced under jit)
+        self.page_size = int(page_size)
         # set by attention on the cache it RETURNS, at trace time: which
         # read it took ('pool' | 'gather', see `paged_kv_read`). Not a
         # pytree leaf: a cache rebuilt from leaves has forgotten it.
@@ -91,19 +110,20 @@ class PagedKVCache:
     def empty(num_pages, page_size, max_blocks, batch, num_heads,
               head_dim, dtype='float32'):
         import paddle_tpu as paddle
-        k = paddle.zeros([num_pages, page_size, num_heads, head_dim], dtype)
-        v = paddle.zeros([num_pages, page_size, num_heads, head_dim], dtype)
-        return PagedKVCache(k, v,
+        shape = list(paged_pool_shape(num_heads, head_dim, num_pages,
+                                      page_size))
+        return PagedKVCache(paddle.zeros(shape, dtype),
+                            paddle.zeros(shape, dtype),
                             jnp.zeros((batch, max_blocks), jnp.int32),
-                            jnp.zeros((batch,), jnp.int32))
+                            jnp.zeros((batch,), jnp.int32), page_size)
 
 
 jax.tree_util.register_pytree_node(
     PagedKVCache,
     lambda c: ((_raw_leaf(c.k), _raw_leaf(c.v), c.block_tables, c.lengths),
-               None),
-    lambda _, ch: PagedKVCache(_tensor_leaf(ch[0]), _tensor_leaf(ch[1]),
-                               ch[2], ch[3]))
+               c.page_size),
+    lambda page_size, ch: PagedKVCache(
+        _tensor_leaf(ch[0]), _tensor_leaf(ch[1]), ch[2], ch[3], page_size))
 
 
 class RecurrentCache:
@@ -128,6 +148,17 @@ jax.tree_util.register_pytree_node(
     lambda _, ch: RecurrentCache(*ch))
 
 
+_LANES = 128     # the minor axis of a TPU tile
+
+
+def paged_pool_shape(num_heads, head_dim, num_pages, page_size):
+    """`[G, rows, W]` of one K or V pool (see `PagedKVCache`): heads
+    narrower than the lanes sit side by side in one row of `W` lanes when
+    a whole number of them fills it."""
+    per = _LANES // head_dim if _LANES % head_dim == 0 else 1
+    return (-(-num_heads // per), num_pages * page_size, per * head_dim)
+
+
 def paged_kv_read(batch, capacity, pool_rows):
     """Which read paged attention takes, from the shapes alone: 'pool'
     attends over every pool row in place and masks what a row does not
@@ -139,64 +170,101 @@ def paged_kv_read(batch, capacity, pool_rows):
     return 'pool' if batch * capacity >= pool_rows else 'gather'
 
 
-def _pool_attention(q, kf, vf, mask):
-    """q `[B, n, H, Dh]` against ALL pool rows kf / vf `[R, H, Dh]` under
-    an additive mask `[B, 1, n, R]`: `_sdpa_ref`'s arithmetic (products
-    in the operands' dtype, float32 softmax) with no batch axis on the
-    keys, so nothing of the pool is copied per row."""
-    s = jnp.einsum('bqhd,khd->bhqk', q, kf) * (1.0 / q.shape[-1] ** 0.5)
-    p = jax.nn.softmax((s + mask).astype(jnp.float32), axis=-1)
-    return jnp.einsum('bhqk,khd->bqhd', p.astype(q.dtype), vf)
+def _pack(x, groups, lanes):
+    """`[B, n, H, Dh]` -> `[B, G, n, W]`: heads side by side in a row,
+    the last group filled with zeros."""
+    b, n, h, dh = x.shape
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, groups * (lanes // dh) - h), (0, 0)))
+    return jnp.transpose(x.reshape(b, n, groups, lanes), (0, 2, 1, 3))
 
 
-def paged_attention(q, k, v, cache, scope, choose_read=paged_kv_read):
-    """Write this call's K/V rows `[B, n, H, Dh]` into `cache`'s pools at
-    each row's length and attend q over what the row then holds, causally.
-    Returns (attention output `[B, n, H, Dh]` as a Tensor, the cache with
-    the new pools and `kv_read` set). `scope` prefixes the named scopes
-    of the device ops (`<scope>.paged_write`, `.paged_gather`, `.mask`,
-    `.core`); `choose_read` is `paged_kv_read` unless the caller looks
-    it up elsewhere."""
-    from ...nn import functional as F
-    q, k, v = _raw_leaf(q), _raw_leaf(k), _raw_leaf(v)
-    b, n = q.shape[0], q.shape[1]
-    num_pages, page = cache.k.shape[0], cache.k.shape[1]
-    nb = cache.block_tables.shape[1]
-    L = nb * page                       # logical capacity per row
-    t = cache.lengths                   # [B] per-row write offsets
-    bt = cache.block_tables             # [B, nb] physical page ids
-    if not isinstance(t, jax.core.Tracer) and int(jnp.max(t)) + n > L:
-        # (under jit lengths are traced; the serving engine guards
-        # capacity at admission instead)
-        raise ValueError(
-            'paged cache overflow: max row length %d + %d new '
-            'tokens > capacity %d' % (int(jnp.max(t)), n, L))
-    # write: token i of row s sits at absolute position t[s]+i;
-    # its pool row is bt[s, pos // page] * page + pos % page.
-    # ONE flat scatter covers all rows; clipping keeps garbage
-    # from frozen rows inside the pool (it lands on the scratch
-    # page or the row's own dead rows — both unreachable, see
-    # PagedKVCache invariants)
-    ck, cv = _raw_leaf(cache.k), _raw_leaf(cache.v)
+def _write(pools, news, start, bt, page):
+    """Rows `[start[s], start[s] + n)` of each sequence (`news`: K and V,
+    `[B, G, n, W]`) into `pools` through the pages they touch: taken from
+    the pool's page view, filled where a row is the call's, scattered back
+    — whatever the start, on a page boundary (every chunk the scheduler
+    makes) or not; a row of a touched page outside the run keeps what it
+    held. A page past the sequence's last block is the scratch page (entry
+    0 of every table), never the last block, whose rows may be real. One
+    form for a decode step's single rows too: a row a
+    `dynamic_update_slice` keeps the layout as well, but unrolls `B`
+    updates a pool a layer into the program, and the decode step measured
+    a fifth slower with them."""
+    b, groups, n, lanes = news[0].shape
+    nb = bt.shape[1]
+    m = (n + page - 2) // page + 1          # pages a run of n rows touches
+    blk = (start // page)[:, None] + jnp.arange(m)                 # [B, m]
+    ids = jnp.where(blk < nb, jnp.take_along_axis(
+        bt, jnp.minimum(blk, nb - 1), axis=1), 0).reshape(-1)
+    # row c of a sequence's m pages is token c - start % page of the call
+    tok = jnp.arange(m * page)[None, :] - (start % page)[:, None]
+    mine = (tok >= 0) & (tok < n)                           # [B, m*page]
+    # (a page the run does not reach — the last one, from a page boundary
+    # — is not put back: another sequence may be writing the scratch page)
+    put_ids = jnp.where(mine.reshape(b * m, page).any(-1), ids,
+                        pools[0].shape[1] // page)
+    src = jnp.clip(tok, 0, n - 1)[:, None, :, None]
+    out = []
+    for pool, new in zip(pools, news):
+        view = pool.reshape(groups, -1, page, lanes)
+        old = jnp.take(view, ids, axis=1).reshape(groups, b, m * page, lanes)
+        put = jnp.where(mine[:, None, :, None],
+                        jnp.take_along_axis(new, src, axis=2),
+                        jnp.swapaxes(old, 0, 1))         # [B, G, m*page, W]
+        put = jnp.swapaxes(put, 0, 1).reshape(groups, b * m, page, lanes)
+        out.append(view.at[:, put_ids].set(put, mode='drop').reshape(
+            pool.shape))
+    return out
+
+
+def _row_views(pool, bt, page):
+    """Each sequence's logical view `[B, G, capacity, W]` of `pool`: its
+    block table's pages, taken from the page view."""
+    groups, _, lanes = pool.shape
+    view = jnp.take(pool.reshape(groups, -1, page, lanes), bt, axis=1)
+    return jnp.swapaxes(view, 0, 1).reshape(
+        bt.shape[0], groups, bt.shape[1] * page, lanes)
+
+
+def _attention(q, kk, vv, mask, head_dim):
+    """q `[B, G, n, W]` against keys and values in the pool's layout —
+    every pool row `[G, R, W]` (no batch axis: nothing of the pool is
+    copied per sequence) or the gathered views `[B, G, L, W]` — under an
+    additive mask `[B, n, rows]`: `_sdpa_ref`'s arithmetic (products in
+    the operands' dtype, float32 softmax), a head at a time. A head of a
+    group sees its own lanes only: its query is zero on its neighbours'
+    (exact: a product with zero adds nothing), and of the output row it
+    keeps its own."""
+    lanes = q.shape[-1]
+    own = (jnp.arange(lanes) // head_dim
+           == jnp.arange(lanes // head_dim)[:, None])[:, None, :]  # [u,1,W]
+    kv = {3: 'gkl', 4: 'bgkl'}[kk.ndim]     # the pool's rows | the views
+    q = jnp.where(own, q[:, :, None], 0).astype(q.dtype)     # [B,G,u,n,W]
+    s = jnp.einsum('bguql,%s->bguqk' % kv, q, kk) * (1.0 / head_dim ** 0.5)
+    p = jax.nn.softmax((s + mask[:, None, None]).astype(jnp.float32), axis=-1)
+    o = jnp.einsum('bguqk,%s->bguql' % kv, p.astype(q.dtype), vv)
+    return jnp.sum(jnp.where(own, o, 0), axis=2)                # [B,G,n,W]
+
+
+@functools.partial(jax.jit, static_argnames=('page', 'scope', 'read'))
+def _paged_call(q, k, v, ck, cv, bt, t, *, page, scope, read):
+    """`paged_attention` on arrays. A function of its own under `jit`: a
+    program traces and lowers it once however many layers call it (they
+    share one function in the module; XLA inlines it), which is most of
+    what a 48-layer program costs to trace and to hash for the compile
+    cache on a run that compiles nothing."""
+    b, n, heads, head_dim = q.shape
+    groups, pool_rows, lanes = ck.shape
+    nb = bt.shape[1]
+    # write: token i of row s sits at absolute position t[s]+i; its
+    # pool row is bt[s, pos // page] * page + pos % page. Garbage from
+    # frozen rows stays inside the row's own blocks or on the scratch
+    # page — both unreachable, see PagedKVCache invariants
     with _scope(scope + '.paged_write'):
-        pos = jnp.clip(t[:, None] + jnp.arange(n)[None, :], 0, L - 1)
-        rows = (jnp.take_along_axis(bt, pos // page, axis=1) * page
-                + pos % page)                                # [B, n]
-        flat_shape = (num_pages * page,) + tuple(ck.shape[2:])
-        kf = ck.reshape(flat_shape)
-        vf = cv.reshape(flat_shape)
-        idx = rows.reshape(-1)
-        kf = kf.at[idx].set(k.astype(kf.dtype).reshape(
-            (b * n,) + flat_shape[1:]))
-        vf = vf.at[idx].set(v.astype(vf.dtype).reshape(
-            (b * n,) + flat_shape[1:]))
-    new_cache = PagedKVCache(Tensor(kf.reshape(ck.shape)),
-                             Tensor(vf.reshape(cv.shape)), bt, t)
-    # read, by shape at trace time (`paged_kv_read`): the pool's
-    # rows where they lie when every row's logical view together
-    # would be at least the pool, else the gathered view. Same
-    # arithmetic either way; keys only come in another order.
-    read = new_cache.kv_read = choose_read(b, L, num_pages * page)
+        ck, cv = _write((ck, cv), [_pack(x.astype(ck.dtype), groups, lanes)
+                                   for x in (k, v)], t, bt, page)
+    kk, vv = ck, cv
+    qpos = t[:, None] + jnp.arange(n)[None, :]               # [B, n]
     if read == 'pool':
         # a pool row (p, r) is logical position j*page + r of the
         # row whose FIRST block-table entry holding p is j (nb:
@@ -205,37 +273,62 @@ def paged_attention(q, k, v, cache, scope, choose_read=paged_kv_read):
         # so its first j lies past the row's length, and an idle
         # row (t = 0, all scratch) sees position 0 as below.
         with _scope(scope + '.mask'):
-            qpos = t[:, None] + jnp.arange(n)[None, :]       # [B, n]
-            holds = bt[:, :, None] == jnp.arange(num_pages)
+            holds = bt[:, :, None] == jnp.arange(pool_rows // page)
             first = jnp.min(jnp.where(
                 holds, jnp.arange(nb)[None, :, None], nb), axis=1)
             kpos = (first[:, :, None] * page
-                    + jnp.arange(page)).reshape(b, num_pages * page)
-            allow = qpos[:, :, None] >= kpos[:, None, :]
-            mask = jnp.where(allow, 0.0, -1e9)[:, None].astype(
-                jnp.float32)                   # [B, 1, n, pool rows]
-        with _scope(scope + '.core'):
-            out = Tensor(_pool_attention(q, kf, vf, mask))
-        return out, new_cache
-    # gather each row's logical [L] view through its block table
-    # (this step's rows included — written above), then the same
-    # masked attention as the slot path. The gather materializes
-    # [B, L, H, Dh] activations; persistent memory stays
-    # page-granular, which is where the density win lives.
-    with _scope(scope + '.paged_gather'):
-        view = (bt[:, :, None] * page
-                + jnp.arange(page)[None, None, :]).reshape(b, L)
-        kg = jnp.take(kf, view, axis=0)                # [B, L, H, Dh]
-        vg = jnp.take(vf, view, axis=0)
-    # per-row validity mask: query row i of sequence s sits at
-    # absolute position t[s]+i and sees logical positions <= it
+                    + jnp.arange(page)).reshape(b, pool_rows)
+    else:
+        # each row's logical [L] view through its block table (this
+        # step's rows included — written above). The views are
+        # [B, G, L, W] activations; persistent memory stays
+        # page-granular, which is where the density win lives.
+        with _scope(scope + '.paged_gather'):
+            kk, vv = _row_views(ck, bt, page), _row_views(cv, bt, page)
+        kpos = jnp.arange(nb * page)[None, :]
+    # per-row validity mask: query i of row s sits at absolute
+    # position t[s]+i and sees logical positions <= it
     with _scope(scope + '.mask'):
-        qpos = t[:, None] + jnp.arange(n)[None, :]           # [B, n]
-        allow = qpos[:, :, None] >= jnp.arange(L)[None, None, :]
-        mask = Tensor(jnp.where(allow, 0.0, -1e9)[:, None].astype(
-            jnp.float32))                                # [B,1,n,L]
+        allow = qpos[:, :, None] >= kpos[:, None, :]
+        mask = jnp.where(allow, 0.0, -1e9).astype(jnp.float32)
     with _scope(scope + '.core'):
-        out = F.scaled_dot_product_attention(
-            Tensor(q), Tensor(kg), Tensor(vg), attn_mask=mask,
-            is_causal=False, dropout_p=0.0)
-    return out, new_cache
+        out = _attention(_pack(q, groups, lanes), kk, vv, mask, head_dim)
+        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(
+            b, n, -1, head_dim)[:, :, :heads]
+    return out, ck, cv
+
+
+def paged_attention(q, k, v, cache, scope, choose_read=paged_kv_read):
+    """Write this call's K/V rows `[B, n, H, Dh]` into `cache`'s pools at
+    each row's length and attend q over what the row then holds, causally.
+    Returns (attention output `[B, n, H, Dh]` as a Tensor, the cache with
+    the new pools and `kv_read` set). `scope` prefixes the named scopes
+    of the device ops (`<scope>.paged_write`, `.paged_gather`, `.mask`,
+    `.core`). The read is chosen by shape at trace time (`paged_kv_read`;
+    same arithmetic either way, keys only come in another order);
+    `choose_read` is `paged_kv_read` unless the caller looks it up
+    elsewhere."""
+    q, k, v = _raw_leaf(q), _raw_leaf(k), _raw_leaf(v)
+    ck, cv = _raw_leaf(cache.k), _raw_leaf(cache.v)
+    n, heads, head_dim = q.shape[1:]
+    page, pool_rows = cache.page_size, ck.shape[1]
+    want = paged_pool_shape(heads, head_dim, pool_rows // page, page)
+    if ck.shape != want:
+        raise ValueError(
+            'K/V pool %s is not the pool of %d heads of %d (%s: '
+            'paged_pool_shape)' % (ck.shape, heads, head_dim, want))
+    t = cache.lengths                       # [B] per-row write offsets
+    bt = jnp.asarray(cache.block_tables)    # [B, nb] physical page ids
+    L = bt.shape[1] * page
+    if not isinstance(t, jax.core.Tracer) and int(jnp.max(t)) + n > L:
+        # (under jit lengths are traced; the serving engine guards
+        # capacity at admission instead)
+        raise ValueError(
+            'paged cache overflow: max row length %d + %d new '
+            'tokens > capacity %d' % (int(jnp.max(t)), n, L))
+    read = choose_read(q.shape[0], L, pool_rows)
+    out, ck, cv = _paged_call(q, k, v, ck, cv, bt, jnp.asarray(t), page=page,
+                              scope=scope, read=read)
+    new_cache = PagedKVCache(Tensor(ck), Tensor(cv), bt, t, page)
+    new_cache.kv_read = read
+    return Tensor(out), new_cache

@@ -17,8 +17,17 @@ WIDTH with the depth cut to two layers (what the compiler checks per layer
 does not change with depth) and the one serving program that compiles in
 seconds; the 12-layer train step, whose memory is read against the chip's
 16 GB, and the serving programs that sort the vocabulary are marked slow.
+
+The paged programs are also held to the K/V pools' invariant (`_pool_invariant`):
+no program copies or re-lays a pool, and a pool keeps the default layout of
+its shape from the parameters to the outputs — tier-1 for `paged_attention`
+alone at the benchmark's two head shapes and for the verify program, slow for
+the programs that sort and for the decode programs at the page counts the
+configurations want next (1 024 and 3 072), read against the chip's memory.
 """
+import math
 import os
+import re
 
 import numpy as np
 import pytest
@@ -190,6 +199,85 @@ def _engine_programs(layers):
     }
 
 
+def _pool_invariant(text, pool_shape):
+    """What `PagedKVCache` promises of a compiled program: no `copy` or
+    `transpose` gives an array of a pool's element count, every mention of
+    the pool's shape carries ONE minor-to-major order, the default `{2,1,0}`
+    (whatever tiling or memory space follows it), and an asynchronous
+    `copy-start` of a pool only moves it between memory spaces in that
+    layout (XLA's prefetch of the attention's operand into fast memory: the
+    read itself, which it schedules for pools that fit)."""
+    count = math.prod(pool_shape)
+    shape = r'bf16\[%s\]' % ','.join(str(d) for d in pool_shape)
+    op = re.compile(r'= \(?\w+\[([\d,]+)\]\{[^ ]*\}?.* (copy|transpose|copy-start)\(')
+    for line in text.splitlines():
+        m = op.search(line)
+        if not m or math.prod(map(int, m.group(1).split(','))) != count:
+            continue
+        orders = set(re.findall(r'\[[\d,]+\]\{([\d,]+)',
+                                line.split(m.group(2) + '(')[0]))
+        rank = m.group(1).count(',') + 1      # (3, or 4 for the page view)
+        default = ','.join(str(d) for d in reversed(range(rank)))
+        assert m.group(2) == 'copy-start' and orders == {default}, line[:300]
+    orders = set(re.findall(shape + r'\{([\d,]+)', text))
+    assert orders == {'2,1,0'}, orders
+
+
+# GPT-2 XL's and Olmo-Hybrid's full-attention layers at the benchmark's sizes:
+# (heads, head_dim, pages, blocks a row, decode rows, prefill chunk)
+POOLS = {'gpt2-xl': (25, 64, 512, 64, 24, 256),
+         'olmo-hybrid': (30, 128, 2432, 512, 16, 512)}
+
+
+@pytest.mark.parametrize('program', ['decode', 'verify', 'prefill'])
+@pytest.mark.parametrize('widths', sorted(POOLS))
+def test_paged_attention_leaves_the_pools_where_they_lie(chip, on_chip_dispatch,
+                                                         widths, program):
+    """Four layers of `paged_attention` alone — a decode burst's 4-step scan
+    over donated pools, a verify call over 4 drafts, a one-row prefill chunk —
+    compile to programs that never copy a pool (3 - 13 s each)."""
+    from paddle_tpu.framework.core import Tensor
+    from paddle_tpu.text.models import cache as C
+    heads, dh, pages, nb, rows, chunk = POOLS[widths]
+    page, layers = 16, 4
+    b, n = {'decode': (rows, 1), 'verify': (rows, 5),
+            'prefill': (1, chunk)}[program]
+    pool = C.paged_pool_shape(heads, dh, pages, page)
+
+    def forward(pools, bt, lens, x):
+        out = []
+        for ck, cv in pools:
+            cache = C.PagedKVCache(Tensor(ck), Tensor(cv), bt, lens, page)
+            o, new = C.paged_attention(x, x * 0.5, x * 0.25, cache, 'x.attn')
+            x = (x + o._data).astype(x.dtype)
+            out.append((new.k._data, new.v._data))
+        return out, x
+
+    def burst(pools, bt, lens, x):
+        def body(carry, _):
+            pools, lens, x = carry
+            pools, x = forward(pools, bt, lens, x)
+            return (pools, lens + 1, x), x[:, 0, 0, 0]
+        (pools, _, _), ys = jax.lax.scan(body, (pools, lens, x), None,
+                                         length=4)
+        return pools, ys
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    args = ([(sds(pool, jnp.bfloat16),) * 2 for _ in range(layers)],
+            sds((b, nb), jnp.int32), sds((b,), jnp.int32),
+            sds((b, n, heads, dh), jnp.bfloat16))
+    compiled = jax.jit(burst if program == 'decode' else forward,
+                       donate_argnums=(0,)).lower(*args).compile()
+    _pool_invariant(compiled.as_text(), pool)
+    # ... and holds them once: the outputs are the donated inputs, and the
+    # temporaries (scores, a chunk's gathered views) could not hold the
+    # pools a second time
+    m = compiled.memory_analysis()
+    held = 2 * layers * 2 * math.prod(pool)
+    assert m.alias_size_in_bytes >= held
+    assert m.temp_size_in_bytes < held / 2
+
+
 # every program that picks a token sorts the 30528-wide vocabulary row
 # (serving/engine.py _pick_token), and that sort alone takes ~25 s to compile
 # for the chip whatever the depth — so tier-1 compiles the one program
@@ -216,3 +304,75 @@ def test_engine_program_compiles_for_v5e(chip, on_chip_dispatch, name):
     # 8 rows of 256 against a pool of 65 x 16: the decode and verify
     # batches read the pool in place, the one-row chunk gathers its view
     assert ('gpt.attn.paged_gather' in text) == (name == 'paged_prefill')
+    if name.startswith('paged'):
+        from paddle_tpu.text.models.cache import paged_pool_shape
+        _pool_invariant(text, paged_pool_shape(12, 64, 65, 16))
+
+
+def _described(make_model):
+    """A model of bfloat16 parameters that are described, not built."""
+    from paddle_tpu import nn
+    with nn.skip_init():
+        model = make_model()
+    for param in model.parameters():
+        param._data = jax.ShapeDtypeStruct(param._data.shape, jnp.bfloat16)
+    model.eval()
+    return model
+
+
+def _decode_program(eng, pages, chip):
+    """The engine's decode program lowered over pools of `pages` pages,
+    described and not built (the engine itself holds a two-page pool)."""
+    from paddle_tpu.serving.kv_cache import build_paged_pools
+    pools = jax.eval_shape(lambda: build_paged_pools(
+        eng._model, pages, eng.page_size, eng.num_slots))
+    args = (eng._params, eng._bufs, pools, eng.scheduler.block_tables,
+            eng._lens, eng._last, eng._gen, eng._budgets, eng._active,
+            eng._keys, eng._temps, eng._topks, eng._sample)
+    return eng._decode_jit.lower(*_abstract(args, chip)).compile()
+
+
+@pytest.mark.slow
+def test_gpt2_xl_decode_program_fits_1024_pages(chip, on_chip_dispatch):
+    """The page count `gpt2-xl-serve-1chip` asked for (ISSUE 25) and could not
+    have while the decode program held every pool twice, padded 2.56x."""
+    from paddle_tpu.serving import PagedContinuousBatchingEngine
+    from paddle_tpu.text.models.cache import paged_pool_shape
+    model = _described(lambda: GPTForCausalLM(GPTConfig(
+        vocab_size=50257, hidden_size=1600, num_layers=48, num_heads=25,
+        max_position_embeddings=1024, dropout=0.0)))
+    eng = PagedContinuousBatchingEngine(
+        model, num_seqs=24, max_len=1024, page_size=16, num_pages=2,
+        prefill_chunk=256, decode_block=4, donate=True)
+    compiled = _decode_program(eng, 1024, chip)
+    _pool_invariant(compiled.as_text(), paged_pool_shape(25, 64, 1024, 16))
+    assert _hbm_bytes(compiled) < HBM_BYTES - 1.5e9
+
+
+@pytest.mark.slow
+def test_olmo_hybrid_decode_program_fits_3072_pages(chip, on_chip_dispatch):
+    """... and the 3 072 `olmo-hybrid-7b-serve-1chip` asked for: the model at
+    the configuration's own sizes (16 layers, 4 of them with pools)."""
+    import json
+    from paddle_tpu.serving import PagedContinuousBatchingEngine
+    from paddle_tpu.text.models import OlmoHybridConfig, OlmoHybridForCausalLM
+    from paddle_tpu.text.models.cache import paged_pool_shape
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, '..', 'benchmarks', 'configs',
+                           'olmo-hybrid-7b-serve-1chip.json')) as f:
+        cfg = json.load(f)
+    m, e = cfg['model'], cfg['engine']
+    fields = OlmoHybridConfig.__init__.__code__.co_varnames
+    model = _described(lambda: OlmoHybridForCausalLM(OlmoHybridConfig(
+        **{k: v for k, v in m.items() if k in fields})))
+    eng = PagedContinuousBatchingEngine(
+        model, num_seqs=e['num_seqs'], max_len=e['max_len'],
+        page_size=e['page_size'], num_pages=2,
+        prefill_chunk=e['prefill_chunk'], decode_block=e['decode_block'],
+        prefix_cache=False, donate=True)
+    compiled = _decode_program(eng, 3072, chip)
+    _pool_invariant(compiled.as_text(), paged_pool_shape(
+        m['num_attention_heads'], m['hidden_size'] // m['num_attention_heads'],
+        3072, e['page_size']))
+    assert _hbm_bytes(compiled) < HBM_BYTES - 1.5e9
+

@@ -305,7 +305,7 @@ def test_the_model_names_what_each_layer_keeps(served):
         6 * (4 * 8 * 16 + 3 * 128) * 4
     state = kv_cache.build_paged_pools(model, 5, 8, num_seqs=3)
     assert [tuple(a.shape for a in s) for s in state[2:4]] == [
-        ((3, 4, 8, 16), (3, 3, 128)), ((5, 8, 4, 16), (5, 8, 4, 16))]
+        ((3, 4, 8, 16), (3, 3, 128)), ((1, 40, 128), (1, 40, 128))]
 
 
 def test_gpt2_goes_through_the_same_interface():
@@ -383,5 +383,7 @@ def test_spans_gauge_and_scopes_of_the_state(served, traced):
         'proj', 'conv', 'gates', 'core', 'state_write', 'norm_gate', 'out')]
     for scope in scopes:
         assert scope in text, scope
-    assert 'gpt.' not in text
+    # (a jitted helper first traced under another model keeps that file's
+    # NAME in the frame table: only a scope counts)
+    assert not re.search(r'gpt\.(?!py\b)', text)
     assert eng.trace_counts == {'prefill': 1, 'decode': 1, 'verify': 0}

@@ -7,6 +7,7 @@ hashing/eviction, page-aware admission — plus the lifecycle edges:
 allocator double-free strictness, FIFO fairness under sustained full
 occupancy, shutdown semantics, and page-leak-free churn.
 """
+import jax
 import numpy as np
 import pytest
 
@@ -17,6 +18,7 @@ from paddle_tpu.serving import (ContinuousBatchingEngine, NGramProposer,
 from paddle_tpu.serving.kv_cache import (SCRATCH_PAGE, PageAllocator,
                                          PrefixCache)
 from paddle_tpu.serving.scheduler import Request
+from paddle_tpu.text.models import cache as cache_mod
 from paddle_tpu.text.models import gpt
 from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
 
@@ -497,42 +499,151 @@ def test_decode_program_ops_carry_the_scope_names(model):
 # ---- the K/V read: the pool in place, or each row's gathered view ------
 
 
-@pytest.mark.parametrize('n', [1, 3])
+def _slot_view(pool, table, page, dh):
+    """Pool `[G, rows, W]` -> the row's logical `[capacity, H, Dh]` (H:
+    the group's heads side by side, a last group's padding included)."""
+    rows = (table[:, None] * page + np.arange(page)).reshape(-1)
+    return np.transpose(pool[:, rows], (1, 0, 2)).reshape(len(rows), -1, dh)
+
+
+# n = 1 is a decode step, 3 and 5 a verify call over drafts, from lengths
+# that sit on no page boundary
+@pytest.mark.parametrize('n', [1, 3, 5])
 def test_pool_read_agrees_with_the_gathered_view(model, monkeypatch, n):
     """Float32 logits and greedy picks of the two reads over one pool of
-    random rows: a page shared by two rows, scratch entries behind a
-    row's pages, an idle row (all scratch, length 0) and a row that this
-    call fills to capacity. Whatever lies past a row's length, on pages
-    it does not hold or on the scratch page, is garbage to both."""
+    random rows, and of the slot path over the same rows: a page shared
+    by two rows, scratch entries behind a row's pages, an idle row (all
+    scratch, length 0), a frozen lane (a row still in prefill, at a page
+    boundary) and a row that this call fills to capacity. Whatever lies
+    past a row's length, on pages it does not hold or on the scratch
+    page, is garbage to all three."""
     page, nb, num_pages, layers, heads, dh = 8, 4, 12, 2, 4, 16
     rng = np.random.RandomState(5)
-    tables = np.asarray([[1, 2, 0, 0],      # 13 rows, page 1 shared
+    tables = np.asarray([[1, 2, 11, 0],     # 13 rows, page 1 shared
                          [1, 3, 4, 0],      # 17 rows, page 1 shared
                          [0, 0, 0, 0],      # idle
+                         [9, 10, 0, 0],     # frozen in prefill at 8
                          [5, 6, 7, 8]],     # full after this call
                         np.int32)
-    lens = np.asarray([13, 17, 0, nb * page - n], np.int32)
-    ids = rng.randint(0, 211, (4, n)).astype(np.int32)
-    pools = [tuple(rng.randn(num_pages, page, heads, dh).astype(np.float32)
-                   for _ in 'kv') for _ in range(layers)]
-    assert gpt.paged_kv_read(4, nb * page, num_pages * page) == 'pool'
+    lens = np.asarray([13, 17, 0, 8, nb * page - n], np.int32)
+    ids = rng.randint(0, 211, (5, n)).astype(np.int32)
+    shape = cache_mod.paged_pool_shape(heads, dh, num_pages, page)
+    assert shape == (1, num_pages * page, 128)   # 8 heads of 16 fill a row
+    pools = [tuple(rng.randn(*shape).astype(np.float32) for _ in 'kv')
+             for _ in range(layers)]
+    assert gpt.paged_kv_read(5, nb * page, num_pages * page) == 'pool'
 
     def run(read):
         monkeypatch.setattr(gpt, 'paged_kv_read', lambda *shape: read)
         caches = [gpt.GPTPagedCache(paddle.to_tensor(k), paddle.to_tensor(v),
-                                    tables, lens) for k, v in pools]
+                                    tables, lens, page) for k, v in pools]
         logits, new = model(paddle.to_tensor(ids), caches=caches)
         assert [c.kv_read for c in new] == [read] * layers
         return logits.numpy(), [(c.k.numpy(), c.v.numpy()) for c in new]
 
     (pool, pool_kv), (gather, gather_kv) = run('pool'), run('gather')
-    assert pool.dtype == np.float32 and pool.shape == (4, n, 211)
+    assert pool.dtype == np.float32 and pool.shape == (5, n, 211)
     np.testing.assert_allclose(pool, gather, rtol=0, atol=1e-5)
     assert (pool.argmax(-1) == gather.argmax(-1)).all()
     # the write is one code: the first layer's pools come out the same
     # bit for bit, the next one's inputs already differ in the last digit
     np.testing.assert_array_equal(pool_kv[0], gather_kv[0])
     np.testing.assert_allclose(pool_kv[1], gather_kv[1], rtol=0, atol=1e-5)
+    # ... and it wrote rows [len, len + n) of each row's view (the idle
+    # row's on the scratch page) and nothing else
+    k0 = pools[0][0].copy()
+    kept = np.ones(num_pages * page, bool)
+    for s in range(5):
+        for j in range(lens[s], lens[s] + n):
+            kept[tables[s, j // page] * page + j % page] = False
+    np.testing.assert_array_equal(pool_kv[0][0][:, kept], k0[:, kept])
+    live = slice(0, heads * dh)        # (the other lanes of a row are padding)
+    assert not (pool_kv[0][0][:, ~kept][:, page:, live]
+                == k0[:, ~kept][:, page:, live]).any()
+    # the slot path over each row's logical view
+    slots = [gpt.GPTSlotCache(*(paddle.to_tensor(np.stack(
+        [_slot_view(x, tables[s], page, dh)[:, :heads] for s in range(5)]))
+        for x in kv), lens) for kv in pools]
+    slot, _ = model(paddle.to_tensor(ids), caches=slots)
+    for paged in (pool, gather):
+        np.testing.assert_allclose(paged, slot.numpy(), rtol=0, atol=1e-5)
+        assert (paged.argmax(-1) == slot.numpy().argmax(-1)).all()
+
+
+def _attend(q, kview, vview, qpos):
+    """Plain float64 causal attention of q `[n, H, Dh]` over one row's
+    logical view `[L, H, Dh]`."""
+    s = np.einsum('qhd,khd->hqk', q, kview) / np.sqrt(q.shape[-1])
+    s = np.where(qpos[None, :, None] >= np.arange(len(kview)), s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    return np.einsum('hqk,khd->qhd', p / p.sum(-1, keepdims=True), vview)
+
+
+# (heads, head_dim): eight narrow heads a row with room to spare, GPT-2
+# XL's odd number of heads of 64 (two a row, the last row half padding),
+# Olmo-Hybrid's heads of 128 (a row each).
+# (start, n) on pages of 8 with 6 blocks: whole pages from a boundary (what
+# the scheduler makes), from inside a page, a run whose padded tail passes
+# the last block (start 40 and 37, the last block real: the tail goes to
+# the scratch page), a run that is no whole page, and single rows
+@pytest.mark.parametrize('heads,dh', [(4, 16), (3, 64), (2, 128)])
+@pytest.mark.parametrize('start,n', [
+    (0, 16), (16, 16), (13, 16), (3, 8), (40, 16), (37, 16), (5, 6),
+    (21, 1), (47, 1)])
+def test_a_call_writes_its_rows_and_no_other(heads, dh, start, n):
+    """A one-row call's rows `[start, start + n)` land in the row's pages
+    (what runs past its last block on the scratch page), every other pool
+    row stays bit for bit — from an unaligned start too, which no
+    scheduler makes — and the output is plain causal attention over what
+    the row holds, by the gathered view and by the pool."""
+    page, nb, num_pages = 8, 6, 10
+    rng = np.random.RandomState(start * 31 + n)
+    table = np.asarray([[4, 2, 7, 9, 3, 5]], np.int32)
+    lens = np.asarray([start], np.int32)
+    shape = cache_mod.paged_pool_shape(heads, dh, num_pages, page)
+    kp, vp = (rng.randn(*shape).astype(np.float32) for _ in 'kv')
+    q, k, v = (rng.randn(1, n, heads, dh).astype(np.float32)
+               for _ in 'qkv')
+    real = min(n, nb * page - start)    # tokens inside the row's capacity
+    lanes = heads * dh                  # live lanes of a token's rows
+    row_of = lambda x: np.pad(x.reshape(-1), (0, shape[0] * shape[2] - lanes)
+                              ).reshape(shape[0], shape[2])
+    want_k, want_v = kp.copy(), vp.copy()
+    touched = np.zeros(num_pages * page, bool)
+    touched[:page] = True                       # scratch: anything goes
+    for i in range(real):
+        r = table[0, (start + i) // page] * page + (start + i) % page
+        want_k[:, r], want_v[:, r] = row_of(k[0, i]), row_of(v[0, i])
+        touched[r] = True
+    view = lambda pool: _slot_view(pool, table[0], page, dh)[
+        :, :heads].astype(np.float64)
+    want = _attend(q[0, :real].astype(np.float64), view(want_k),
+                   view(want_v), start + np.arange(real))
+
+    def call(read, lens):
+        c = cache_mod.PagedKVCache(paddle.to_tensor(kp), paddle.to_tensor(vp),
+                                   table, lens, page)
+        out, new = cache_mod.paged_attention(q, k, v, c, 't',
+                                             lambda *shape: read)
+        assert new.kv_read == read
+        return out._data, new.k._data, new.v._data
+
+    for read in ('gather', 'pool'):
+        if real < n:
+            # (lengths the host can see are range-checked; the engine's
+            # are traced, and the tail must find the scratch page)
+            with pytest.raises(ValueError, match='overflow'):
+                call(read, lens)
+            out, got_k, got_v = map(np.asarray, jax.jit(
+                lambda lens: call(read, lens))(lens))
+        else:
+            out, got_k, got_v = map(np.asarray, call(read, lens))
+        for got, wanted in ((got_k, want_k), (got_v, want_v)):
+            np.testing.assert_array_equal(got[:, page:][:, touched[page:]],
+                                          wanted[:, page:][:, touched[page:]])
+            np.testing.assert_array_equal(got[:, ~touched],
+                                          wanted[:, ~touched])
+        np.testing.assert_allclose(out[0, :real], want, rtol=0, atol=2e-5)
 
 
 @pytest.mark.parametrize('spec_k', [0, 3])

@@ -238,864 +238,18 @@ def _serving_workload(n_req, lens, mnt, mean_gap, vocab, tenants=None):
         output={'dist': 'fixed', 'len': mnt}, tenants=tenants)
 
 
-def _perf_fields(eng, t_cold=None, bursts=None, wall=None):
-    """Perf-introspection fields for a serving bench row: cold/warm
-    compile seconds, post-warmup recompile count, and the cost-model
-    MFU/roofline block over the engine's steady-state program (decode,
-    or the verify forward under speculation)."""
-    out = {}
-    if t_cold is not None:
-        out['compile_s_cold'] = round(t_cold, 3)
-    out['recompiles'] = eng.perf.recompiles
-    try:
-        est = eng.perf_estimate(bursts=bursts, wall_seconds=wall)
-    except Exception:
-        est = None
-    if est:
-        out['compile_s_warm'] = round(est['compile_s_warm'], 3)
-        intensity = est.get('arithmetic_intensity')
-        if intensity is not None and intensity != float('inf'):
-            out['arithmetic_intensity'] = round(intensity, 2)
-        out['roofline_bound'] = est['roofline_bound']
-        if 'mfu_est' in est:
-            out['mfu_est'] = round(est['mfu_est'], 4)
-    try:
-        from paddle_tpu.framework import compile_cache
-        hr = compile_cache.hit_rate()
-        if hr is not None:
-            out['compile_cache_hit_rate'] = round(hr, 4)
-    except Exception:
-        pass
-    return out
-
-
-def _drive_cb(engine, prompts, arrivals, mnt):
-    """Feed the engine its arrival trace in real time and drain it."""
-    from paddle_tpu.serving.metrics import ServingMetrics
-    engine.metrics = ServingMetrics()     # drop warmup samples
-    reqs = []
-    i = 0
-    t0 = time.time()
-    while i < len(prompts) or engine.scheduler.pending:
-        now = time.time() - t0
-        while i < len(prompts) and arrivals[i] <= now:
-            reqs.append(engine.add_request(prompts[i], max_new_tokens=mnt))
-            i += 1
-        if engine.scheduler.pending:
-            engine.step()
-        elif i < len(prompts):
-            time.sleep(min(arrivals[i] - now, 0.01))
-    dt = time.time() - t0
-    toks = sum(len(r.tokens) for r in reqs)
-    return toks / dt, engine.metrics.report()
-
-
-def _drive_sequential(model, prompts, arrivals, mnt):
-    """Baseline: one generate() per request, strictly in arrival order
-    (the pre-continuous-batching serving shape: each request owns the
-    model until it finishes)."""
-    import paddle_tpu as paddle
-    lat = []
-    t0 = time.time()
-    for p, arr in zip(prompts, arrivals):
-        now = time.time() - t0
-        if now < arr:
-            time.sleep(arr - now)
-        s0 = time.time()
-        _ = model.generate(paddle.to_tensor([p]),
-                           max_new_tokens=mnt).numpy()
-        lat.append(time.time() - s0)
-    dt = time.time() - t0
-    return len(prompts) * mnt / dt, statistics.median(lat)
-
-
-def bench_serving(on_tpu):
-    """Continuous-batching serving rung: tok/s, p50/p99 per-token
-    latency and slot occupancy vs the sequential generate() baseline
-    under a Poisson arrival trace, plus the tok/s-vs-slot-count
-    saturation curve (8/16/32) and an int8 weight-only variant.
-
-    The headline comparison is throughput under load: sequential serving
-    runs [1, hidden] decode GEMMs while requests queue; the engine keeps
-    the same GEMMs at slot-count batch. Same prompts, same trace, same
-    greedy sampling — and the engine's greedy tokens are asserted
-    identical to generate()'s in tests/test_serving.py, so the speedup
-    is not bought with drift.
-    """
-    import paddle_tpu as paddle
-    from paddle_tpu.serving import ContinuousBatchingEngine
-    from paddle_tpu.slim import quantize_weight_only, streamed_bytes
-    from paddle_tpu.text.models import GPTConfig, GPTForCausalLM
-
-    paddle.seed(0)
-    if on_tpu:
-        cfg = GPTConfig(vocab_size=30528, hidden_size=768, num_layers=12,
-                        num_heads=12, max_position_embeddings=1024,
-                        dropout=0.0)
-        lens, mnt, n_req = (32, 64, 96, 128), 64, 32
-        max_len, chunk, block = 256, 32, 8
-        slot_curve, mean_gap = (8, 16, 32), 0.02
-    else:
-        # big enough that decode GEMMs outweigh host dispatch (a
-        # hidden-64 toy is dispatch-bound and hides the batching win),
-        # arrival rate high enough that serving is service-bound — the
-        # regime continuous batching exists for
-        cfg = GPTConfig(vocab_size=512, hidden_size=256, num_layers=4,
-                        num_heads=4, max_position_embeddings=128,
-                        dropout=0.0)
-        lens, mnt, n_req = (8, 16, 24, 32), 32, 24
-        max_len, chunk, block = 64, 32, 8
-        slot_curve, mean_gap = (8, 16, 32), 0.002
-    model = GPTForCausalLM(cfg)
-    if on_tpu:
-        model.bfloat16()
-    model.eval()
-    spec = _serving_workload(n_req, lens, mnt, mean_gap, cfg.vocab_size)
-    trace = spec.generate()
-    prompts = trace.prompts()
-    arrivals = trace.arrivals()
-    rows = []
-
-    def run_variant(tag, extra):
-        # sequential baseline: compile every (prompt_len, mnt) signature
-        # before timing — serving steady state, not cold-start
-        for n0 in lens:
-            _ = model.generate(paddle.to_tensor([[0] * n0]),
-                               max_new_tokens=mnt).numpy()
-        seq_tps, seq_lat = _drive_sequential(model, prompts, arrivals, mnt)
-        for num_slots in slot_curve:
-            eng = ContinuousBatchingEngine(
-                model, num_slots=num_slots, max_len=max_len,
-                prefill_chunk=chunk, decode_block=block)
-            t0c = time.time()
-            eng.generate(prompts[:2], max_new_tokens=2)     # compile
-            t_cold = time.time() - t0c
-            b0 = eng.timeline.steps
-            w0 = time.time()
-            if num_slots == slot_curve[0]:
-                # headline point: the real-time Poisson trace
-                tps, rep = _drive_cb(eng, prompts, arrivals, mnt)
-                row = {'metric': 'serving_cb_tokens_per_sec' + tag,
-                       'value': round(tps, 2), 'unit': 'tokens/sec',
-                       'num_slots': num_slots,
-                       'latency_p50_ms': round(rep['latency_p50_ms'], 3),
-                       'latency_p99_ms': round(rep['latency_p99_ms'], 3),
-                       'occupancy_mean': round(rep['occupancy_mean'], 3),
-                       'sequential_tokens_per_sec': round(seq_tps, 2),
-                       'sequential_latency_median_s': round(seq_lat, 4),
-                       'speedup_vs_sequential': round(tps / seq_tps, 2),
-                       'trace': 'poisson', 'mean_gap_s': mean_gap,
-                       'requests': n_req, 'new_tokens': mnt,
-                       'workload_spec': spec.hash,
-                       'traces': eng.compiled_sizes(),
-                       'degraded': not on_tpu}
-            else:
-                # saturation curve: everything queued at t=0
-                tps, rep = _drive_cb(eng, prompts, [0.0] * n_req, mnt)
-                row = {'metric': 'serving_cb_tokens_per_sec' + tag,
-                       'value': round(tps, 2), 'unit': 'tokens/sec',
-                       'num_slots': num_slots,
-                       'occupancy_mean': round(rep['occupancy_mean'], 3),
-                       'trace': 'burst', 'requests': n_req,
-                       'new_tokens': mnt, 'workload_spec': spec.hash,
-                       'degraded': not on_tpu}
-            row.update(_perf_fields(eng, t_cold,
-                                    eng.timeline.steps - b0,
-                                    time.time() - w0))
-            row.update(extra)
-            rows.append(row)
-
-    run_variant('', {'stream_bytes': streamed_bytes(model)})
-    try:
-        quantize_weight_only(model)
-        # quantization invalidates generate()'s compiled caches (the
-        # buffer pytree changed shape); they re-key automatically
-        run_variant('_int8w', {'stream_bytes': streamed_bytes(model)})
-    except Exception as e:
-        rows.append({'metric': 'serving_cb_tokens_per_sec_int8w',
-                     'error': repr(e)[:300]})
-    return rows
-
-
-def _drive_paged(engine, prompts, arrivals, mnt):
-    """_drive_cb plus the paged engine's capacity counters: returns
-    (tok/s, report, peak pages in use across steps)."""
-    from paddle_tpu.serving.metrics import ServingMetrics
-    engine.metrics = ServingMetrics()     # drop warmup samples
-    reqs, peak = [], 0
-    i = 0
-    t0 = time.time()
-    while i < len(prompts) or engine.scheduler.pending:
-        now = time.time() - t0
-        while i < len(prompts) and arrivals[i] <= now:
-            reqs.append(engine.add_request(prompts[i], max_new_tokens=mnt))
-            i += 1
-        if engine.scheduler.pending:
-            engine.step()
-            peak = max(peak, engine.pages.in_use)
-        elif i < len(prompts):
-            time.sleep(min(arrivals[i] - now, 0.01))
-    dt = time.time() - t0
-    toks = sum(len(r.tokens) for r in reqs)
-    return toks / dt, engine.metrics.report(), peak
-
-
-def bench_serving_paged(on_tpu):
-    """Paged-KV serving rung: page-granular KV + prefix sharing + spec
-    decode vs the PR-3 slot engine at the SAME occupancy, on a shared-
-    system-prompt workload (every request opens with the same system
-    prefix, the traffic shape prefix caching exists for).
-
-    Rows (all keyed by workload/page_size/spec_k for the regression
-    gate): the headline paged tok/s row carries the slot engine's tok/s
-    on the identical trace plus prefix hit-rate, prefilled-token count
-    and peak pages-in-use as fields; prefix hit-rate and spec accept-
-    rate also get their own gated rows (both regress DOWN). Greedy
-    parity across all three modes is asserted in tests/test_serving.py,
-    so none of these numbers is bought with output drift.
-    """
-    import paddle_tpu as paddle
-    from paddle_tpu.serving import (ContinuousBatchingEngine,
-                                    PagedContinuousBatchingEngine)
-    from paddle_tpu.text.models import GPTConfig, GPTForCausalLM
-
-    paddle.seed(0)
-    if on_tpu:
-        cfg = GPTConfig(vocab_size=30528, hidden_size=768, num_layers=12,
-                        num_heads=12, max_position_embeddings=1024,
-                        dropout=0.0)
-        sys_len, tail_lens, mnt, n_req = 64, (8, 16, 24, 32), 64, 32
-        max_len, chunk, block, num_seqs, page = 256, 32, 8, 8, 16
-    else:
-        # same regime as bench_serving's CPU branch: decode GEMMs big
-        # enough to outweigh host dispatch, burst arrivals so the run is
-        # service-bound at full occupancy
-        cfg = GPTConfig(vocab_size=512, hidden_size=256, num_layers=4,
-                        num_heads=4, max_position_embeddings=128,
-                        dropout=0.0)
-        sys_len, tail_lens, mnt, n_req = 32, (4, 8, 12, 16), 32, 24
-        max_len, chunk, block, num_seqs, page = 96, 32, 8, 8, 16
-    model = GPTForCausalLM(cfg)
-    if on_tpu:
-        model.bfloat16()
-    model.eval()
-    from paddle_tpu.capacity import workload
-    spec = workload.WorkloadSpec(
-        requests=n_req, seed=0, vocab_size=cfg.vocab_size,
-        arrival={'process': 'burst'},        # everything at t=0
-        lengths={'dist': 'ladder', 'lens': list(tail_lens)},
-        output={'dist': 'fixed', 'len': mnt},
-        prefix={'len': sys_len, 'groups': 1, 'prob': 1.0})
-    trace = spec.generate()
-    prompts = trace.prompts()
-    arrivals = trace.arrivals()              # burst: full occupancy
-    base = {'new_tokens': mnt, 'num_slots': num_seqs, 'page_size': page,
-            'workload': 'shared_prefix', 'trace': 'burst',
-            'workload_spec': spec.hash,
-            'requests': n_req, 'degraded': not on_tpu}
-    rows = []
-
-    # slot engine on the identical trace = the same-occupancy baseline
-    slot = ContinuousBatchingEngine(model, num_slots=num_seqs,
-                                    max_len=max_len, prefill_chunk=chunk,
-                                    decode_block=block)
-    slot.generate(prompts[:2], max_new_tokens=2)             # compile
-    slot_tps, _ = _drive_cb(slot, prompts, arrivals, mnt)
-
-    for spec_k in (0, 4):
-        eng = PagedContinuousBatchingEngine(
-            model, num_seqs=num_seqs, max_len=max_len, page_size=page,
-            prefill_chunk=chunk, decode_block=block, spec_k=spec_k)
-        t0c = time.time()
-        eng.generate(prompts[:2], max_new_tokens=2)          # compile
-        t_cold = time.time() - t0c
-        b0 = eng.timeline.steps
-        w0 = time.time()
-        tps, rep, peak = _drive_paged(eng, prompts, arrivals, mnt)
-        wall = time.time() - w0
-        tag = '_spec' if spec_k else ''
-        rows.append(dict(base, metric='serving_paged_tokens_per_sec' + tag,
-                         value=round(tps, 2), unit='tokens/sec',
-                         spec_k=spec_k,
-                         slot_tokens_per_sec=round(slot_tps, 2),
-                         speedup_vs_slot=round(tps / slot_tps, 3),
-                         prefix_hit_rate=round(rep['prefix_hit_rate'], 3),
-                         prefill_tokens=rep['prefill_tokens'],
-                         pages_in_use_peak=peak,
-                         spec_accept_rate=round(rep['spec_accept_rate'], 3),
-                         occupancy_mean=round(rep['occupancy_mean'], 3),
-                         traces=eng.compiled_sizes(),
-                         **_perf_fields(eng, t_cold,
-                                        eng.timeline.steps - b0, wall)))
-        if not spec_k:
-            rows.append(dict(base, metric='serving_paged_prefix_hit_rate',
-                             value=round(rep['prefix_hit_rate'], 4),
-                             unit='ratio', spec_k=spec_k,
-                             prefill_tokens=rep['prefill_tokens']))
-        else:
-            rows.append(dict(base, metric='serving_paged_spec_accept_rate',
-                             value=round(rep['spec_accept_rate'], 4),
-                             unit='ratio', spec_k=spec_k,
-                             spec_proposed=rep['spec_proposed'],
-                             spec_accepted=rep['spec_accepted']))
-    return rows
-
-
-def bench_serving_gateway(on_tpu):
-    """Multi-replica gateway rung: the Poisson-arrival chaos workload
-    from ISSUE 8 — a 2-replica ServingGateway under the bench_serving
-    arrival trace, measured clean and with one replica killed mid-burst.
-
-    Rows (keyed by replicas/kill_at/policy for the regression gate): the
-    clean gateway tok/s, the chaos-run tok/s (kill at 50% of
-    submissions, failover count as a field), and the chaos completed
-    ratio — the acceptance number, which must stay 1.0: every request
-    finishes even though half the pool died mid-run. Exact-token parity
-    of failed-over requests is asserted in
-    tests/test_serving_gateway.py, so the throughput is not bought with
-    drift or drops.
-    """
-    import paddle_tpu as paddle
-    from paddle_tpu.monitor.registry import MetricRegistry
-    from paddle_tpu.serving import ContinuousBatchingEngine, ServingGateway
-    from paddle_tpu.text.models import GPTConfig, GPTForCausalLM
-
-    paddle.seed(0)
-    if on_tpu:
-        cfg = GPTConfig(vocab_size=30528, hidden_size=768, num_layers=12,
-                        num_heads=12, max_position_embeddings=1024,
-                        dropout=0.0)
-        lens, mnt, n_req = (32, 64, 96, 128), 64, 32
-        max_len, chunk, block, num_slots = 256, 32, 8, 8
-        mean_gap = 0.02
-    else:
-        # same service-bound regime as bench_serving's CPU branch
-        cfg = GPTConfig(vocab_size=512, hidden_size=256, num_layers=4,
-                        num_heads=4, max_position_embeddings=128,
-                        dropout=0.0)
-        lens, mnt, n_req = (8, 16, 24, 32), 32, 24
-        max_len, chunk, block, num_slots = 64, 32, 8, 8
-        mean_gap = 0.002
-    model = GPTForCausalLM(cfg)
-    if on_tpu:
-        model.bfloat16()
-    model.eval()
-    from paddle_tpu.capacity.replay import replay as replay_trace
-    spec = _serving_workload(n_req, lens, mnt, mean_gap, cfg.vocab_size)
-    trace = spec.generate()
-    prompts = trace.prompts()
-    replicas, kill_frac = 2, 0.5
-
-    def factory():
-        return ContinuousBatchingEngine(
-            model, num_slots=num_slots, max_len=max_len,
-            prefill_chunk=chunk, decode_block=block)
-
-    def drive(kill_at):
-        reg = MetricRegistry()
-        gw = ServingGateway(factory, replicas=replicas, registry=reg)
-        t0c = time.time()
-        gw.generate(prompts[:replicas], max_new_tokens=2)     # compile
-        t_cold = time.time() - t0c
-        b0 = sum(r.engine.timeline.steps for r in gw.pool)
-        gw.start()
-        kill_i = None if kill_at is None else int(n_req * kill_at)
-
-        def maybe_kill(i):
-            if kill_i is not None and i == kill_i:
-                gw.kill_replica(1)
-
-        res = replay_trace(gw, trace, max_new_tokens=mnt,
-                                     timeout=600,
-                                     before_submit=maybe_kill)
-        bursts = sum(r.engine.timeline.steps for r in gw.pool) - b0
-        gw.shutdown()
-        failovers = int(reg.get('gateway_failover_total').value())
-        # replica 0 always survives the chaos run: its decode program is
-        # representative, and bursts summed pool-wide make the MFU an
-        # aggregate utilization over the whole gateway
-        perf = _perf_fields(gw.pool[0].engine, t_cold, bursts, res.wall_s)
-        return (res.tokens_per_sec, res.completed_ratio, failovers,
-                gw.report(), perf)
-
-    base = {'unit': 'tokens/sec', 'trace': 'poisson',
-            'mean_gap_s': mean_gap, 'requests': n_req, 'new_tokens': mnt,
-            'num_slots': num_slots, 'replicas': replicas,
-            'policy': 'least_loaded', 'workload_spec': spec.hash,
-            'degraded': not on_tpu}
-    rows = []
-    tps, ratio, fo, rep, perf = drive(None)
-    rows.append(dict(base, metric='serving_gateway_tokens_per_sec',
-                     value=round(tps, 2), kill_at='none', failovers=fo,
-                     completed_ratio=round(ratio, 4), **perf))
-    tps, ratio, fo, rep, perf = drive(kill_frac)
-    rows.append(dict(base, metric='serving_gateway_tokens_per_sec_chaos',
-                     value=round(tps, 2), kill_at=kill_frac, failovers=fo,
-                     completed_ratio=round(ratio, 4),
-                     replicas_alive=rep['replicas_alive'], **perf))
-    rows.append(dict(base, metric='serving_gateway_completed_ratio',
-                     value=round(ratio, 4), unit='ratio',
-                     kill_at=kill_frac, failovers=fo))
-    return rows
-
-
-def bench_serving_gateway_tenants(on_tpu):
-    """Mixed-tenant gateway rung (ISSUE 15): the Poisson workload split
-    across two tenants ('premium' short prompts, 'batch' long prompts)
-    through a clean 2-replica gateway, observed through the wide-event
-    request log rather than the aggregate counters.
-
-    Rows: one per-tenant TTFT p50 row per tenant (unit 'ms', keyed by
-    the `tenant` aux field — the regression gate checks these
-    lower-is-better), plus a kv attribution row whose value is the
-    per-tenant KV page·second split. Every row carries the cross-check
-    fields `kv_events_page_seconds` (sum over wide events) and
-    `kv_pool_page_seconds` (sum of the slot allocators' pool-occupancy
-    integrals): for the slot engine the two are equal by construction,
-    and tools/request_report.py --kv-integral gates exactly that."""
-    import paddle_tpu as paddle
-    from paddle_tpu.monitor.events import (RequestLog,
-                                           set_default_request_log)
-    from paddle_tpu.monitor.registry import MetricRegistry
-    from paddle_tpu.serving import ContinuousBatchingEngine, ServingGateway
-    from paddle_tpu.serving.metrics import percentile
-    from paddle_tpu.text.models import GPTConfig, GPTForCausalLM
-
-    paddle.seed(0)
-    if on_tpu:
-        cfg = GPTConfig(vocab_size=30528, hidden_size=768, num_layers=12,
-                        num_heads=12, max_position_embeddings=1024,
-                        dropout=0.0)
-        lens, mnt, n_req = (32, 64, 96, 128), 64, 32
-        max_len, chunk, block, num_slots = 256, 32, 8, 8
-        mean_gap = 0.02
-    else:
-        cfg = GPTConfig(vocab_size=512, hidden_size=256, num_layers=4,
-                        num_heads=4, max_position_embeddings=128,
-                        dropout=0.0)
-        lens, mnt, n_req = (8, 16, 24, 32), 32, 24
-        max_len, chunk, block, num_slots = 64, 32, 8, 8
-        mean_gap = 0.002
-    model = GPTForCausalLM(cfg)
-    if on_tpu:
-        model.bfloat16()
-    model.eval()
-    from paddle_tpu.capacity.replay import replay as replay_trace
-    # premium gets the short half of the length ladder, batch the long
-    # half — distinguishable TTFT profiles from one workload
-    spec = _serving_workload(
-        n_req, lens, mnt, mean_gap, cfg.vocab_size,
-        tenants={'mode': 'round_robin', 'tenants': [
-            {'name': 'premium',
-             'lengths': {'dist': 'ladder',
-                         'lens': list(lens[:len(lens) // 2])}},
-            {'name': 'batch',
-             'lengths': {'dist': 'ladder',
-                         'lens': list(lens[len(lens) // 2:])}}]})
-    trace = spec.generate()
-    prompts = trace.prompts()
-
-    def factory():
-        return ContinuousBatchingEngine(
-            model, num_slots=num_slots, max_len=max_len,
-            prefill_chunk=chunk, decode_block=block)
-
-    # the log must be installed BEFORE construction: engines and the
-    # gateway cache default_request_log() like they cache the tracer
-    log = RequestLog(capacity=4 * n_req)
-    prev_log = set_default_request_log(log)
-    try:
-        reg = MetricRegistry()
-        gw = ServingGateway(factory, replicas=2, registry=reg)
-        gw.generate(prompts[:2], max_new_tokens=2,
-                    tenant='warmup')                          # compile
-        gw.start()
-        res = replay_trace(gw, trace, max_new_tokens=mnt,
-                                     timeout=600)
-        dt = res.wall_s
-        gw.shutdown()
-        # pool-occupancy integral across the pool; wide-event sum must
-        # match it exactly for slot engines (warmup events included —
-        # the integral saw those slots too)
-        pool_ps = sum(rep.engine.allocator.page_seconds()
-                      for rep in gw.pool)
-        events = log.events()
-    finally:
-        set_default_request_log(prev_log)
-    toks = res.tokens
-    ev_ps = sum(e['kv_page_seconds'] for e in events)
-    kv_by_tenant = {}
-    ttft_by_tenant = {}
-    for e in events:
-        kv_by_tenant[e['tenant']] = (kv_by_tenant.get(e['tenant'], 0.0)
-                                     + e['kv_page_seconds'])
-        if e['first_token_t'] is not None and e['arrival_t'] is not None:
-            ttft_by_tenant.setdefault(e['tenant'], []).append(
-                (e['first_token_t'] - e['arrival_t']) * 1e3)
-    base = {'trace': 'poisson', 'mean_gap_s': mean_gap,
-            'requests': n_req, 'new_tokens': mnt,
-            'num_slots': num_slots, 'replicas': 2, 'workload': 'mixed',
-            'policy': 'least_loaded', 'workload_spec': spec.hash,
-            'degraded': not on_tpu,
-            'kv_events_page_seconds': round(ev_ps, 6),
-            'kv_pool_page_seconds': round(pool_ps, 6)}
-    rows = [dict(base, metric='serving_gateway_mixed_tokens_per_sec',
-                 value=round(toks / dt, 2), unit='tokens/sec')]
-    for tenant in ('premium', 'batch'):
-        rows.append(dict(
-            base, metric='serving_gateway_tenant_ttft_p50',
-            value=round(percentile(ttft_by_tenant.get(tenant, [0.0]),
-                                   50), 3),
-            unit='ms', tenant=tenant,
-            tenant_requests=sum(1 for e in events
-                                if e['tenant'] == tenant),
-            tenant_kv_page_seconds=round(
-                kv_by_tenant.get(tenant, 0.0), 6)))
-    return rows
-
-
-def bench_serving_gateway_qos(on_tpu):
-    """Overload-QoS rung (ISSUE 17): a mixed-tenant burst through a
-    2-replica gateway behind the admission layer — 'premium' (priority
-    1, unthrottled) vs 'bg' (token-bucket rate-limited, priority 0) —
-    where the BACKGROUND arrival rate DOUBLES halfway through the run
-    (a second bg-only trace overlaid from the midpoint). Graceful
-    degradation is the claim: the gateway sheds background traffic
-    (outcome='rejected' wide events) while every premium request
-    completes (asserted == 1.0 inline) and the premium TTFT tail stays
-    bounded.
-
-    Rows for the regression gate: premium TTFT p99 (ms,
-    lower-is-better), shed rate (ratio, lower-is-better — a regression
-    here means the policy started over-shedding the same workload), and
-    the premium completed ratio (ratio, higher-is-better)."""
-    import paddle_tpu as paddle
-    from paddle_tpu.capacity.replay import replay as replay_trace
-    from paddle_tpu.capacity.workload import Trace
-    from paddle_tpu.monitor.events import (RequestLog,
-                                           set_default_request_log)
-    from paddle_tpu.monitor.registry import MetricRegistry
-    from paddle_tpu.serving import (ContinuousBatchingEngine, QosPolicy,
-                                    ServingGateway, TenantClass)
-    from paddle_tpu.serving.metrics import percentile
-    from paddle_tpu.text.models import GPTConfig, GPTForCausalLM
-
-    paddle.seed(0)
-    if on_tpu:
-        cfg = GPTConfig(vocab_size=30528, hidden_size=768, num_layers=12,
-                        num_heads=12, max_position_embeddings=1024,
-                        dropout=0.0)
-        lens, mnt, n_req = (32, 64, 96, 128), 64, 32
-        max_len, chunk, block, num_slots = 256, 32, 8, 8
-        mean_gap, bg_rate, slo_ms = 0.02, 30.0, 2000.0
-    else:
-        cfg = GPTConfig(vocab_size=512, hidden_size=256, num_layers=4,
-                        num_heads=4, max_position_embeddings=128,
-                        dropout=0.0)
-        lens, mnt, n_req = (8, 16, 24, 32), 32, 24
-        max_len, chunk, block, num_slots = 64, 32, 8, 8
-        mean_gap, bg_rate, slo_ms = 0.002, 300.0, 5000.0
-    model = GPTForCausalLM(cfg)
-    if on_tpu:
-        model.bfloat16()
-    model.eval()
-    # steady half: premium + bg round-robin; burst half: a bg-only
-    # trace at the SAME per-request gap overlaid from the midpoint, so
-    # the background arrival rate doubles while premium's is unchanged
-    spec = _serving_workload(
-        n_req, lens, mnt, mean_gap, cfg.vocab_size,
-        tenants={'mode': 'round_robin', 'tenants': [
-            {'name': 'premium'}, {'name': 'bg'}]})
-    burst_spec = _serving_workload(
-        n_req // 2, lens, mnt, mean_gap, cfg.vocab_size,
-        tenants={'mode': 'round_robin', 'tenants': [{'name': 'bg'}]})
-    a, b = spec.generate(), burst_spec.generate()
-    t_mid = float(a.arrival[-1]) * 0.5
-    bg_id = a.tenant_names.index('bg')
-    arr = np.concatenate([a.arrival, b.arrival + t_mid])
-    order = np.argsort(arr, kind='stable')
-    trace = Trace(
-        arr[order],
-        np.concatenate([a.prompt_len, b.prompt_len])[order],
-        np.concatenate([a.new_tokens, b.new_tokens])[order],
-        np.concatenate([a.tenant_id,
-                        np.full(len(b), bg_id, np.int64)])[order],
-        a.tenant_names,
-        np.full(len(order), -1, np.int64),
-        np.zeros(len(order), np.int64),
-        meta={'vocab_size': cfg.vocab_size, 'spec': {'seed': 0}})
-    prompts = trace.prompts()
-
-    def factory():
-        return ContinuousBatchingEngine(
-            model, num_slots=num_slots, max_len=max_len,
-            prefill_chunk=chunk, decode_block=block)
-
-    def policy():
-        return QosPolicy(classes=[
-            TenantClass('premium', priority=1),
-            TenantClass('bg', rate=bg_rate, burst=max(4, num_slots),
-                        priority=0)])
-
-    log = RequestLog(capacity=4 * len(trace))
-    prev_log = set_default_request_log(log)
-    try:
-        reg = MetricRegistry()
-        gw = ServingGateway(factory, replicas=2, admission=policy(),
-                            registry=reg)
-        gw.generate(prompts[:2], max_new_tokens=2,
-                    tenant='warmup')                          # compile
-        gw.start()
-        res = replay_trace(gw, trace, max_new_tokens=mnt, timeout=600)
-        gw.shutdown()
-        events = [e for e in log.events() if e['tenant'] != 'warmup']
-    finally:
-        set_default_request_log(prev_log)
-    tenants = trace.tenants()
-    premium = [h for h, t in zip(res.handles, tenants) if t == 'premium']
-    shed = sum(1 for h in res.handles if h.error is not None)
-    shed_rate = shed / float(len(res.handles))
-    prem_done = sum(1 for h in premium if h.done and h.error is None)
-    prem_ratio = prem_done / float(len(premium))
-    if prem_ratio != 1.0:
-        raise AssertionError(
-            'premium completed_ratio %.4f != 1.0 under background burst'
-            % prem_ratio)
-    prem_ttft = [(e['first_token_t'] - e['arrival_t']) * 1e3
-                 for e in events
-                 if e['tenant'] == 'premium'
-                 and e['first_token_t'] is not None]
-    p99 = percentile(prem_ttft, 99) or 0.0
-    rejected_events = sum(1 for e in events if e['outcome'] == 'rejected')
-    if rejected_events != shed:
-        raise AssertionError(
-            'rejected wide events (%d) != shed handles (%d)'
-            % (rejected_events, shed))
-    base = {'trace': 'poisson+bg_burst', 'mean_gap_s': mean_gap,
-            'requests': len(trace), 'new_tokens': mnt,
-            'num_slots': num_slots, 'replicas': 2,
-            'policy': 'least_loaded', 'bg_rate': bg_rate,
-            'bg_doubles_at_s': round(t_mid, 4),
-            'workload_spec': spec.hash, 'burst_spec': burst_spec.hash,
-            'degraded': not on_tpu}
-    return [
-        dict(base, metric='serving_gateway_qos_premium_ttft_p99',
-             value=round(p99, 3), unit='ms', slo_ttft_ms=slo_ms,
-             slo_ok=bool(p99 <= slo_ms),
-             premium_requests=len(premium)),
-        dict(base, metric='serving_gateway_qos_shed_rate',
-             value=round(shed_rate, 4), unit='ratio', shed=shed),
-        dict(base, metric='serving_gateway_qos_premium_completed_ratio',
-             value=round(prem_ratio, 4), unit='ratio',
-             premium_requests=len(premium)),
-    ]
-
-
-def bench_serving_gateway_multimodel(on_tpu):
-    """Multi-model serving rung (ISSUE 19): N models behind one
-    2-replica gateway of ModelHost replicas, a zipf-mixed Poisson burst
-    routed by model affinity, and a zero-downtime `rollout()` of the
-    head model's weights fired MID-burst from the replay hook.
-
-    Acceptance, asserted inline (a broken swap must fail the rung, not
-    ship a row):
-      * completed_ratio == 1.0 — every request before, during and
-        after the weight swap finishes (drain-never-kill applied to
-        weights instead of replicas);
-      * per-model wide-event attribution matches the workload's model
-        mix EXACTLY (the trace is the oracle for who asked for what);
-      * the warm bring-up of the new version reports zero persistent
-        compile-cache misses — same program shapes, new weights;
-      * weight paging proof on a budgeted host: resident bytes never
-        exceed the byte budget and the eviction counters match the LRU
-        oracle replayed in plain python.
-    """
-    import paddle_tpu as paddle
-    from paddle_tpu.capacity.replay import replay as replay_trace
-    from paddle_tpu.framework import io_save
-    from paddle_tpu.monitor.events import (RequestLog,
-                                           set_default_request_log)
-    from paddle_tpu.monitor.registry import MetricRegistry
-    from paddle_tpu.serving import (ContinuousBatchingEngine,
-                                    ModelAffinityRouter, ModelHost,
-                                    ModelRegistry, ServingGateway)
-    from paddle_tpu.text.models import GPTConfig, GPTForCausalLM
-    import shutil
-    import tempfile
-
-    if on_tpu:
-        cfg = GPTConfig(vocab_size=30528, hidden_size=768, num_layers=12,
-                        num_heads=12, max_position_embeddings=1024,
-                        dropout=0.0)
-        lens, mnt, n_req = (32, 64, 96, 128), 64, 32
-        max_len, chunk, block, num_slots = 256, 32, 8, 8
-        mean_gap = 0.02
-    else:
-        # smaller than the other gateway rungs: the rung builds
-        # n_models+1 engine instances, so weights are kept light
-        cfg = GPTConfig(vocab_size=512, hidden_size=128, num_layers=2,
-                        num_heads=4, max_position_embeddings=128,
-                        dropout=0.0)
-        lens, mnt, n_req = (8, 16, 24, 32), 16, 24
-        max_len, chunk, block, num_slots = 64, 32, 8, 8
-        mean_gap = 0.002
-    n_models, swap_frac = 3, 0.5
-    swap_at = int(n_req * swap_frac)
-    head = 'model_000'
-
-    root = tempfile.mkdtemp(prefix='bench_registry_')
-    try:
-        # publish one distinctly-seeded artifact per model, plus the
-        # head model's v2 (the weights the mid-burst rollout ships)
-        reg = ModelRegistry(root=root)
-        for i in range(n_models):
-            paddle.seed(100 + i)
-            m = GPTForCausalLM(cfg)
-            reg.publish('model_%03d' % i, 'v1', m.state_dict())
-        paddle.seed(200)
-        reg.publish(head, 'v2', GPTForCausalLM(cfg).state_dict())
-        nbytes = reg.entry(head, 'v1').nbytes
-
-        def engine_for(entry):
-            m = GPTForCausalLM(cfg)
-            m.set_state_dict(io_save.load(entry.path))
-            if on_tpu:
-                m.bfloat16()
-            m.eval()
-            return ContinuousBatchingEngine(
-                m, num_slots=num_slots, max_len=max_len,
-                prefill_chunk=chunk, decode_block=block)
-
-        spec = _serving_workload(
-            n_req, lens, mnt, mean_gap, cfg.vocab_size)
-        spec.models = {'mode': 'zipf', 'count': n_models}
-        trace = spec.generate()
-
-        def host_factory():
-            # serving hosts get headroom: every model plus the rollout's
-            # incoming version must be co-resident under load
-            return ModelHost(reg, engine_for,
-                             byte_budget=(n_models + 2) * nbytes,
-                             max_len=max_len)
-
-        log = RequestLog(capacity=4 * n_req)
-        prev_log = set_default_request_log(log)
-        try:
-            mreg = MetricRegistry()
-            gw = ServingGateway(host_factory, replicas=2, registry=mreg,
-                                router=ModelAffinityRouter())
-            t0c = time.time()
-            gw.generate(trace.prompts()[:2], max_new_tokens=2,
-                        model=head, tenant='warmup')          # compile
-            t_cold = time.time() - t0c
-            gw.start()
-            rollout = {}
-
-            def swap(i):
-                if i == swap_at:
-                    rollout.update(gw.rollout(head, 'v2'))
-
-            res = replay_trace(gw, trace, max_new_tokens=mnt,
-                               timeout=600, before_submit=swap)
-            gw.shutdown()
-            events = [e for e in log.events() if e['tenant'] != 'warmup']
-        finally:
-            set_default_request_log(prev_log)
-
-        if res.completed_ratio != 1.0:
-            raise AssertionError(
-                'rollout lost requests: completed_ratio %.4f != 1.0'
-                % res.completed_ratio)
-        if not rollout or rollout.get('to_version') != 'v2':
-            raise AssertionError('mid-burst rollout did not run: %r'
-                                 % (rollout,))
-        if int(rollout.get('cache_misses') or 0) > 0:
-            raise AssertionError(
-                'warm bring-up missed the compile cache: %r' % (rollout,))
-        # the trace is the attribution oracle: wide events per model
-        # must equal the workload's model mix exactly
-        ev_mix = {}
-        for e in events:
-            ev_mix[e['model']] = ev_mix.get(e['model'], 0) + 1
-        if ev_mix != trace.model_mix():
-            raise AssertionError(
-                'wide-event attribution %r != trace model mix %r'
-                % (ev_mix, trace.model_mix()))
-
-        # ---- weight paging proof: budget holds 2 of the 3 models ----
-        pager = ModelHost(reg, engine_for,
-                          byte_budget=2 * nbytes + nbytes // 2)
-        oracle_resident, oracle_evicted = [], []
-        max_resident = 0
-        for i in list(range(n_models)) * 2:
-            key = ('model_%03d' % i, 'v1')
-            pager.load(*key)
-            if key in oracle_resident:
-                oracle_resident.remove(key)
-            while len(oracle_resident) >= 2:
-                oracle_evicted.append(oracle_resident.pop(0))
-            oracle_resident.append(key)
-            if pager.resident_bytes > pager.byte_budget:
-                raise AssertionError(
-                    'resident bytes %d exceed budget %d'
-                    % (pager.resident_bytes, pager.byte_budget))
-            max_resident = max(max_resident, len(pager.resident_models()))
-        evictions = {
-            'model_%03d' % i: int(pager._m_evictions.labels(
-                model='model_%03d' % i).value())
-            for i in range(n_models)}
-        want = {'model_%03d' % i:
-                sum(1 for k in oracle_evicted if k[0] == 'model_%03d' % i)
-                for i in range(n_models)}
-        if evictions != want:
-            raise AssertionError('eviction counters %r != LRU oracle %r'
-                                 % (evictions, want))
-        pager.shutdown()
-
-        base = {'trace': 'poisson', 'mean_gap_s': mean_gap,
-                'requests': n_req, 'new_tokens': mnt,
-                'num_slots': num_slots, 'replicas': 2,
-                'n_models': n_models, 'swap_at': swap_frac,
-                'policy': 'model_affinity', 'workload_spec': spec.hash,
-                'degraded': not on_tpu}
-        toks = sum(int(e['output_tokens'] or 0) for e in events)
-        rows = [
-            dict(base, metric='serving_gateway_multimodel_tokens_per_sec',
-                 value=round(res.tokens_per_sec, 2), unit='tokens/sec',
-                 compile_s_cold=round(t_cold, 3),
-                 model_mix=trace.model_mix(), event_tokens=toks),
-            dict(base,
-                 metric='serving_gateway_multimodel_completed_ratio',
-                 value=round(res.completed_ratio, 4), unit='ratio'),
-            dict(base, metric='serving_gateway_rollout_warm_load_s',
-                 value=round(float(rollout.get('load_s') or 0.0), 3),
-                 unit='s', model=head,
-                 cache_hits=int(rollout.get('cache_hits') or 0),
-                 cache_misses=int(rollout.get('cache_misses') or 0)),
-            dict(base, metric='registry_paging_evictions',
-                 value=sum(evictions.values()), unit='count',
-                 byte_budget=pager.byte_budget,
-                 artifact_bytes=nbytes, max_models_resident=max_resident,
-                 resident_bytes_final=pager.resident_bytes),
-        ]
-        return rows
-    finally:
-        shutil.rmtree(root, ignore_errors=True)
-
-
 def bench_serving_fabric(on_tpu):
     """Serving-fabric rung (ISSUE 20): the gateway fronting REAL worker
     processes over the socket transport.
 
     Three measurements, each on a fresh 2-process worker pair:
 
-    - clean Poisson burst tok/s (the cross-process tax vs the in-proc
-      bench_serving_gateway rung is this row's whole point);
+    - clean Poisson burst tok/s over the socket transport;
     - the same burst with one worker SIGKILLed mid-run — the chaos
       acceptance: completed_ratio must stay 1.0 (token parity of
       failed-over requests is pinned in tests/test_serving_fabric.py);
     - a shared-system-prompt workload routed by LeastLoaded vs the
-      gateway's PrefixAffinityRouter over paged workers: the prefix
+      gateway's PrefixAffinityRouter: the prefix
       directory's hit-rate win is the tracked value.
 
     Rows are keyed by transport/n_procs (+ policy for the router pair)
@@ -1178,7 +332,7 @@ def bench_serving_fabric(on_tpu):
                      value=round(res.completed_ratio, 4), unit='ratio',
                      kill_at=0.5, failovers=fo))
 
-    # shared-system-prompt workload over paged workers: 90% of requests
+    # shared-system-prompt workload: 90% of requests
     # share a 24-token system prefix (3 pages at the preset's page
     # size 8) in 4 groups — more groups than replicas, so least-loaded
     # pays a cold miss per (group, replica) pair while affinity pays
@@ -1267,113 +421,6 @@ def bench_supervisor_recovery(on_tpu):
              'journal_replayed': journal.replayed,
              'journal_dedup_hits': journal.dedup_hits,
              'degraded': not on_tpu}]
-
-
-def bench_capacity_calibration(on_tpu):
-    """Capacity-simulator calibration rung (ISSUE 16): replay a small
-    Poisson trace through a real 1-replica in-proc gateway, fit the
-    two-parameter service model from its wide events, re-run the SAME
-    trace through the discrete-event simulator, and report the TTFT
-    divergence (max of p50/p99 relative error — the regression gate
-    checks it LOWER-is-better; K-S statistic rides along as a field).
-
-    A second, ungated-by-measurement row answers the acceptance
-    question directly: a million-request synthetic sweep under a PINNED
-    service model (so the reported minimum-replica answer is
-    deterministic run to run), with the measured model's answer as an
-    informational field.
-    """
-    import paddle_tpu as paddle
-    from paddle_tpu.capacity import simulator, workload
-    from paddle_tpu.capacity.replay import measure as replay_measure
-    from paddle_tpu.monitor.registry import MetricRegistry
-    from paddle_tpu.serving import ContinuousBatchingEngine
-    from paddle_tpu.text.models import GPTConfig, GPTForCausalLM
-
-    paddle.seed(0)
-    if on_tpu:
-        cfg = GPTConfig(vocab_size=30528, hidden_size=768, num_layers=12,
-                        num_heads=12, max_position_embeddings=1024,
-                        dropout=0.0)
-        lens, mnt, n_req = (32, 64, 96, 128), 64, 32
-        max_len, chunk, block, num_slots = 256, 32, 8, 8
-        mean_gap = 0.02
-    else:
-        # the bench_serving CPU regime: decode-GEMM-bound, service-bound
-        cfg = GPTConfig(vocab_size=512, hidden_size=256, num_layers=4,
-                        num_heads=4, max_position_embeddings=128,
-                        dropout=0.0)
-        lens, mnt, n_req = (8, 16, 24, 32), 32, 24
-        max_len, chunk, block, num_slots = 64, 32, 8, 8
-        mean_gap = 0.002
-    model = GPTForCausalLM(cfg)
-    if on_tpu:
-        model.bfloat16()
-    model.eval()
-    spec = _serving_workload(n_req, lens, mnt, mean_gap, cfg.vocab_size)
-    trace = spec.generate()
-
-    def factory():
-        return ContinuousBatchingEngine(
-            model, num_slots=num_slots, max_len=max_len,
-            prefill_chunk=chunk, decode_block=block)
-
-    reg = MetricRegistry()
-    real_events, res = replay_measure(
-        factory, trace, replicas=1, max_new_tokens=mnt, registry=reg)
-    fitted = simulator.ServiceModel.from_events(
-        real_events, prefill_chunk=chunk, decode_block=block,
-        num_slots=num_slots, trace=trace, replicas=1)
-    sim = simulator.simulate(trace, fitted, replicas=1,
-                             router='least_loaded', registry=reg)
-    div = simulator.compare_events(sim.to_events(), real_events)['overall']
-    rows = [{'metric': 'capacity_sim_ttft_divergence',
-             'value': round(max(div['p50_rel_err'], div['p99_rel_err']), 4),
-             'unit': 'rel_err', 'trace': 'poisson',
-             'mean_gap_s': mean_gap, 'requests': n_req,
-             'new_tokens': mnt, 'num_slots': num_slots, 'replicas': 1,
-             'workload_spec': spec.hash,
-             'ks': round(div['ks'], 4),
-             'p50_rel_err': round(div['p50_rel_err'], 4),
-             'p99_rel_err': round(div['p99_rel_err'], 4),
-             'sim_p50_ms': round(div['sim_p50_s'] * 1e3, 3),
-             'real_p50_ms': round(div['real_p50_s'] * 1e3, 3),
-             'sim_p99_ms': round(div['sim_p99_s'] * 1e3, 3),
-             'real_p99_ms': round(div['real_p99_s'] * 1e3, 3),
-             'service_model': fitted.to_dict(),
-             'replay_tokens_per_sec': round(res.tokens_per_sec, 2),
-             'degraded': not on_tpu}]
-
-    # million-request sweep under a pinned model: the reported
-    # minimum-replica answer must be deterministic for the gate
-    big = workload.WorkloadSpec(
-        requests=1000000, seed=0,
-        arrival={'process': 'diurnal', 'mean_gap_s': 0.0005,
-                 'period_s': 120.0, 'peak_to_trough': 4.0},
-        lengths={'dist': 'zipf', 'a': 1.8, 'min': 8, 'max': 256},
-        output={'dist': 'lognormal', 'median': 12, 'sigma': 0.5,
-                'min': 1, 'max': 64},
-        tenants={'mode': 'zipf', 'count': 20, 'a': 1.5})
-    pinned = simulator.ServiceModel(0.002, 0.004, prefill_chunk=chunk,
-                                    decode_block=block,
-                                    num_slots=num_slots)
-    sweep = simulator.sweep_replicas(big.generate(), pinned,
-                                     counts=(8, 16, 32), slo_ttft_s=0.25)
-    measured_min = simulator.sweep_replicas(
-        trace, fitted, counts=(1, 2, 4),
-        slo_ttft_s=10 * div['real_p99_s'])['min_replicas']
-    rows.append({'metric': 'capacity_sweep_min_replicas',
-                 'value': sweep['min_replicas'], 'unit': 'replicas',
-                 'requests': sweep['requests'],
-                 'slo_ttft_s': sweep['slo_ttft_s'],
-                 'workload_spec': big.hash,
-                 'sweep_points': sweep['points'],
-                 'sweep_wall_s': round(sum(p['sim_wall_s']
-                                           for p in sweep['points']), 3),
-                 'measured_model_min_replicas': measured_min,
-                 'service_model': pinned.to_dict(),
-                 'degraded': not on_tpu})
-    return rows
 
 
 def bench_ingest(on_tpu):
@@ -1540,10 +587,7 @@ def main():
     on_tpu = jax.devices()[0].platform == 'tpu'
     failed = None
     for fn in (bench_resnet, bench_yolo_infer, bench_gpt_decode,
-               bench_serving, bench_serving_paged, bench_serving_gateway,
-               bench_serving_gateway_tenants, bench_serving_gateway_qos,
-               bench_serving_gateway_multimodel, bench_serving_fabric,
-               bench_supervisor_recovery, bench_capacity_calibration,
+               bench_serving_fabric, bench_supervisor_recovery,
                bench_ingest):
         try:
             res = fn(on_tpu)

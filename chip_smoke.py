@@ -10,13 +10,14 @@ random weights from --seed), and checks what comes out:
          at ln(vocab), stay finite and fall; the compiled step holds the
          Pallas flash forward AND backward kernels; donation ran and did
          not strand the model.
-  serve  the slot engine and the paged engine (prefix cache on) each answer
-         16 mixed-length requests added over time so slots and pages are
-         freed and reused, one program per phase, one request streamed; the
-         slot engine once more behind an in-process ServingGateway. With f32
-         weights under jax.default_matmul_precision("highest") every
-         request's tokens equal model.generate()'s token for token; for the
-         bf16 run the share that still agrees is printed, not asserted.
+  serve  the paged engine (prefix cache on) answers 16 mixed-length
+         requests added over time so slots and pages are freed and reused,
+         one program per phase, one request streamed; the same engine once
+         more behind an in-process ServingGateway, tokens equal to the
+         direct drive. With f32 weights under
+         jax.default_matmul_precision("highest") every request's tokens
+         equal model.generate()'s token for token; for the bf16 run the
+         share that still agrees is printed, not asserted.
 
   --chips 4  runs ONLY the multichip phase: the same train step under
          fleet.init + fleet.fleet_train_step on a four-device mesh
@@ -47,7 +48,7 @@ import numpy as np
 FULL = dict(vocab_size=30528, hidden_size=768, num_layers=12, num_heads=12)
 TOY = dict(vocab_size=512, hidden_size=128, num_layers=2, num_heads=4)
 
-# train: batch x seq; serve: the engines' shape and the request mix
+# train: batch x seq; serve: the engine's shape and the request mix
 FULL_SIZES = dict(batch=32, seq=512, steps=8, slots=8, max_len=256, chunk=32,
                   block=8, page=16, pages=65, prefix=64, new_tokens=32,
                   lengths=(32, 72, 96, 128))
@@ -270,7 +271,7 @@ def check_answers(label, tokens, widths, new_tokens):
 
 def answer_all(label, eng, prompts, widths, sizes, cache_arrays, programs,
                expect_donation):
-    """Drive `eng` over the prompts and hold it to what both engines owe:
+    """Drive `eng` over the prompts and hold it to what the engine owes:
     every request answered, one program per phase, no recompile after
     warm-up, donation as expected. Returns (tokens, donated)."""
     nt = sizes['new_tokens']
@@ -285,77 +286,63 @@ def answer_all(label, eng, prompts, widths, sizes, cache_arrays, programs,
     return tokens, donated
 
 
-def run_engines(model, widths, sizes, prompts, expect_donation,
-                with_gateway):
-    """Both engines (and optionally the gateway) over the same prompts on
-    `model` as it stands; returns {'slot': tokens, 'paged': tokens}."""
+def run_engine(model, widths, sizes, prompts, expect_donation):
+    """The engine driven directly and behind the gateway over the same
+    prompts on `model` as it stands; returns {'paged': tokens, 'gateway':
+    tokens}."""
     from paddle_tpu.monitor.registry import MetricRegistry
-    from paddle_tpu.serving import (ContinuousBatchingEngine,
-                                    PagedContinuousBatchingEngine,
+    from paddle_tpu.serving import (PagedContinuousBatchingEngine,
                                     ServingGateway)
     nt = sizes['new_tokens']
 
-    def slot_engine():
-        return ContinuousBatchingEngine(
-            model, num_slots=sizes['slots'], max_len=sizes['max_len'],
-            prefill_chunk=sizes['chunk'], decode_block=sizes['block'])
+    def engine():
+        return PagedContinuousBatchingEngine(
+            model, num_seqs=sizes['slots'], max_len=sizes['max_len'],
+            page_size=sizes['page'], num_pages=sizes['pages'],
+            prefill_chunk=sizes['chunk'], decode_block=sizes['block'],
+            prefix_cache=True)
 
     out = {}
-    eng = slot_engine()
-    out['slot'], donated = answer_all(
-        'slot', eng, prompts, widths, sizes,
-        lambda e: [t._data for c in e._caches for t in (c.k, c.v)],
-        {'prefill': 1, 'decode': 1}, expect_donation)
-    check(eng.perf_estimate() is not None,
-          'slot: perf_estimate could not price the decode program')
-    print('serve/slot: %d requests over %d slots, programs %r, cache '
-          'donated: %r' % (N_REQUESTS, sizes['slots'],
-                           eng.compiled_sizes(), donated))
-    eng.shutdown()
-
-    eng = PagedContinuousBatchingEngine(
-        model, num_seqs=sizes['slots'], max_len=sizes['max_len'],
-        page_size=sizes['page'], num_pages=sizes['pages'],
-        prefill_chunk=sizes['chunk'], decode_block=sizes['block'],
-        prefix_cache=True)
+    eng = engine()
     out['paged'], donated = answer_all(
         'paged', eng, prompts, widths, sizes,
         lambda e: [a for kv in e._pools for a in kv],
         {'prefill': 1, 'decode': 1, 'verify': 0}, expect_donation)
+    check(eng.perf_estimate() is not None,
+          'paged: perf_estimate could not price the decode program')
     demand = sum(-(-(len(p) + nt - 1) // sizes['page']) for p in prompts)
     check(demand > sizes['pages'] - 1,
           'paged: the pool (%d pages) covers the whole demand (%d): no page '
           'is reused' % (sizes['pages'] - 1, demand))
     check(eng.prefix.hits > 0, 'paged: the shared prefix never hit the cache')
-    print('serve/paged: %d requests, %d pages demanded from a pool of %d, '
-          'prefix blocks hit %d / missed %d, programs %r, pool donated: %r'
-          % (N_REQUESTS, demand, sizes['pages'] - 1, eng.prefix.hits,
-             eng.prefix.misses, eng.compiled_sizes(), donated))
+    print('serve/paged: %d requests over %d slots, %d pages demanded from a '
+          'pool of %d, prefix blocks hit %d / missed %d, programs %r, pool '
+          'donated: %r'
+          % (N_REQUESTS, sizes['slots'], demand, sizes['pages'] - 1,
+             eng.prefix.hits, eng.prefix.misses, eng.compiled_sizes(),
+             donated))
     eng.shutdown()
 
-    if with_gateway:
-        # the door users call; sync drive with a bound, so a replica the
-        # gateway marked lost shows as a failure, not as a hang
-        gw = ServingGateway(slot_engine, replicas=1,
-                            registry=MetricRegistry())
-        reqs = [gw.submit(p, max_new_tokens=nt) for p in prompts]
-        for _ in range(100 * N_REQUESTS):
-            if not gw.step():
-                break
-        else:
-            raise AssertionError('gateway: requests still outstanding')
-        check(not gw.failover_log,
-              'gateway: a replica was lost: %r' % gw.failover_log)
-        out['gateway'] = [list(r.tokens) for r in reqs]
-        check_answers('gateway', out['gateway'], widths, nt)
-        # same engine, same dtype, other co-batching: a request's tokens
-        # may not depend on who shared the batch
-        check(out['gateway'] == out['slot'],
-              'gateway: tokens differ from the slot engine driven directly')
-        print('serve/gateway: %d requests through ServingGateway'
-              '(replicas=1), tokens equal the direct slot engine'
-              % N_REQUESTS)
-        gw.shutdown()
+    # the door users call; sync drive with a bound, so a replica the
+    # gateway marked lost shows as a failure, not as a hang
+    gw = ServingGateway(engine, replicas=1, registry=MetricRegistry())
+    reqs = [gw.submit(p, max_new_tokens=nt) for p in prompts]
+    for _ in range(100 * N_REQUESTS):
+        if not gw.step():
+            break
+    else:
+        raise AssertionError('gateway: requests still outstanding')
+    check(not gw.failover_log,
+          'gateway: a replica was lost: %r' % gw.failover_log)
+    out['gateway'] = [list(r.tokens) for r in reqs]
+    check_answers('gateway', out['gateway'], widths, nt)
+    # same engine, same dtype, other co-batching and other prefix hits: a
+    # request's tokens may not depend on who shared the batch or the pages
+    check(out['gateway'] == out['paged'],
+          'gateway: tokens differ from the engine driven directly')
+    print('serve/gateway: %d requests through ServingGateway(replicas=1), '
+          'tokens equal the engine driven directly' % N_REQUESTS)
+    gw.shutdown()
     return out
 
 
@@ -383,7 +370,7 @@ def agreement(tokens, ref):
 
 def phase_serve(device, widths, sizes, seed, clock):
     import jax
-    # the engines donate their caches on tpu/gpu, not on cpu
+    # the engine donates its pools on tpu/gpu, not on cpu
     expect_donation = device.platform in ('tpu', 'gpu')
     prompts = make_prompts(widths, sizes, seed)
     nt = sizes['new_tokens']
@@ -394,30 +381,28 @@ def phase_serve(device, widths, sizes, seed, clock):
     t0, mark = time.perf_counter(), clock.mark()
     with jax.default_matmul_precision('highest'):
         ref = reference_tokens(model, prompts, nt)
-        exact = run_engines(model, widths, sizes, prompts, expect_donation,
-                            with_gateway=False)
+        exact = run_engine(model, widths, sizes, prompts, expect_donation)
     for name, tokens in exact.items():
         share, first = agreement(tokens, ref)
         check(share == 1.0,
-              'parity: %s engine at f32/highest differs from generate() on '
+              'parity: %s drive at f32/highest differs from generate() on '
               '%d of %d requests, first at token %r — a bug (donation, cache '
               'rows, page reuse), not rounding'
               % (name, round((1 - share) * N_REQUESTS), N_REQUESTS, first))
     compile_s, programs = clock.since(mark)
-    print('serve/parity: slot and paged engines equal generate() token for '
-          'token on %d requests x %d tokens (f32, highest precision); '
+    print('serve/parity: the engine, direct and behind the gateway, equals '
+          'generate() token for token on %d requests x %d tokens (f32, '
+          'highest precision); '
           '%.1f s wall, %.1f s compiling %d program(s)'
           % (N_REQUESTS, nt, time.perf_counter() - t0, compile_s, programs))
 
     t0, mark = time.perf_counter(), clock.mark()
     model.bfloat16()
-    bf16 = run_engines(model, widths, sizes, prompts, expect_donation,
-                       with_gateway=True)
-    for name in ('slot', 'paged'):
-        share, first = agreement(bf16[name], ref)
-        print('serve/bf16: %s engine agrees with the f32 reference on %.0f%% '
-              'of requests (first divergence at token %s) — printed, not '
-              'asserted' % (name, 100 * share, first))
+    bf16 = run_engine(model, widths, sizes, prompts, expect_donation)
+    share, first = agreement(bf16['paged'], ref)
+    print('serve/bf16: the engine agrees with the f32 reference on %.0f%% of '
+          'requests (first divergence at token %s) — printed, not asserted'
+          % (100 * share, first))
     compile_s, programs = clock.since(mark)
     total = sum(len(t) for toks in bf16.values() for t in toks)
     print('serve/bf16: %d tokens, %.1f s wall, %.1f s compiling %d '

@@ -63,9 +63,8 @@ def replay(gateway, trace, speed=1.0, max_new_tokens=None, seed=0,
     seed: sampling seed for every request — engines are deterministic
     per (prompt, sampling, seed), which is what makes failover
     exact-token and replays reproducible. before_submit(i) runs just
-    before request i is submitted — the hook bench_serving_gateway uses
-    to kill a replica mid-burst at the same point the retired inline
-    loop did. Requests still unfinished after `timeout` seconds (each)
+    before request i is submitted — the hook bench_serving_fabric uses
+    to kill a worker mid-burst. Requests still unfinished after `timeout` seconds (each)
     are left behind and counted out of `completed` — the chaos bench's
     completed_ratio, not an exception.
     """

@@ -360,16 +360,16 @@ class Predictor:
     def clone(self):
         return Predictor(self._config)
 
-    def decode_engine(self, num_slots=8, max_len=None, prefill_chunk=16,
-                      decode_block=4, paged=False, **paged_kwargs):
-        """Continuous-batching front door over the loaded model.
+    def decode_engine(self, **engine_kwargs):
+        """Continuous-batching front door over the loaded model: a
+        `serving.PagedContinuousBatchingEngine`, its constructor's
+        keywords (num_seqs, max_len, page_size, num_pages,
+        prefill_chunk, decode_block, spec_k, prefix_cache, ...) passed
+        through.
 
-        Only meaningful when the artifact is a causal LM with the slot-
-        cache decode path (GPTForCausalLM); anything else fails here
-        with a clear error instead of deep inside the first step().
-        `paged=True` returns the page-granular engine (prefix sharing,
-        optional speculative decoding); extra keyword args — page_size,
-        num_pages, spec_k, prefix_cache, ... — pass through to it.
+        Only meaningful when the artifact is a causal LM with a cached
+        decode path (GPTForCausalLM); anything else fails here with a
+        clear error instead of deep inside the first step().
         """
         layer = self._layer
         if layer is None or not (hasattr(layer, 'generate')
@@ -379,19 +379,8 @@ class Predictor:
                 'decode_engine() needs a causal-LM artifact '
                 '(GPTForCausalLM with a KV-cache decode path); loaded '
                 'model is %s' % type(layer).__name__)
-        if paged:
-            from ..serving import PagedContinuousBatchingEngine
-            return PagedContinuousBatchingEngine(
-                layer, num_seqs=num_slots, max_len=max_len,
-                prefill_chunk=prefill_chunk, decode_block=decode_block,
-                **paged_kwargs)
-        if paged_kwargs:
-            raise TypeError('decode_engine() got paged-only arguments %r '
-                            'without paged=True' % sorted(paged_kwargs))
-        from ..serving import ContinuousBatchingEngine
-        return ContinuousBatchingEngine(
-            layer, num_slots=num_slots, max_len=max_len,
-            prefill_chunk=prefill_chunk, decode_block=decode_block)
+        from ..serving import PagedContinuousBatchingEngine
+        return PagedContinuousBatchingEngine(layer, **engine_kwargs)
 
     def decode_gateway(self, replicas=2, router=None, autoscaler=None,
                        registry=None, **engine_kwargs):
@@ -399,8 +388,8 @@ class Predictor:
         replica factory clones this predictor's artifact into fresh
         decode engines (the reference's fleet-of-AnalysisPredictors
         deployment shape, in one process). Engine construction kwargs
-        — num_slots, max_len, paged=True, page_size, ... — pass through
-        to decode_engine() per replica."""
+        — num_seqs, max_len, page_size, ... — pass through to
+        decode_engine() per replica."""
         # non-causal-LM artifacts fail in the first factory call (the
         # gateway builds its initial replicas eagerly), with
         # decode_engine()'s clear TypeError

@@ -1,32 +1,48 @@
-"""Continuous-batching engine: two jitted programs + a thread-safe door.
+"""Paged continuous batching: block-granular KV + prefix reuse + spec
+decode, in exactly THREE compiled programs behind a thread-safe door.
 
-The whole engine compiles exactly TWO programs, each with one static
-shape, so request admit/retire churn can never retrace:
+The engine keeps one physical pool of fixed-size pages per layer and maps
+sequences onto it through host numpy block tables (vLLM's PagedAttention
+layout):
 
-  prefill chunk  — [1, C] prompt tokens into ONE slot's cache rows
-                   (slot sliced out, forwarded, written back; the slot
-                   index / row offset / valid count are traced scalars);
-  decode burst   — K cached decode steps for ALL slots in one dispatch
+  - a sequence holds only the pages its actual length needs (reserved
+    up front at admission — residents can never fail mid-flight);
+  - requests sharing a prompt prefix map their leading block-table
+    entries to the SAME already-filled pages (PrefixCache, chain-hashed
+    full blocks) and skip that part of prefill entirely;
+  - optionally, an n-gram proposer drafts K tokens per decode step and
+    ONE batched verify forward accepts the longest prefix matching the
+    model's own greedy picks — up to K+1 tokens per dispatch, output
+    token-identical to sequential generate() by construction (every
+    accepted token equals the greedy pick the model would have made).
+
+Program set, each with one static shape, so request admit/retire churn
+can never retrace (trace-count gauges assert it):
+
+  prefill chunk  — [1, C] prompt tokens through one sequence's block-
+                   table row (row offset / valid count are traced);
+  decode burst   — K cached steps for ALL sequences in one dispatch
                    (lax.scan; per-step `step_active` masking freezes
-                   finished or still-prefilling slots in-program, so the
-                   burst length never depends on occupancy).
+                   finished or still-prefilling rows in-program, so the
+                   burst length never depends on occupancy; spec off);
+  verify pass    — [S, K+1] draft tokens for ALL sequences (spec on).
 
-Correctness relies on the GPTSlotCache invariants (text/models/gpt.py):
-rows at/beyond a slot's length are unreachable garbage, attention writes
-at the pre-step offsets and the ENGINE advances lengths — prefill
-write-back sets `start + valid` (padding rows stay invalid), the decode
-burst adds `step_active` per step.
+Only the layers' state lives on device — per layer, as the model names
+it (`cache_specs()`, serving/kv_cache.py): the page pools of a layer that
+keeps K/V rows, or `[num_seqs, ...]` arrays that belong to a slot for a
+recurrent layer (a linear-attention state). Block tables and lengths are
+host numpy handed to jit per dispatch (values change freely, shapes
+never). A recurrent layer's state cannot be shared by prefix nor taken
+back after a rejected draft, so a model with one runs with
+`prefix_cache=False` and `spec_k=0` (the constructor says so); a
+preempted request recomputes from position 0, state and all.
 
-Greedy output is token-identical to sequential generate(): the masked
-slot attention contributes exact zeros for invalid rows (scores hit
--1e9 and underflow to 0.0 after the f32 softmax), and sampling mirrors
+Greedy output is token-identical to sequential generate(): rows at or
+beyond a sequence's length are unreachable garbage (masked scores hit
+-1e9 and underflow to 0.0 after the f32 softmax), attention writes at
+the pre-step offsets and the ENGINE advances lengths; sampling mirrors
 generate()'s per-request PRNG stream (one split at prefill, one per
 decode step, advanced only on active steps).
-
-`_EngineBase` holds everything that is NOT about the cache layout — the
-thread-safe front door, the scheduler glue, metrics, shutdown — so the
-paged engine (serving/paged_engine.py) shares it verbatim and differs
-only in its compiled programs and page bookkeeping.
 """
 import queue as _queue
 import threading
@@ -43,13 +59,13 @@ from ..monitor import events as _events
 from ..monitor import tracing as _tracing
 from ..monitor.perf import CompileWatchdog, StepTimeline
 from ..monitor.perf import costmodel as _costmodel
-from ..text.models.gpt import GPTSlotCache
-from .kv_cache import (SlotAllocator, build_slot_caches, cache_specs,
-                       kv_row_bytes)
+from .kv_cache import (PageAllocator, PrefixCache, SlotAllocator,
+                       build_paged_pools, cache_specs, kv_row_bytes,
+                       layer_caches, layer_state, state_bytes_per_seq)
 from .metrics import ServingMetrics
-from .scheduler import Request, Scheduler
+from .scheduler import PagedScheduler, Request
 
-__all__ = ['ContinuousBatchingEngine']
+__all__ = ['PagedContinuousBatchingEngine', 'NGramProposer']
 
 
 @jax.named_scope('serving.pick_token')    # names the device ops, no more
@@ -72,16 +88,52 @@ def _pick_token(lg, key, temp, topk, sample):
     return jnp.where(sample, sampled, greedy)
 
 
-class _EngineBase:
-    """Cache-layout-agnostic half of a continuous-batching engine.
+class NGramProposer:
+    """Prompt-lookup drafting: find the most recent earlier occurrence
+    of the sequence's trailing n-gram and propose whatever followed it.
+
+    Free (no draft model, no device work) and surprisingly effective on
+    serving traffic, where outputs quote their prompts — exactly the
+    regime prefix sharing also targets. Wrong drafts cost only their
+    share of one verify pass; the accept rule keeps output exact.
+    """
+
+    def __init__(self, n=2):
+        if n < 1:
+            raise ValueError('n-gram size must be >= 1')
+        self.n = int(n)
+
+    def propose(self, history, k):
+        """k draft ids continuing `history` (prompt + generated so far).
+        Falls back to repeating the last token when the n-gram has no
+        earlier occurrence — a cheap guess beats proposing nothing,
+        since the verify pass runs at [S, K+1] either way."""
+        n = min(self.n, len(history) - 1)
+        draft = []
+        if n > 0:
+            tail = history[-n:]
+            for i in range(len(history) - n - 1, -1, -1):
+                if history[i:i + n] == tail:
+                    draft = list(history[i + n:i + n + k])
+                    break
+        last = history[-1]
+        while len(draft) < k:
+            draft.append(draft[-1] if draft else last)
+        return draft[:k]
+
+
+class PagedContinuousBatchingEngine:
+    """Page-granular continuous batching over a decoder that names its
+    per-layer caches (`cache_specs()`): GPTForCausalLM,
+    OlmoHybridForCausalLM.
 
     Front door (`add_request` / `step` / `run` / `stream` / `generate`)
     is thread-safe: any number of threads may submit and drive; an RLock
     serializes scheduler state and device dispatches while `Request.wait`
-    and stream consumption stay lock-free. Subclasses own the compiled
-    programs: they set `self.allocator` / `self.scheduler` and implement
-    `_prefill_call` / `_decode_step` (and may hook `_bind` /
-    `_on_step_metrics`).
+    and stream consumption stay lock-free. `spec_k > 0` replaces the
+    decode burst with draft-and-verify and is greedy-only: sampled
+    requests are rejected at add_request, because the accept rule
+    compares drafts against argmax picks.
 
     With the tracer on, a step explains itself: `serving.step` with
     `serving.step.admit`, `serving.step.prefill` (one
@@ -92,15 +144,61 @@ class _EngineBase:
     """
 
     # traced-body counter keys, one per compiled program; the zero-
-    # retrace assertion is `trace_counts` staying all-ones across an
-    # arbitrary admit/retire workload
-    _programs = ('prefill', 'decode')
+    # retrace assertion is `trace_counts` staying at most one per key
+    # across an arbitrary admit/retire workload
+    _programs = ('prefill', 'decode', 'verify')
 
-    def __init__(self, model, num_slots, max_len):
+    def __init__(self, model, num_seqs=8, max_len=None, page_size=16,
+                 num_pages=None, prefill_chunk=16, decode_block=4,
+                 spec_k=0, ngram=2, prefix_cache=True, preempt=False,
+                 max_preempts=None, donate=None):
         model.eval()
         self._model = model
-        self.num_slots = int(num_slots)
+        self.num_slots = int(num_seqs)
         self.max_len = int(max_len or model.config.max_position_embeddings)
+        if self.max_len > model.config.max_position_embeddings:
+            raise ValueError(
+                'max_len %d exceeds max_position_embeddings %d'
+                % (self.max_len, model.config.max_position_embeddings))
+        self.page_size = int(page_size)
+        self.num_blocks = -(-self.max_len // self.page_size)
+        if num_pages is None:
+            # parity default: enough for every sequence at max_len plus
+            # scratch. Real deployments size the pool to ACTUAL length
+            # distributions (the density win); the scheduler's up-front
+            # reservation keeps a small pool safe, just slower to admit.
+            num_pages = self.num_slots * self.num_blocks + 1
+        self.num_pages = int(num_pages)
+        self.decode_block = int(decode_block)
+        if self.decode_block < 1:
+            raise ValueError('decode_block must be >= 1')
+        self.spec_k = int(spec_k)
+        if self.spec_k < 0:
+            raise ValueError('spec_k must be >= 0')
+        self._proposer = NGramProposer(ngram) if self.spec_k else None
+        # programs that must trace before the watchdog's warmup barrier
+        # can be declared: the verify program only ever traces when
+        # speculation is on, and must not be waited for forever without
+        self._warm_programs = (self._programs if self.spec_k
+                               else ('prefill', 'decode'))
+        # what each layer keeps (the model names it): K/V rows in the
+        # page pool, or per-SLOT arrays that every token rewrites
+        self._specs = cache_specs(model)
+        self._state_seq_bytes = state_bytes_per_seq(self._specs)
+        if self._state_seq_bytes and prefix_cache:
+            raise ValueError(
+                'prefix_cache=True with a recurrent layer: shared pages '
+                'hold K/V rows only, so a prefix hit would start the '
+                "recurrent layers' state from zeros at the hit's end "
+                'instead of from the prefix. Pass prefix_cache=False '
+                '(snapshots of state per cached block are not built yet).')
+        if self._state_seq_bytes and self.spec_k:
+            raise ValueError(
+                'spec_k=%d with a recurrent layer: a verify pass advances '
+                'the state over every draft and cannot take back the ones '
+                'the accept rule rejects (rejected K/V rows are simply '
+                'overwritten; a state has no dead rows). Pass spec_k=0.'
+                % self.spec_k)
         self.metrics = ServingMetrics()
         self._params = _fm.extract_params(model)
         self._bufs = _fm.extract_buffers(model)
@@ -123,10 +221,8 @@ class _EngineBase:
         # cached at construction (like the registry): swap the default
         # tracer BEFORE building the engine under test
         self._tracer = _tracing.default_tracer()
-        # wide-event request log, same caching rule; subclasses set the
-        # page->bytes factor once their cache layout is known
+        # wide-event request log, same caching rule
         self.events = _events.default_request_log()
-        self._kv_page_bytes = 0
         self.trace_counts = {k: 0 for k in self._programs}
         # scrape-visible retrace canary: flat at 1 per program == the
         # bounded-compilation contract holds in production, not just
@@ -152,6 +248,48 @@ class _EngineBase:
                                      tracer=self._tracer)
         self._decode_args = None
         self._step_index = 0
+        self._pools = build_paged_pools(model, self.num_pages,
+                                        self.page_size, self.num_slots)
+        self.pages = PageAllocator(self.num_pages)
+        self.prefix = (PrefixCache(self.page_size, self.pages)
+                       if prefix_cache else None)
+        self.allocator = SlotAllocator(self.num_slots)
+        self.scheduler = PagedScheduler(self.allocator, self.pages,
+                                        self.max_len, prefill_chunk,
+                                        self.page_size, self.prefix)
+        # priority preemption: a page-blocked high-priority arrival may
+        # evict strictly-lower-priority residents (scheduler policy);
+        # this engine's hook clears the freed lane and accounts the
+        # eviction. max_preempts bounds how often one request may lose
+        # its pages before it is finished terminally (outcome
+        # 'preempted') instead of requeued.
+        self.scheduler.preempt_enabled = bool(preempt)
+        self.scheduler.max_preempts = (None if max_preempts is None
+                                       else int(max_preempts))
+        self.scheduler.on_preempt = self._on_preempt
+        # billing unit for kv_byte_seconds: one physical page
+        self._kv_page_bytes = kv_row_bytes(self._specs) * self.page_size
+        # per-row KV length (rows written), the block-table companion to
+        # the host control arrays above. Mid-prefill rows track
+        # consumed so in-program garbage writes from frozen lanes land
+        # on rows the next real pass overwrites anyway.
+        self._lens = np.zeros((self.num_slots,), np.int32)
+        self._prefix_seen = [0, 0]    # hit/miss totals already reported
+        # which K/V read each program took ('pool' | 'gather'), written
+        # where the program is traced (`_unpack`) from what attention
+        # recorded on the caches it returned; the spans' `kv_read` tag
+        self.kv_read = {}
+        if donate is None:
+            donate = jax.default_backend() in ('tpu', 'gpu')
+        dn = (2,) if donate else ()
+        self._prefill_jit = jax.jit(self._prefill_fn, donate_argnums=dn)
+        self._decode_jit = jax.jit(self._decode_fn, donate_argnums=dn)
+        self._verify_jit = jax.jit(self._verify_fn, donate_argnums=dn)
+        self._verify_args = None
+
+    @property
+    def num_seqs(self):
+        return self.num_slots
 
     # ---- front door ---------------------------------------------------
 
@@ -165,8 +303,8 @@ class _EngineBase:
         the per-tenant metric families and the wide event. `model` is
         the second attribution dimension (multi-model gateways route on
         it; a single-model engine just records it). `priority` (int,
-        higher wins) orders admission and — on the paged engine with
-        preempt=True — marks lower-priority residents evictable.
+        higher wins) orders admission and — with preempt=True — marks
+        lower-priority residents evictable.
         `emit_event=False` suppresses this engine's wide event — the
         gateway sets it so a failed-over request still produces exactly
         ONE canonical record (the gateway's, which knows the failover
@@ -206,11 +344,10 @@ class _EngineBase:
         req._tenant_label = self.metrics.tenant_label(req.tenant)
         req._model_label = self.metrics.model_label(
             getattr(req, 'model', None))
-        # front-door guard, shared by BOTH engines (the paged subclass
-        # overrides _validate without chaining): a request whose worst
-        # case — prompt plus every generated token but the last — cannot
-        # fit the cache would sit at the queue head forever, wedging
-        # admission for everyone behind it. Fail loud at submission.
+        # front-door guard: a request whose worst case — prompt plus
+        # every generated token but the last — cannot fit the cache
+        # would sit at the queue head forever, wedging admission for
+        # everyone behind it. Fail loud at submission.
         worst = len(req.prompt) + req.max_new_tokens - 1
         if len(req.prompt) and worst > self.max_len:
             raise ValueError(
@@ -222,7 +359,12 @@ class _EngineBase:
             if self._closed:
                 raise RuntimeError(
                     'engine is shut down — it no longer admits requests')
-            self._validate(req)
+            if self.spec_k and req.do_sample:
+                raise ValueError(
+                    'speculative decoding (spec_k=%d) is greedy-only: '
+                    'the accept rule compares drafts against argmax '
+                    'picks. Submit with do_sample=False or run '
+                    'spec_k=0.' % self.spec_k)
             self.scheduler.submit(req)
             self.metrics.on_arrival(req.id, req._arrival_t)
             tr = self._tracer
@@ -244,9 +386,6 @@ class _EngineBase:
                                     queue_depth=len(self.scheduler.queue))
         return req
 
-    def _validate(self, req):
-        """Subclass hook: extra front-door checks (lock held)."""
-
     def shutdown(self):
         """Refuse all future add_request calls. In-flight requests may
         still be driven to completion with step()/run(); shutdown only
@@ -267,11 +406,14 @@ class _EngineBase:
                     # CPU reads "the host was busy"
                     cpu0 = time.process_time()
                     compiles0 = self.perf.counts['compile']
+                    slots, nbytes = self._state_in_use()
                     sp.tags.update(step=self._step_index,
                                    residents=len(sched.resident),
                                    queue_depth=len(sched.queue),
-                                   slots_in_use=self.allocator.in_use)
-                    self._tag_step(sp)
+                                   slots_in_use=self.allocator.in_use,
+                                   pages_in_use=self.pages.in_use,
+                                   state_slots_in_use=slots,
+                                   state_bytes=nbytes)
                 with tr.start_span('serving.step.admit',
                                    annotate=True) as ph_admit:
                     admitted = self._admit()
@@ -290,12 +432,18 @@ class _EngineBase:
                 burst = self._decode_step()
                 self.metrics.on_step(self.allocator.in_use, self.num_slots)
                 self.metrics.on_queue_depth(len(sched.queue))
-                self._on_step_metrics()
+                self.metrics.on_pages_in_use(self.pages.in_use)
+                self.metrics.on_state_bytes(self._state_in_use()[1])
+                if self.prefix is not None:
+                    h, m = self.prefix.hits, self.prefix.misses
+                    self.metrics.on_prefix_lookup(
+                        h - self._prefix_seen[0], m - self._prefix_seen[1])
+                    self._prefix_seen = [h, m]
                 for prog, child in self._m_trace.items():
                     child.set(self.trace_counts[prog])
                 if not self.perf.armed and all(
                         self.trace_counts[p] > 0
-                        for p in self._warm_programs()):
+                        for p in self._warm_programs):
                     self.perf.declare_warmup(
                         '%s steady state' % type(self).__name__)
                 detail = None
@@ -311,12 +459,6 @@ class _EngineBase:
                     # flagged one carries the whole step's phases
                     self.timeline.end_step(detail=detail)
             return sched.pending
-
-    def _tag_step(self, span):
-        """Subclass hook: more of the state at a step's entry."""
-
-    def _tag_prefill_call(self, span):
-        """Subclass hook: what the call's program says of itself."""
 
     def _step_detail(self, sp, ph_admit, ph_prefill, burst, compiles):
         """Where a step went, for a `perf.straggler` record: phase
@@ -369,11 +511,6 @@ class _EngineBase:
         """Times each program has been traced — the no-retrace metric."""
         return dict(self.trace_counts)
 
-    def _warm_programs(self):
-        """Programs that must trace before the watchdog's warmup
-        barrier can be declared (subclasses drop conditional ones)."""
-        return self._programs
-
     def rebind_perf(self, registry):
         """Move the perf instrumentation onto `registry` (the gateway
         replica pattern: engine metrics live on a private per-replica
@@ -388,12 +525,6 @@ class _EngineBase:
                                      tracer=self._tracer)
         return self
 
-    def _perf_target(self):
-        """(jitted_fn, last-dispatch args) for the steady-state program
-        the cost model should price — the decode program by default
-        (the spec-decode engine overrides with its verify program)."""
-        return self._decode_jit, self._decode_args
-
     def perf_estimate(self, bursts=None, wall_seconds=None):
         """Cost-model estimate of the steady-state program (the
         dollar spender): analytic flops/bytes, roofline bound, warm
@@ -404,14 +535,18 @@ class _EngineBase:
         a measurement, not a retrace) and reuses the exact arrays of
         the last dispatch, so the traced avals match and the program's
         trace count stays flat."""
-        jit_fn, args = self._perf_target()
+        # under speculation the verify forward is the steady-state
+        # spender (the plain decode program never dispatches)
+        if self.spec_k and self._verify_args is not None:
+            jit_fn, args = self._verify_jit, self._verify_args
+        else:
+            jit_fn, args = self._decode_jit, self._decode_args
         if args is None:
             return None
         with self._lock, self.perf.suspended():
-            import time as _time
-            t0 = _time.monotonic()
+            t0 = time.monotonic()
             compiled = jit_fn.lower(*args).compile()
-            warm_s = _time.monotonic() - t0
+            warm_s = time.monotonic() - t0
         step_s = None
         if bursts and wall_seconds and bursts > 0:
             step_s = wall_seconds / float(bursts)
@@ -424,6 +559,12 @@ class _EngineBase:
     @property
     def occupancy(self):
         return self.allocator.occupancy
+
+    def _state_in_use(self):
+        """(slots whose recurrent state belongs to a resident, its
+        bytes): zeros for a model that keeps K/V rows only."""
+        slots = self.allocator.in_use if self._state_seq_bytes else 0
+        return slots, slots * self._state_seq_bytes
 
     # ---- scheduler glue (lock held) -----------------------------------
 
@@ -455,16 +596,15 @@ class _EngineBase:
             # prefill end — created here, advanced by the final chunk
             req._key = np.asarray(jax.random.PRNGKey(req.seed))
             # no cache reset needed: the first prefill chunk writes from
-            # the occupant's own offset and its write-back length
-            # unreaches the previous occupant's rows
-            self._bind(slot, req)
+            # the occupant's own offset and its length unreaches the
+            # previous occupant's rows. A prefix hit means rows [0, hit)
+            # are already valid shared pages: the row's length starts
+            # there, not at zero
+            self._lens[slot] = req._consumed
+            if req._prefix_hit and req._span is not None:
+                req._span.add_event('prefix_cache_hit',
+                                    tokens=req._prefix_hit)
         return len(admitted)
-
-    def _bind(self, slot, req):
-        """Subclass hook: extra per-admission state (lock held)."""
-
-    def _on_step_metrics(self):
-        """Subclass hook: extra per-step gauges (lock held)."""
 
     def _trace_prefill(self, req, start, valid, final):
         """Annotate the request's prefill phase span with one chunk; the
@@ -480,9 +620,9 @@ class _EngineBase:
 
     def _prefill_step(self):
         """One chunk per prefilling resident, each its own jitted call
-        (`_prefill_call`, the subclass's); returns (calls, prompt tokens
-        forwarded). A call's span ends after its host sync, so the host
-        time BETWEEN two calls is `serving.step.prefill`'s self time."""
+        (`_prefill_call`); returns (calls, prompt tokens forwarded). A
+        call's span ends after its host sync, so the host time BETWEEN
+        two calls is `serving.step.prefill`'s self time."""
         tr = self._tracer
         calls = tokens = 0
         for req, start, ids, valid, final in self.scheduler.prefill_plan():
@@ -497,8 +637,8 @@ class _EngineBase:
                     tok = int(tok)           # the call's host sync
                 if sp:
                     sp.tags.update(slot=slot, start=start, tokens=valid,
-                                   final=final)
-                    self._tag_prefill_call(sp)
+                                   final=final,
+                                   kv_read=self.kv_read['prefill'])
             calls += 1
             tokens += valid
             self.metrics.on_prefill_tokens(valid)
@@ -565,6 +705,7 @@ class _EngineBase:
         req.outcome = outcome
         slot = req.slot
         self._active[slot] = False
+        self._lens[slot] = 0
         del self._requests[slot]
         self.scheduler.retire(req)     # sets req.kv_page_seconds
         req._finish_t = self.metrics.now()
@@ -613,132 +754,154 @@ class _EngineBase:
             replicas=[],
             outcome=outcome)
 
+    def _on_preempt(self, slot, req, dropped):
+        """PagedScheduler eviction hook (lock held): the victim's pages
+        and slot are already released — freeze the lane so the next
+        decode burst cannot advance it (the freed pages may belong to
+        someone else by then) and close the victim's phase span. A
+        `dropped` victim burned its preemption budget: retire it here
+        with outcome='preempted' (the scheduler already closed its
+        billing window and sets the finished flag after this returns)."""
+        self._active[slot] = False
+        self._lens[slot] = 0
+        self._requests.pop(slot, None)
+        self.metrics.on_preempted(req._tenant_label)
+        if req._phase is not None:
+            req._phase.finish()
+            req._phase = None
+        if req._span is not None:
+            req._span.add_event('preempted', count=req._preempts,
+                                dropped=dropped)
+        if not dropped:
+            return
+        req.outcome = 'preempted'
+        req._finish_t = self.metrics.now()
+        self.metrics.on_retired(req.id)
+        self.metrics.on_tenant_retired(
+            req._tenant_label, req.kv_page_seconds * self._kv_page_bytes)
+        if req._span is not None:
+            req._span.set_tag('tokens', len(req.tokens))
+            req._span.add_event('retired')
+            req._span.finish()
+        self._emit_wide_event(req, 'preempted')
 
-class ContinuousBatchingEngine(_EngineBase):
-    """Slot-based continuous batching over a GPTForCausalLM.
+    # ---- the three compiled programs ----------------------------------
 
-    Every slot reserves `max_len` KV rows (GPTSlotCache); see
-    PagedContinuousBatchingEngine for the page-granular variant with
-    prefix sharing and speculative decoding.
-    """
+    def _unpack(self, program, pools, caches, slot=None):
+        self.kv_read[program] = next(
+            (c.kv_read for c in caches if hasattr(c, 'block_tables')), None)
+        return layer_state(pools, caches, slot)
 
-    def __init__(self, model, num_slots=8, max_len=None, prefill_chunk=16,
-                 decode_block=4, donate=None):
-        super().__init__(model, num_slots, max_len)
-        self.decode_block = int(decode_block)
-        if self.decode_block < 1:
-            raise ValueError('decode_block must be >= 1')
-        self._caches = build_slot_caches(model, self.num_slots, self.max_len)
-        self.allocator = SlotAllocator(self.num_slots)
-        self.scheduler = Scheduler(self.allocator, self.max_len,
-                                   prefill_chunk)
-        # billing unit for kv_byte_seconds: a slot reserves max_len rows
-        self._kv_page_bytes = kv_row_bytes(
-            cache_specs(model)) * self.max_len
-        if donate is None:
-            # cache buffers dominate engine memory; donating them lets
-            # XLA update in place. CPU donation is a no-op that warns.
-            donate = jax.default_backend() in ('tpu', 'gpu')
-        dn = (2,) if donate else ()
-        self._prefill_jit = jax.jit(self._prefill_fn, donate_argnums=dn)
-        self._decode_jit = jax.jit(self._decode_fn, donate_argnums=dn)
-
-    # ---- the two compiled programs ------------------------------------
-
-    def _prefill_fn(self, params, bufs, caches, slot, ids, start, valid,
-                    key, temp, topk, sample):
-        """One [1, C] prompt chunk into slot `slot` at row `start`.
-
-        Only `valid` of the C tokens are real; padded rows write garbage
-        K/V beyond the valid length, which the write-back length
-        (`start + valid`) keeps unreachable (the next chunk or decode
-        step overwrites row start+valid before it becomes visible).
-        Returns the updated caches, the post-chunk logits' pick (only
-        meaningful on the final chunk) and the advanced PRNG key.
-        """
+    def _prefill_fn(self, params, bufs, pools, bt1, len1, ids, valid,
+                    key, temp, topk, sample, slot=None):
+        """One [1, C] prompt chunk through block-table row `bt1` at
+        offset len1. For K/V only `valid` tokens are real, padded-tail
+        writes are garbage the next pass overwrites, and the returned
+        pick matters on the final chunk; the PRNG key advances by one
+        split. A recurrent layer has no dead rows: it works on row
+        `slot` of its state (passed when the model has such a layer),
+        starts from zeros when len1 is 0 and takes `valid` tokens."""
         self.trace_counts['prefill'] += 1
-        small = []
-        for c in caches:
-            ks = jax.lax.dynamic_slice_in_dim(c.k._data, slot, 1, axis=0)
-            vs = jax.lax.dynamic_slice_in_dim(c.v._data, slot, 1, axis=0)
-            small.append(GPTSlotCache(Tensor(ks), Tensor(vs),
-                                      jnp.full((1,), start, jnp.int32)))
-        (lg, small2), _ = _fm.functional_call(
+        caches = layer_caches(self._specs, pools, bt1, len1,
+                              jnp.reshape(valid, (1,)), self.page_size,
+                              slot)
+        (lg, new_cs), _ = _fm.functional_call(
             self._model, params, bufs, args=(Tensor(ids),),
-            kwargs={'caches': small}, training=False)
-        new_caches = []
-        for c, s2 in zip(caches, small2):
-            kb = jax.lax.dynamic_update_slice(
-                c.k._data, s2.k._data, (slot, 0, 0, 0))
-            vb = jax.lax.dynamic_update_slice(
-                c.v._data, s2.v._data, (slot, 0, 0, 0))
-            new_caches.append(GPTSlotCache(
-                Tensor(kb), Tensor(vb),
-                c.lengths.at[slot].set(start + valid)))
+            kwargs={'caches': caches}, training=False)
         last = jax.lax.dynamic_index_in_dim(lg[0], valid - 1, axis=0,
                                             keepdims=False)
         key2, sub = jax.random.split(key)
         tok = _pick_token(last, sub, temp, topk, sample)
-        return new_caches, tok, key2
+        return self._unpack('prefill', pools, new_cs, slot), tok, key2
 
-    def _decode_fn(self, params, bufs, caches, tok, gen, budgets, active,
-                   keys, temps, topks, sample):
-        """K cached decode steps for all slots in one dispatch.
-
-        `step_active` freezes slots that are unoccupied, mid-prefill, or
-        out of budget: their lengths / gen counts / keys do not advance
-        and their fed token repeats, so a frozen slot's garbage logits
-        never leak into state. The scan length is the FIXED decode_block
-        — a finishing slot idles for the burst's remainder rather than
-        shortening it (a variable length would recompile)."""
+    def _decode_fn(self, params, bufs, pools, bt, lens, tok, gen,
+                   budgets, active, keys, temps, topks, sample):
+        """K cached decode steps for all rows in one dispatch, lengths
+        carried through the scan (block tables are per-dispatch
+        constants). `step_active` freezes rows that are unoccupied,
+        mid-prefill or out of budget: their lengths / gen counts / keys
+        do not advance and their fed token repeats, so a frozen lane's
+        garbage logits never leak into state; it writes K/V garbage
+        where nobody reads and keeps its recurrent state bit for bit.
+        The scan length is the FIXED decode_block — a finishing row
+        idles for the burst's remainder rather than shortening it (a
+        variable length would recompile)."""
         self.trace_counts['decode'] += 1
 
         def body(carry, _):
-            caches, tok, gen, keys = carry
+            pools, lens, tok, gen, keys = carry
             step_active = active & (gen < budgets)
+            inc = step_active.astype(jnp.int32)
+            caches = layer_caches(self._specs, pools, bt, lens, inc,
+                                  self.page_size)
             (lg, new_cs), _ = _fm.functional_call(
                 self._model, params, bufs, args=(Tensor(tok),),
                 kwargs={'caches': caches}, training=False)
-            inc = step_active.astype(jnp.int32)
-            new_cs = [GPTSlotCache(c.k, c.v, c.lengths + inc)
-                      for c in new_cs]
-            ks = jax.vmap(jax.random.split)(keys)       # [S, 2, 2]
+            ks = jax.vmap(jax.random.split)(keys)
             subs = ks[:, 1]
             keys2 = jnp.where(step_active[:, None], ks[:, 0], keys)
             nxt = jax.vmap(_pick_token)(lg[:, -1], subs, temps, topks,
                                         sample)
             tok2 = jnp.where(step_active, nxt, tok[:, 0])[:, None]
-            return (new_cs, tok2, gen + inc, keys2), (tok2[:, 0],
-                                                      step_active)
+            return ((self._unpack('decode', pools, new_cs), lens + inc,
+                     tok2, gen + inc, keys2), (tok2[:, 0], step_active))
 
         carry, (toks, actives) = jax.lax.scan(
-            body, (caches, tok, gen, keys), None, length=self.decode_block)
-        new_caches, tok2, gen2, keys2 = carry
-        return new_caches, tok2, gen2, keys2, toks, actives
+            body, (pools, lens, tok, gen, keys), None,
+            length=self.decode_block)
+        pools2, lens2, tok2, gen2, keys2 = carry
+        return pools2, lens2, tok2, gen2, keys2, toks, actives
+
+    def _verify_fn(self, params, bufs, pools, bt, lens, toks):
+        """ONE forward over [S, K+1] rows: position 0 feeds each row's
+        last emitted token, positions 1..K feed its drafts. Returns the
+        greedy pick after every position — pick i is the model's true
+        next token given [..., tok_0..tok_i], which is what the host
+        accept rule compares drafts against. Writes land at lens..
+        lens+K; rows past what acceptance advances are garbage the next
+        pass overwrites (or scratch-mapped, past the reservation)."""
+        self.trace_counts['verify'] += 1
+        # (no `valid`: a model with a recurrent layer never gets here)
+        caches = layer_caches(self._specs, pools, bt, lens, None,
+                              self.page_size)
+        (lg, new_cs), _ = _fm.functional_call(
+            self._model, params, bufs, args=(Tensor(toks),),
+            kwargs={'caches': caches}, training=False)
+        picks = jnp.argmax(lg.astype(jnp.float32), axis=-1).astype(
+            jnp.int32)
+        return self._unpack('verify', pools, new_cs), picks
 
     # ---- per-step dispatches (lock held) ------------------------------
 
     def _prefill_call(self, req, start, ids, valid):
-        self._caches, tok, key2 = self._prefill_jit(
-            self._params, self._bufs, self._caches,
-            np.int32(req.slot),
+        slot = req.slot
+        # the program learns its slot only where a layer's state lives
+        # per slot; a model of K/V rows alone is addressed by `bt1`
+        where = (np.int32(slot),) if self._state_seq_bytes else ()
+        self._pools, tok, key2 = self._prefill_jit(
+            self._params, self._bufs, self._pools,
+            self.scheduler.block_tables[slot:slot + 1],
+            np.asarray([start], np.int32),
             np.asarray(ids, np.int32)[None, :],
-            np.int32(start), np.int32(valid), req._key,
+            np.int32(valid), req._key,
             np.float32(req.temperature), np.int32(req.top_k),
-            np.asarray(req.do_sample))
+            np.asarray(req.do_sample), *where)
+        self._lens[slot] = start + valid
         return tok, key2
 
     def _decode_step(self):
         slots = self.scheduler.decode_slots()
         if not slots:
             return
+        if self.spec_k:
+            return self._spec_step(slots)
         # the span covers dispatch AND the device_get sync — the burst's
         # actual wall time, not just the async enqueue; `_burst_done`
-        # splits the same window into host_dispatch (enqueue returns)
-        # and device_block (results ready). Dispatch args are stashed
-        # for perf_estimate's cost-model lowering (same avals, no
-        # retrace).
-        args = (self._params, self._bufs, self._caches, self._last,
+        # splits the same window (host_dispatch vs device_block) and the
+        # dispatch args are stashed for perf_estimate's cost-model
+        # lowering (identical avals, so no retrace).
+        args = (self._params, self._bufs, self._pools,
+                self.scheduler.block_tables, self._lens, self._last,
                 self._gen, self._budgets, self._active, self._keys,
                 self._temps, self._topks, self._sample)
         self._decode_args = args
@@ -748,14 +911,14 @@ class ContinuousBatchingEngine(_EngineBase):
                 'serving.decode_burst', annotate=True, mono=t0,
                 tags={'rows': len(slots),
                       'block': self.decode_block}) as sp:
-            (self._caches, last, gen, keys, toks,
+            (self._pools, lens, last, gen, keys, toks,
              actives) = self._decode_jit(*args)
             t1 = clock()
-            last, gen, keys, toks, actives = jax.device_get(
-                (last, gen, keys, toks, actives))
-            burst = self._burst_done(sp, t0, t1, clock())
-        # device_get can hand back read-only views; these three are
-        # mutated in place at prefill/retire
+            lens, last, gen, keys, toks, actives = jax.device_get(
+                (lens, last, gen, keys, toks, actives))
+            burst = self._burst_done(sp, t0, t1, clock(),
+                                     kv_read=self.kv_read['decode'])
+        self._lens = np.array(lens)
         self._last = np.array(last)
         self._gen = np.array(gen)
         self._keys = np.array(keys)
@@ -764,6 +927,60 @@ class ContinuousBatchingEngine(_EngineBase):
             new = [int(toks[k, slot]) for k in range(toks.shape[0])
                    if actives[k, slot]]
             self._emit(req, new)
+            if len(req.tokens) >= req.max_new_tokens:
+                self._retire(req)
+        return burst
+
+    def _spec_step(self, slots):
+        """Draft K tokens per decoding row, verify all rows in ONE
+        [S, K+1] forward, accept each row's longest draft prefix that
+        matches the model's own greedy picks, plus the pick after it
+        (the 'bonus' token — free, since the verify forward already
+        computed it). Worst case (0 accepted) this emits 1 token per
+        row, exactly a decode step; best case K+1."""
+        K = self.spec_k
+        toks = np.zeros((self.num_slots, K + 1), np.int32)
+        drafts = {}
+        for slot in slots:
+            req = self._requests[slot]
+            d = self._proposer.propose(req.prompt + req.tokens, K)
+            drafts[slot] = d
+            toks[slot, 0] = self._last[slot, 0]
+            toks[slot, 1:] = d
+        args = (self._params, self._bufs, self._pools,
+                self.scheduler.block_tables, self._lens, toks)
+        self._verify_args = args
+        clock = self.metrics.now
+        t0 = clock()
+        with self._tracer.start_span(
+                'serving.decode_burst', annotate=True, mono=t0,
+                tags={'rows': len(slots), 'spec_k': K}) as sp:
+            self._pools, picks = self._verify_jit(*args)
+            t1 = clock()
+            picks = np.asarray(jax.device_get(picks))
+            burst = self._burst_done(sp, t0, t1, clock(),
+                                     kv_read=self.kv_read['verify'])
+        for slot in slots:
+            req = self._requests[slot]
+            d, g = drafts[slot], picks[slot]
+            a = 0
+            while a < K and d[a] == int(g[a]):
+                a += 1
+            # accepted drafts + the bonus pick, clipped to budget; a
+            # decoding row always has budget left (it would have retired
+            # otherwise), so at least one token emits and lens advances
+            left = int(self._budgets[slot]) - int(self._gen[slot])
+            emit = [int(x) for x in g[:min(a + 1, left)]]
+            self.metrics.on_spec(K, max(len(emit) - 1, 0))
+            req._spec_proposed += K
+            req._spec_accepted += max(len(emit) - 1, 0)
+            if req._span is not None:
+                req._span.add_event('spec_accept', proposed=K,
+                                    accepted=max(len(emit) - 1, 0))
+            self._lens[slot] += len(emit)
+            self._gen[slot] += len(emit)
+            self._last[slot, 0] = emit[-1]
+            self._emit(req, emit)
             if len(req.tokens) >= req.max_new_tokens:
                 self._retire(req)
         return burst

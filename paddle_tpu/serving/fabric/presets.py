@@ -27,8 +27,8 @@ from ...framework import io_save
 __all__ = ['PRESETS', 'preset', 'build_model', 'build_engine',
            'publish_preset', 'host_factory']
 
-# model: GPTConfig kwargs. engine: 'slot' | 'paged'. engine_kwargs:
-# engine constructor kwargs. seed: global RNG seed pinned per preset.
+# model: GPTConfig kwargs. engine_kwargs: engine constructor kwargs.
+# seed: global RNG seed pinned per preset.
 PRESETS = {
     # the test-suite workhorse: matches the serving test fixtures so a
     # worker process and an in-proc reference engine are token-identical
@@ -36,30 +36,27 @@ PRESETS = {
         'model': dict(vocab_size=211, hidden_size=64, num_layers=2,
                       num_heads=4, max_position_embeddings=128,
                       dropout=0.0),
-        'engine': 'slot',
-        'engine_kwargs': dict(num_slots=2, max_len=32, prefill_chunk=8,
-                              decode_block=2),
+        'engine_kwargs': dict(num_seqs=2, max_len=32, page_size=8,
+                              prefill_chunk=8, decode_block=2),
         'seed': 7,
     },
-    # same weights, paged KV with the prefix cache on — the preset the
+    # same weights, more and longer sequences — the preset the
     # prefix-affinity routing bench runs, where directory hits matter
     'gpt-nano-paged': {
         'model': dict(vocab_size=211, hidden_size=64, num_layers=2,
                       num_heads=4, max_position_embeddings=128,
                       dropout=0.0),
-        'engine': 'paged',
         'engine_kwargs': dict(num_seqs=4, max_len=64, page_size=8,
                               prefill_chunk=8, decode_block=2,
                               prefix_cache=True),
         'seed': 7,
     },
-    # bench-sized: the CPU serving-bench config (bench_extra) with a
-    # paged engine big enough for Poisson bursts over real sockets
+    # bench-sized: the CPU serving-bench config (bench_extra), big
+    # enough for Poisson bursts over real sockets
     'gpt-micro': {
         'model': dict(vocab_size=512, hidden_size=128, num_layers=2,
                       num_heads=4, max_position_embeddings=256,
                       dropout=0.0),
-        'engine': 'paged',
         'engine_kwargs': dict(num_seqs=8, max_len=128, page_size=16,
                               prefill_chunk=16, decode_block=4,
                               prefix_cache=True),
@@ -76,7 +73,6 @@ def preset(name):
         raise KeyError('unknown preset %r; available: %s'
                        % (name, sorted(PRESETS))) from None
     return {'model': dict(spec['model']),
-            'engine': spec['engine'],
             'engine_kwargs': dict(spec['engine_kwargs']),
             'seed': spec['seed']}
 
@@ -101,16 +97,13 @@ def build_engine(name, model=None, state_dict=None, **overrides):
     """The preset's engine around `model` (built fresh if omitted).
     `overrides` patch engine kwargs (e.g. spec_k for a spec-decode
     variant) without forking the preset."""
-    from ..engine import ContinuousBatchingEngine
-    from ..paged_engine import PagedContinuousBatchingEngine
+    from ..engine import PagedContinuousBatchingEngine
     spec = preset(name)
     if model is None:
         model = build_model(name, state_dict=state_dict)
     kwargs = spec['engine_kwargs']
     kwargs.update(overrides)
-    cls = PagedContinuousBatchingEngine if spec['engine'] == 'paged' \
-        else ContinuousBatchingEngine
-    return cls(model, **kwargs)
+    return PagedContinuousBatchingEngine(model, **kwargs)
 
 
 def publish_preset(registry, name, version='v0'):

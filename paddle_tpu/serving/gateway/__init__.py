@@ -2,9 +2,9 @@
 
 Turns one continuous-batching engine into a self-healing pool:
 
-    gw = ServingGateway(lambda: ContinuousBatchingEngine(model, ...),
-                        replicas=2,
-                        autoscaler=AutoscalePolicy(slo_ttft_s=0.5))
+    gw = ServingGateway(
+        lambda: PagedContinuousBatchingEngine(model, ...), replicas=2,
+        autoscaler=AutoscalePolicy(slo_ttft_s=0.5))
     gw.start()
     req = gw.submit(prompt, max_new_tokens=32)
     req.wait(); req.tokens      # token-identical to a single engine

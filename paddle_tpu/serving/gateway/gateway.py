@@ -1,7 +1,7 @@
 """The gateway: one front door over a pool of engine replicas.
 
-    gw = ServingGateway(lambda: ContinuousBatchingEngine(model, ...),
-                        replicas=2)
+    gw = ServingGateway(
+        lambda: PagedContinuousBatchingEngine(model, ...), replicas=2)
     req = gw.submit(prompt, max_new_tokens=32)
     gw.run()                       # or gw.start() for driver threads
     req.tokens                     # identical to a single engine's output

@@ -1,8 +1,7 @@
 """One engine replica behind the gateway: transport shim + lifecycle.
 
-An InprocReplica wraps a ContinuousBatchingEngine (or the paged
-variant) running in this process and gives it the same *shape* as a
-remote worker:
+An InprocReplica wraps a PagedContinuousBatchingEngine running in this
+process and gives it the same *shape* as a remote worker:
 
 - an endpoint string ('inproc://gw-replica-N') that chaos injectors
   scope to — every submission fires the resilience 'send' hook and

@@ -1,45 +1,41 @@
 """Slot and page bookkeeping for the serving caches, and the per-layer
-cache interface between the engines and a model.
+cache interface between the engine and a model.
 
-Two K/V layouts share this host module:
+`PagedKVCache` (text/models/cache.py): per layer, a K pool and a V pool
+of `num_pages * page_size` token rows, `[G, rows, W]` — a row is `W`
+lanes wide (one head of 128, or the narrower heads that fill 128 side by
+side), the `G` groups outermost: the one layout the write and the read
+both take as it lies, so no program copies a pool — addressed through
+per-sequence block tables: a sequence only holds the pages it needs, and
+sequences sharing a prompt prefix map their leading block-table entries
+to the SAME physical page. `PageAllocator` (refcounted free list) and
+`PrefixCache` (block-hash -> page, LRU) own the host side;
+`SlotAllocator` owns which sequence rows (slots) are free and who holds
+them.
 
-- `GPTSlotCache` (text/models/gpt.py): per layer, fixed
-  [num_slots, max_len, H, Dh] buffers plus a per-slot valid-length
-  vector — every slot reserves `max_len` rows. `SlotAllocator` owns
-  which slots are free and who holds them.
-- `PagedKVCache` (text/models/cache.py): per layer, a K pool and a V
-  pool of `num_pages * page_size` token rows, `[G, rows, W]` — a row is
-  `W` lanes wide (one head of 128, or the narrower heads that fill 128
-  side by side), the `G` groups outermost: the one layout the write and
-  the read both take as it lies, so no program copies a pool — addressed
-  through per-sequence block tables: a sequence only holds the pages it
-  needs, and sequences sharing a prompt prefix map their leading
-  block-table entries to the SAME physical page. `PageAllocator`
-  (refcounted free list) and `PrefixCache` (block-hash -> page, LRU) own
-  the host side.
+Beside pages, the engine keeps a second kind of state for a layer that
+names it (`RecurrentSpec`): `[num_seqs, ...]` arrays that belong to a
+SLOT, not to pages — see "the per-layer cache interface" below.
 
-Beside pages, the paged engine keeps a second kind of state for a layer
-that names it (`RecurrentSpec`): `[num_seqs, ...]` arrays that belong to
-a SLOT, not to pages — see "the per-layer cache interface" below.
-
-Neither K/V layout needs buffer clearing on reuse: a new occupant's prefill
-writes from its own offset 0 and the validity mask never lets a query
-see rows at/beyond the owning sequence's current length, so a previous
-occupant's rows are unreachable the moment the length resets (the
-engine's first prefill chunk writes back the new occupant's own length).
+The K/V pools need no clearing on reuse: a new occupant's prefill writes
+from its own offset and the validity mask never lets a query see rows
+at/beyond the owning sequence's current length, so a previous occupant's
+rows are unreachable the moment the length resets (the engine's first
+prefill chunk writes back the new occupant's own length).
 """
 import heapq
 import time
 from collections import OrderedDict
 
-__all__ = ['SlotAllocator', 'build_slot_caches', 'PageAllocator',
+__all__ = ['SlotAllocator', 'PageAllocator',
            'PrefixCache', 'build_paged_pools', 'SCRATCH_PAGE',
            'cache_specs', 'kv_row_bytes', 'state_bytes_per_seq',
            'layer_caches', 'layer_state']
 
 
 class SlotAllocator:
-    """Free-list over a fixed number of KV-cache slots.
+    """Free-list over a fixed number of sequence slots (the rows of the
+    engine's block tables and per-sequence state).
 
     Lowest-index-first allocation (a heap, not a LIFO stack) keeps slot
     assignment deterministic for a given arrival order — parity tests
@@ -89,10 +85,6 @@ class SlotAllocator:
         self._owner[slot] = owner
         self._held_since[slot] = now
         return slot
-
-    def held_since(self, slot):
-        """The integral timestamp at which `slot` was allocated."""
-        return self._held_since.get(slot)
 
     def free(self, slot):
         """Release `slot` back to the free list; returns the seconds it
@@ -332,9 +324,9 @@ class PrefixCache:
 # A model says what each of its layers keeps (`model.cache_specs()`,
 # text/models/cache.py): rows of K and V in the page pool, or a fixed
 # set of arrays per sequence that every token rewrites. Everything the
-# engines hold on the device is built from those specs here, and so are
-# the cache objects a dispatch hands the model; no engine reads a
-# model's attributes.
+# engine holds on the device is built from those specs here, and so are
+# the cache objects a dispatch hands the model; the engine reads none of
+# a model's attributes.
 
 def cache_specs(model):
     """The model's per-layer specs, a list with one entry per layer."""
@@ -375,7 +367,7 @@ def state_bytes_per_seq(specs):
 
 
 def build_paged_pools(model, num_pages, page_size, num_seqs=0):
-    """The paged engine's persistent device state, one entry per layer:
+    """The engine's persistent device state, one entry per layer:
     a (k_pool, v_pool) pair `[G, num_pages * page_size, W]` (pool row
     `page * page_size + r`; `cache.paged_pool_shape`) for a layer that
     keeps K/V rows, a tuple of `[num_seqs, ...]` arrays for a recurrent
@@ -439,21 +431,3 @@ def layer_state(state, caches, slot=None):
                     a, new.astype(a.dtype), slot, axis=0)
                 for a, new in zip(old, c.arrays)))
     return out
-
-
-def build_slot_caches(model, num_slots, max_len):
-    """One GPTSlotCache per transformer layer of a model whose layers
-    all keep K/V rows (a slot reserves `max_len` of them)."""
-    from ..text.models.gpt import GPTSlotCache
-    config = model.config
-    if max_len > config.max_position_embeddings:
-        raise ValueError(
-            'slot capacity %d exceeds max_position_embeddings %d'
-            % (max_len, config.max_position_embeddings))
-    specs = cache_specs(model)
-    if not all(_is_paged(s) for s in specs):
-        raise ValueError(
-            'the slot engine keeps K/V rows only: serve a model with '
-            'recurrent layers through PagedContinuousBatchingEngine')
-    return [GPTSlotCache.empty(num_slots, max_len, s.num_heads, s.head_dim,
-                               dtype=s.dtype) for s in specs]

@@ -73,9 +73,7 @@ class ServingMetrics:
         self._m_prefill = req['serving_prefill_tokens_total']
         self._m_prefill_calls = req['serving_prefill_calls_total']
         self._m_admit_blocked = req['serving_admit_blocked_total']
-        # paged-engine families; registered unconditionally (zeros for
-        # the slot engine) so the scrape schema does not depend on which
-        # engine a process happens to run
+        # page, state, prefix-cache and speculation families
         paged = record_serving_schema(r)
         self._m_pages = paged['serving_kv_pages_in_use']
         self._m_state_bytes = paged['serving_state_bytes']

@@ -201,7 +201,7 @@ class ModelHost:
         req._emit_event = bool(emit_event)
         if stream:
             req._stream_q = _queue.Queue()
-        # the engines' shared front-door guard, verbatim, so impossible
+        # the engine's front-door guard, verbatim, so impossible
         # requests fail here even before their model's engine exists
         worst = len(req.prompt) + req.max_new_tokens - 1
         if self.max_len and len(req.prompt) and worst > self.max_len:

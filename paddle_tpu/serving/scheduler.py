@@ -2,8 +2,9 @@
 
 Policy (Orca-style iteration-level scheduling, FIFO within a step):
 
-  1. ADMIT:  while a slot is free and a request is queued, bind the
-     oldest request to the lowest free slot (deterministic layout).
+  1. ADMIT:  while a slot is free and the head request's pages can be
+     reserved, bind the oldest request to the lowest free slot
+     (deterministic layout).
   2. PREFILL: every resident request still consuming its prompt advances
      by exactly ONE fixed-size chunk per step — chunking bounds the
      latency bubble a long prompt injects between decode steps, the
@@ -11,11 +12,11 @@ Policy (Orca-style iteration-level scheduling, FIFO within a step):
      completion on arrival.
   3. DECODE: all slots whose prompt is fully consumed take one decode
      burst together (engine-side); finished sequences retire and their
-     slots return to the free list the same step.
+     slots and pages return to the free lists the same step.
 
-Everything here is host-side bookkeeping with plain Python ints (plus
-host numpy block tables for the paged variant) — the scheduler never
-touches device arrays, so it cannot cause a retrace.
+Everything here is host-side bookkeeping with plain Python ints plus
+host numpy block tables — the scheduler never touches device arrays, so
+it cannot cause a retrace.
 """
 import itertools
 import threading
@@ -25,7 +26,7 @@ import numpy as np
 
 from .kv_cache import SCRATCH_PAGE
 
-__all__ = ['Request', 'Scheduler', 'PagedScheduler']
+__all__ = ['Request', 'PagedScheduler']
 
 _req_ids = itertools.count()
 
@@ -74,7 +75,7 @@ class Request:
         self._key = None          # PRNG key, set at admission
         self._consumed = 0        # prompt tokens already prefilled
         self._prefix_hit = 0      # prompt tokens served by the prefix
-        #                           cache (paged engine; 0 elsewhere)
+        #                           cache
         self._published = 0       # prompt blocks already in the cache
         self._seq = None          # submission order, set by the scheduler
         self._preempts = 0        # times this request lost its KV pages
@@ -110,138 +111,9 @@ class Request:
                    self.max_new_tokens))
 
 
-class Scheduler:
-    """Admission + chunked-prefill planner over a SlotAllocator."""
-
-    def __init__(self, allocator, max_len, prefill_chunk):
-        if prefill_chunk < 1:
-            raise ValueError('prefill_chunk must be >= 1')
-        self.allocator = allocator
-        self.max_len = int(max_len)
-        self.prefill_chunk = int(prefill_chunk)
-        self.queue = deque()
-        self.resident = {}        # slot -> Request (PREFILL or DECODE)
-        self._submit_seq = itertools.count()
-        # why the last admit() pass left its head queued: 'slots',
-        # 'pages', or 'none' when it emptied the queue
-        self.head_left = 'none'
-
-    def submit(self, req):
-        """Validate capacity and enqueue. Raises on impossible requests —
-        a request that can never fit must fail at the front door, not
-        wedge the queue forever."""
-        n0 = len(req.prompt)
-        if n0 < 1:
-            raise ValueError('empty prompt')
-        if req.max_new_tokens < 1:
-            raise ValueError('max_new_tokens must be >= 1')
-        c = self.prefill_chunk
-        padded = ((n0 + c - 1) // c) * c
-        # two capacity constraints: the final sequence must fit, and the
-        # PADDED last prefill chunk must land inside the buffer (a
-        # clamped dynamic_update_slice would silently shift the write)
-        need = max(n0 + req.max_new_tokens - 1, padded)
-        if need > self.max_len:
-            raise ValueError(
-                'request needs %d cache rows (prompt %d + %d new tokens, '
-                'prefill padding to %d) but slots hold %d'
-                % (need, n0, req.max_new_tokens, padded, self.max_len))
-        req._seq = next(self._submit_seq)
-        self.queue.append(req)
-
-    def _pick_index(self):
-        """Index of the next request to admit: highest priority first,
-        submission order (_seq) within a class — so with uniform
-        priorities this is index 0, the exact historical FIFO, and a
-        preempted request (which keeps its original _seq) resumes ahead
-        of later arrivals of its own class."""
-        best = 0
-        for i in range(1, len(self.queue)):
-            r, b = self.queue[i], self.queue[best]
-            if (r.priority, -r._seq) > (b.priority, -b._seq):
-                best = i
-        return best
-
-    def _note_left(self, head, cause):
-        """The admit pass is over: count it against every request it
-        left queued — the head for `cause`, the rest for queueing behind
-        a blocked head (FIFO: nobody skips ahead)."""
-        self.head_left = cause if self.queue else 'none'
-        for r in self.queue:
-            key = cause if r is head else 'behind_head'
-            r._admit_waits[key] = r._admit_waits.get(key, 0) + 1
-
-    def admit(self):
-        """Bind queued requests to free slots; returns [(slot, req)]."""
-        admitted = []
-        while self.queue and self.allocator.available:
-            i = self._pick_index()
-            req = self.queue[i]
-            del self.queue[i]
-            slot = self.allocator.alloc(req.id)
-            req.slot = slot
-            req.state = PREFILL
-            req._consumed = 0
-            # holding window opens on the allocator's own advance
-            # timestamp, so per-request durations sum exactly to the
-            # pool-occupancy integral
-            req._kv_hold_t = self.allocator.held_since(slot)
-            self.resident[slot] = req
-            admitted.append((slot, req))
-        # the loop ends on an empty queue or on no free slot
-        self._note_left(self.queue[self._pick_index()] if self.queue
-                        else None, 'slots')
-        return admitted
-
-    def prefill_plan(self):
-        """One chunk per prefilling request: [(req, start, ids, valid,
-        final)] where ids is exactly prefill_chunk tokens (zero-padded
-        past `valid`) so the jitted chunk program has one shape."""
-        plan = []
-        c = self.prefill_chunk
-        for slot in sorted(self.resident):
-            req = self.resident[slot]
-            if req.state != PREFILL:
-                continue
-            start = req._consumed
-            valid = min(c, len(req.prompt) - start)
-            ids = req.prompt[start:start + valid] + [0] * (c - valid)
-            plan.append((req, start, ids, valid,
-                         start + valid >= len(req.prompt)))
-        return plan
-
-    def mark_prefilled(self, req, consumed):
-        req._consumed = consumed
-        req._prefill_chunks += 1
-        if req._consumed >= len(req.prompt):
-            req.state = DECODE
-
-    def decode_slots(self):
-        return [s for s in sorted(self.resident)
-                if self.resident[s].state == DECODE]
-
-    def retire(self, req):
-        """Release a finished request's slot and wake any waiters."""
-        slot = req.slot
-        del self.resident[slot]
-        # one slot is the allocation granule: page·seconds == slot·seconds
-        req.kv_page_seconds = self.allocator.free(slot)
-        req.state = DONE
-        req.slot = None
-        if req._stream_q is not None:
-            req._stream_q.put(None)   # stream sentinel: end of tokens
-        req._finished.set()
-
-    @property
-    def pending(self):
-        """Requests not yet DONE anywhere in the system."""
-        return len(self.queue) + len(self.resident)
-
-
-class PagedScheduler(Scheduler):
-    """Page-aware admission over a PageAllocator + optional PrefixCache.
-
-    Same FIFO iteration-level policy as Scheduler, with two additions:
+class PagedScheduler:
+    """Admission + chunked-prefill planner over a SlotAllocator, a
+    PageAllocator and an optional PrefixCache.
 
     - ADMIT reserves the request's ENTIRE page need up front (prefix-hit
       blocks are shared via incref, the rest freshly allocated). Because
@@ -273,9 +145,19 @@ class PagedScheduler(Scheduler):
 
     def __init__(self, allocator, pages, max_len, prefill_chunk,
                  page_size, prefix_cache=None):
-        super().__init__(allocator, max_len, prefill_chunk)
+        if prefill_chunk < 1:
+            raise ValueError('prefill_chunk must be >= 1')
         if page_size < 1:
             raise ValueError('page_size must be >= 1')
+        self.allocator = allocator
+        self.max_len = int(max_len)
+        self.prefill_chunk = int(prefill_chunk)
+        self.queue = deque()
+        self.resident = {}        # slot -> Request (PREFILL or DECODE)
+        self._submit_seq = itertools.count()
+        # why the last admit() pass left its head queued: 'slots',
+        # 'pages', or 'none' when it emptied the queue
+        self.head_left = 'none'
         self.pages = pages
         self.page_size = int(page_size)
         self.prefix = prefix_cache
@@ -317,7 +199,31 @@ class PagedScheduler(Scheduler):
         req._seq = next(self._submit_seq)
         self.queue.append(req)
 
+    def _pick_index(self):
+        """Index of the next request to admit: highest priority first,
+        submission order (_seq) within a class — so with uniform
+        priorities this is index 0, the exact historical FIFO, and a
+        preempted request (which keeps its original _seq) resumes ahead
+        of later arrivals of its own class."""
+        best = 0
+        for i in range(1, len(self.queue)):
+            r, b = self.queue[i], self.queue[best]
+            if (r.priority, -r._seq) > (b.priority, -b._seq):
+                best = i
+        return best
+
+    def _note_left(self, head, cause):
+        """The admit pass is over: count it against every request it
+        left queued — the head for `cause`, the rest for queueing behind
+        a blocked head (FIFO: nobody skips ahead)."""
+        self.head_left = cause if self.queue else 'none'
+        for r in self.queue:
+            key = cause if r is head else 'behind_head'
+            r._admit_waits[key] = r._admit_waits.get(key, 0) + 1
+
     def admit(self):
+        """Bind queued requests to free slots and reserved pages;
+        returns [(slot, req)]."""
         admitted = []
         head, cause = None, 'none'
         while self.queue:
@@ -451,8 +357,28 @@ class PagedScheduler(Scheduler):
             req._finished.set()
         return not dropped
 
+    def prefill_plan(self):
+        """One chunk per prefilling request: [(req, start, ids, valid,
+        final)] where ids is exactly prefill_chunk tokens (zero-padded
+        past `valid`) so the jitted chunk program has one shape."""
+        plan = []
+        c = self.prefill_chunk
+        for slot in sorted(self.resident):
+            req = self.resident[slot]
+            if req.state != PREFILL:
+                continue
+            start = req._consumed
+            valid = min(c, len(req.prompt) - start)
+            ids = req.prompt[start:start + valid] + [0] * (c - valid)
+            plan.append((req, start, ids, valid,
+                         start + valid >= len(req.prompt)))
+        return plan
+
     def mark_prefilled(self, req, consumed):
-        super().mark_prefilled(req, consumed)
+        req._consumed = consumed
+        req._prefill_chunks += 1
+        if req._consumed >= len(req.prompt):
+            req.state = DECODE
         if self.prefix is None:
             return
         # publish every prompt block this chunk completed: its page now
@@ -464,7 +390,13 @@ class PagedScheduler(Scheduler):
             self.prefix.publish(req.prompt, b, int(row[b]))
         req._published = max(req._published, done)
 
+    def decode_slots(self):
+        return [s for s in sorted(self.resident)
+                if self.resident[s].state == DECODE]
+
     def retire(self, req):
+        """Release a finished request's pages and slot and wake any
+        waiters."""
         slot = req.slot
         row = self.block_tables[slot]
         nblocks = self._nblocks.pop(slot, 0)
@@ -475,11 +407,22 @@ class PagedScheduler(Scheduler):
             if row[b] != SCRATCH_PAGE:
                 self.pages.decref(int(row[b]))
         row[:] = SCRATCH_PAGE
-        super().retire(req)
-        # super() set the SLOT holding time; this engine bills PAGES:
-        # every reserved page, shared prefix hits included (the tenant
-        # pinned them for its whole residency even if another tenant
-        # also mapped them — see PageAllocator._advance for why the
-        # per-request sum can exceed the pool integral under sharing).
-        # _kv_acc carries windows closed out by earlier preemptions.
+        del self.resident[slot]
+        self.allocator.free(slot)
+        # the engine bills PAGES: every reserved page, shared prefix
+        # hits included (the tenant pinned them for its whole residency
+        # even if another tenant also mapped them — see
+        # PageAllocator._advance for why the per-request sum can exceed
+        # the pool integral under sharing). _kv_acc carries windows
+        # closed out by earlier preemptions.
         req.kv_page_seconds = req._kv_acc + nblocks * held
+        req.state = DONE
+        req.slot = None
+        if req._stream_q is not None:
+            req._stream_q.put(None)   # stream sentinel: end of tokens
+        req._finished.set()
+
+    @property
+    def pending(self):
+        """Requests not yet DONE anywhere in the system."""
+        return len(self.queue) + len(self.resident)

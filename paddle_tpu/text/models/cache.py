@@ -49,8 +49,8 @@ def _raw_leaf(x):
 
 
 class PagedKVCache:
-    """Block/page-granular KV cache for the paged serving engine
-    (paddle_tpu/serving/paged_engine.py): per layer, a physical K pool
+    """Block/page-granular KV cache for the serving engine
+    (paddle_tpu/serving/engine.py): per layer, a physical K pool
     and V pool `[G, num_pages * page_size, W]` (`paged_pool_shape`) plus
     a per-sequence BLOCK TABLE `[B, max_blocks]` (int32 page ids) and
     per-sequence valid lengths `[B]`. A sequence's logical row j lives in
@@ -298,16 +298,14 @@ def _paged_call(q, k, v, ck, cv, bt, t, *, page, scope, read):
     return out, ck, cv
 
 
-def paged_attention(q, k, v, cache, scope, choose_read=paged_kv_read):
+def paged_attention(q, k, v, cache, scope):
     """Write this call's K/V rows `[B, n, H, Dh]` into `cache`'s pools at
     each row's length and attend q over what the row then holds, causally.
     Returns (attention output `[B, n, H, Dh]` as a Tensor, the cache with
     the new pools and `kv_read` set). `scope` prefixes the named scopes
     of the device ops (`<scope>.paged_write`, `.paged_gather`, `.mask`,
     `.core`). The read is chosen by shape at trace time (`paged_kv_read`;
-    same arithmetic either way, keys only come in another order);
-    `choose_read` is `paged_kv_read` unless the caller looks it up
-    elsewhere."""
+    same arithmetic either way, keys only come in another order)."""
     q, k, v = _raw_leaf(q), _raw_leaf(k), _raw_leaf(v)
     ck, cv = _raw_leaf(cache.k), _raw_leaf(cache.v)
     n, heads, head_dim = q.shape[1:]
@@ -326,7 +324,7 @@ def paged_attention(q, k, v, cache, scope, choose_read=paged_kv_read):
         raise ValueError(
             'paged cache overflow: max row length %d + %d new '
             'tokens > capacity %d' % (int(jnp.max(t)), n, L))
-    read = choose_read(q.shape[0], L, pool_rows)
+    read = paged_kv_read(q.shape[0], L, pool_rows)
     out, ck, cv = _paged_call(q, k, v, ck, cv, bt, jnp.asarray(t), page=page,
                               scope=scope, read=read)
     new_cache = PagedKVCache(Tensor(ck), Tensor(cv), bt, t, page)

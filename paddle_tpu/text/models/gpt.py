@@ -15,8 +15,7 @@ from ...framework.core import Tensor, no_grad_guard
 from ...nn import functional as F
 from ...tensor import manipulation as M
 from .cache import PagedKVCache as GPTPagedCache
-from .cache import (PagedKVSpec, _raw_leaf, _tensor_leaf, paged_attention,
-                    paged_kv_read)
+from .cache import PagedKVSpec, _raw_leaf, _tensor_leaf, paged_attention
 
 __all__ = ['GPTConfig', 'GPTModel', 'GPTForCausalLM']
 
@@ -125,55 +124,6 @@ jax.tree_util.register_pytree_node(GPTStaticCache, _cache_flatten,
                                    _cache_unflatten)
 
 
-class GPTSlotCache:
-    """Slot-batched KV cache for continuous-batching serving
-    (paddle_tpu.serving): fixed [num_slots, max_len, H, Dh] buffers plus a
-    PER-SLOT valid length vector [num_slots] (int32). Unlike
-    GPTStaticCache's single scalar length, each slot advances
-    independently — the compiled decode step keeps ONE static shape no
-    matter which requests currently occupy which slots, so request
-    admit/retire churn never retraces.
-
-    Invariants (the serving engine owns them):
-      - attention WRITES the new k/v at each slot's current length but
-        does NOT advance `lengths` — the engine advances them after the
-        full forward (every layer must write at the same pre-step
-        offsets, and padded prefill tails advance by the VALID token
-        count, not the chunk size);
-      - buffer rows at/beyond a slot's length are garbage (padded prefill
-        tails, stale rows from a retired occupant) and are never attended:
-        the validity mask allows k positions <= the query's absolute
-        position, which never exceeds lengths + n - 1;
-      - overflow is guarded at admission (host side): a traced lengths
-        vector cannot be range-checked in-program.
-    """
-
-    def __init__(self, k_buf, v_buf, lengths):
-        self.k = k_buf
-        self.v = v_buf
-        self.lengths = lengths  # [num_slots] int32 (traced under jit)
-
-    @staticmethod
-    def empty(num_slots, max_len, num_heads, head_dim, dtype='float32'):
-        import paddle_tpu as paddle
-        k = paddle.zeros([num_slots, max_len, num_heads, head_dim], dtype)
-        v = paddle.zeros([num_slots, max_len, num_heads, head_dim], dtype)
-        return GPTSlotCache(k, v, jnp.zeros((num_slots,), jnp.int32))
-
-
-def _slot_cache_flatten(c):
-    return (_raw_leaf(c.k), _raw_leaf(c.v), c.lengths), None
-
-
-def _slot_cache_unflatten(_, children):
-    k, v, lengths = children
-    return GPTSlotCache(_tensor_leaf(k), _tensor_leaf(v), lengths)
-
-
-jax.tree_util.register_pytree_node(GPTSlotCache, _slot_cache_flatten,
-                                   _slot_cache_unflatten)
-
-
 def _cache_get(cache, key, build, cap=8):
     """Bounded per-model compiled-executable cache: a serving loop with
     naturally varying prompt/generation shapes must not pin one XLA
@@ -237,55 +187,8 @@ class GPTAttention(nn.Layer):
                 raise RuntimeError(
                     'GPTPagedCache is an inference-only serving path — '
                     'call model.eval() / no_grad')
-            # the paged write and read are every model's (cache.py); the
-            # read is chosen through this module's `paged_kv_read`
-            out, new_cache = paged_attention(q, k, v, cache, 'gpt.attn',
-                                             paged_kv_read)
-            return self._out(out, b, n), new_cache
-        if isinstance(cache, GPTSlotCache):
-            import jax
-            from ...framework.core import is_grad_enabled
-            if self.training and is_grad_enabled():
-                raise RuntimeError(
-                    'GPTSlotCache is an inference-only serving path — '
-                    'call model.eval() / no_grad')
-            max_len = cache.k.shape[1]
-            t = cache.lengths  # [S] per-slot write offsets
-            if not isinstance(t, jax.core.Tracer) and \
-                    int(jnp.max(t)) + n > max_len:
-                # (under jit lengths are traced; the serving engine guards
-                # capacity at admission instead)
-                raise ValueError(
-                    'slot cache overflow: max slot length %d + %d new '
-                    'tokens > capacity %d' % (int(jnp.max(t)), n, max_len))
-
-            # per-slot write at that slot's current length. vmap over the
-            # slot axis: each slot's [max_len, H, Dh] buffer takes this
-            # step's [n, H, Dh] rows at its own offset — one fused
-            # scatter, same shapes every step regardless of occupancy.
-            def _write(buf, new, off):
-                return jax.lax.dynamic_update_slice(buf, new, (off, 0, 0))
-            k_buf = jax.vmap(_write)(
-                cache.k._data, k._data.astype(cache.k._data.dtype), t)
-            v_buf = jax.vmap(_write)(
-                cache.v._data, v._data.astype(cache.v._data.dtype), t)
-            # lengths intentionally NOT advanced here: every layer must
-            # write at the same pre-step offsets; the engine advances
-            # them once per step (by the VALID token count for padded
-            # prefill chunks)
-            new_cache = GPTSlotCache(Tensor(k_buf), Tensor(v_buf), t)
-            # per-slot validity mask: query row i of slot s sits at
-            # absolute position t[s]+i and sees buffer slots j <= t[s]+i
-            with _scope('gpt.attn.mask'):
-                qpos = t[:, None] + jnp.arange(n)[None, :]       # [S, n]
-                kpos = jnp.arange(max_len)                       # [m]
-                allow = qpos[:, :, None] >= kpos[None, None, :]  # [S, n, m]
-                mask = Tensor(jnp.where(allow, 0.0, -1e9)[:, None].astype(
-                    jnp.float32))                                # [S,1,n,m]
-            with _scope('gpt.attn.core'):
-                out = F.scaled_dot_product_attention(
-                    q, Tensor(k_buf), Tensor(v_buf), attn_mask=mask,
-                    is_causal=False, dropout_p=0.0)
+            # the paged write and read are every model's (cache.py)
+            out, new_cache = paged_attention(q, k, v, cache, 'gpt.attn')
             return self._out(out, b, n), new_cache
         if isinstance(cache, GPTStaticCache):
             import jax
@@ -423,9 +326,8 @@ class GPTModel(nn.Layer):
     def forward(self, input_ids, position_ids=None, caches=None):
         n = input_ids.shape[1]
         if position_ids is None:
-            if caches is not None and isinstance(
-                    caches[0], (GPTSlotCache, GPTPagedCache)):
-                # serving: each slot's positions continue from ITS length
+            if caches is not None and isinstance(caches[0], GPTPagedCache):
+                # serving: each row's positions continue from ITS length
                 position_ids = Tensor(
                     caches[0].lengths[:, None] + jnp.arange(n)[None, :])
             elif caches is not None:

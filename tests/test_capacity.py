@@ -288,15 +288,16 @@ def test_million_request_sweep_is_fast():
 
 def _tiny_engine_factory():
     import paddle_tpu as paddle
-    from paddle_tpu.serving import ContinuousBatchingEngine
+    from paddle_tpu.serving import PagedContinuousBatchingEngine
     from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
     paddle.seed(0)
     cfg = GPTConfig(vocab_size=128, hidden_size=64, num_layers=2,
                     num_heads=4, max_position_embeddings=64, dropout=0.0)
     model = GPTForCausalLM(cfg)
     model.eval()
-    return lambda: ContinuousBatchingEngine(
-        model, num_slots=4, max_len=48, prefill_chunk=8, decode_block=4)
+    return lambda: PagedContinuousBatchingEngine(
+        model, num_seqs=4, max_len=48, page_size=8, prefill_chunk=8,
+        decode_block=4)
 
 
 def test_replay_roundtrip_preserves_order_and_tenants():

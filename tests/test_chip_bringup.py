@@ -41,8 +41,8 @@ def _run(args, tmp_path, **env):
 # ---- chip_smoke.py / bench.py: exit codes and the last line -----------------
 
 @pytest.mark.parametrize('chips,phases', [
-    (1, ('train:', 'serve/slot:', 'serve/paged:', 'serve/gateway:',
-         'serve/parity:', 'serve/bf16:')),
+    (1, ('train:', 'serve/paged:', 'serve/gateway:', 'serve/parity:',
+         'serve/bf16:')),
     (4, ('multichip/dp2 x mp2:', 'multichip/dp2 x sharding2:'))])
 def test_chip_smoke_rehearsal_runs_every_phase(tmp_path, chips, phases):
     proc = _run(['chip_smoke.py', '--rehearse', '--chips', str(chips)],
@@ -101,7 +101,7 @@ def test_bench_extra_exits_nonzero_when_a_rung_raised(monkeypatch, capsys):
 
     def boom(on_tpu):
         raise RuntimeError('rung exploded')
-    monkeypatch.setattr(bench_extra, 'bench_serving', boom)
+    monkeypatch.setattr(bench_extra, 'bench_serving_fabric', boom)
     with pytest.raises(RuntimeError, match='rung exploded'):
         bench_extra.main()
     rows = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
@@ -276,22 +276,17 @@ def toy_lm():
     return model, prompts
 
 
-@pytest.mark.parametrize('kind', ['slot', 'paged', 'spec', 'preempt'])
+@pytest.mark.parametrize('kind', ['paged', 'spec', 'preempt'])
 def test_engine_cache_donation_keeps_tokens(toy_lm, kind):
-    """The engines donate their KV buffers on tpu/gpu only, so that branch
+    """The engine donates its KV pools on tpu/gpu only, so that branch
     never ran in a CPU test. jax's CPU backend does donate when asked:
     with donate=True every old buffer is deleted at dispatch, and prefix
     reuse, speculative verify, preemption/replay and perf_estimate's
     stashed arguments must still give the tokens of the undonated run."""
-    from paddle_tpu.serving import (ContinuousBatchingEngine,
-                                    PagedContinuousBatchingEngine)
+    from paddle_tpu.serving import PagedContinuousBatchingEngine
     model, prompts = toy_lm
 
     def engine(donate):
-        if kind == 'slot':
-            return ContinuousBatchingEngine(
-                model, num_slots=2, max_len=64, prefill_chunk=8,
-                decode_block=2, donate=donate)
         kw = {'spec': {'spec_k': 3},
               'preempt': {'preempt': True, 'num_pages': 13}}.get(kind, {})
         return PagedContinuousBatchingEngine(
@@ -301,8 +296,7 @@ def test_engine_cache_donation_keeps_tokens(toy_lm, kind):
     tokens = []
     for donate in (False, True):
         eng = engine(donate)
-        held = jax.tree_util.tree_leaves(
-            eng._caches if kind == 'slot' else eng._pools)
+        held = jax.tree_util.tree_leaves(eng._pools)
         reqs = [eng.add_request(p, max_new_tokens=10, priority=i % 3)
                 for i, p in enumerate(prompts)]
         eng.run()

@@ -150,7 +150,7 @@ def test_bert_base_train_step_fits_v5e(chip, on_chip_dispatch):
     assert _hbm_bytes(compiled) < HBM_BYTES
 
 
-# ---- the serving engines' programs ------------------------------------------
+# ---- the serving engine's programs -------------------------------------------
 
 def _serving_model(layers):
     paddle.seed(0)
@@ -163,30 +163,20 @@ def _serving_model(layers):
 
 def _engine_programs(layers):
     """{name: (jitted program, the argument tuple its dispatch site builds)}
-    for both engines at chip_smoke.py's serving shape."""
-    from paddle_tpu.serving import (ContinuousBatchingEngine,
-                                    PagedContinuousBatchingEngine)
+    for the engine at chip_smoke.py's serving shape."""
+    from paddle_tpu.serving import PagedContinuousBatchingEngine
     model = _serving_model(layers)
     key = np.zeros((2,), np.uint32)
     ids = np.zeros((1, 32), np.int32)
     sampling = (key, np.float32(1.0), np.int32(0), np.asarray(False))
-    slot = ContinuousBatchingEngine(model, num_slots=8, max_len=256,
-                                    prefill_chunk=32, decode_block=8,
-                                    donate=True)
     paged = PagedContinuousBatchingEngine(
         model, num_seqs=8, max_len=256, page_size=16, num_pages=65,
         prefill_chunk=32, decode_block=8, spec_k=4, donate=True)
-    state = (slot._last, slot._gen, slot._budgets, slot._active, slot._keys,
-             slot._temps, slot._topks, slot._sample)
+    state = (paged._last, paged._gen, paged._budgets, paged._active,
+             paged._keys, paged._temps, paged._topks, paged._sample)
     tables = paged.scheduler.block_tables
     return {
-        # serving/engine.py _prefill_step / _decode_step
-        'slot_prefill': (slot._prefill_jit, (
-            slot._params, slot._bufs, slot._caches, np.int32(0), ids,
-            np.int32(0), np.int32(32)) + sampling),
-        'slot_decode': (slot._decode_jit, (
-            slot._params, slot._bufs, slot._caches) + state),
-        # serving/paged_engine.py _prefill_step / _decode_step / _spec_step
+        # serving/engine.py _prefill_call / _decode_step / _spec_step
         'paged_prefill': (paged._prefill_jit, (
             paged._params, paged._bufs, paged._pools, tables[0:1],
             np.zeros((1,), np.int32), ids, np.int32(32)) + sampling),
@@ -284,8 +274,6 @@ def test_paged_attention_leaves_the_pools_where_they_lie(chip, on_chip_dispatch,
 # without it and the rest are marked slow
 @pytest.mark.parametrize('name', [
     'paged_verify',
-    pytest.param('slot_prefill', marks=pytest.mark.slow),
-    pytest.param('slot_decode', marks=pytest.mark.slow),
     pytest.param('paged_prefill', marks=pytest.mark.slow),
     pytest.param('paged_decode', marks=pytest.mark.slow)])
 def test_engine_program_compiles_for_v5e(chip, on_chip_dispatch, name):
@@ -296,7 +284,7 @@ def test_engine_program_compiles_for_v5e(chip, on_chip_dispatch, name):
     text = compiled.as_text()
     assert text.count('tpu_custom_call') == 0
     assert _hbm_bytes(compiled) < HBM_BYTES
-    scopes = ['gpt.attn.paged_write'] if name.startswith('paged') else []
+    scopes = ['gpt.attn.paged_write']
     if name != 'paged_verify':
         scopes.append('serving.pick_token')
     for scope in scopes + ['gpt.attn.mask', 'gpt.attn.core', 'gpt.lm_head']:
@@ -304,9 +292,8 @@ def test_engine_program_compiles_for_v5e(chip, on_chip_dispatch, name):
     # 8 rows of 256 against a pool of 65 x 16: the decode and verify
     # batches read the pool in place, the one-row chunk gathers its view
     assert ('gpt.attn.paged_gather' in text) == (name == 'paged_prefill')
-    if name.startswith('paged'):
-        from paddle_tpu.text.models.cache import paged_pool_shape
-        _pool_invariant(text, paged_pool_shape(12, 64, 65, 16))
+    from paddle_tpu.text.models.cache import paged_pool_shape
+    _pool_invariant(text, paged_pool_shape(12, 64, 65, 16))
 
 
 def _described(make_model):

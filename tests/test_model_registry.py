@@ -353,7 +353,7 @@ def test_gateway_multimodel_rollout_zero_loss(registry):
 
 def test_gateway_rollout_without_hosts_raises(tmp_path):
     from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
-    from paddle_tpu.serving import ContinuousBatchingEngine
+    from paddle_tpu.serving import PagedContinuousBatchingEngine
     import paddle_tpu as paddle
     paddle.seed(7)
     cfg = GPTConfig(vocab_size=64, hidden_size=32, num_layers=1,
@@ -361,7 +361,7 @@ def test_gateway_rollout_without_hosts_raises(tmp_path):
     m = GPTForCausalLM(cfg)
     m.eval()
     gw = ServingGateway(
-        lambda: ContinuousBatchingEngine(m, num_slots=2, max_len=16),
+        lambda: PagedContinuousBatchingEngine(m, num_seqs=2, max_len=16),
         replicas=1)
     try:
         with pytest.raises(ValueError, match='ModelHost-backed'):

@@ -24,8 +24,7 @@ from paddle_tpu.monitor import MetricRegistry
 from paddle_tpu.monitor.registry import set_default_registry
 from paddle_tpu.monitor.tracing import (FlightRecorder, Tracer,
                                         set_default_tracer)
-from paddle_tpu.serving import (ContinuousBatchingEngine,
-                                PagedContinuousBatchingEngine, kv_cache)
+from paddle_tpu.serving import PagedContinuousBatchingEngine, kv_cache
 from paddle_tpu.text.models import cache as cache_mod
 from paddle_tpu.text.models import olmo_hybrid as O
 
@@ -253,8 +252,6 @@ def test_prefix_reuse_and_speculation_are_refused_with_the_reason(served):
         _engine(model, prefix_cache=True)
     with pytest.raises(ValueError, match='cannot take back'):
         _engine(model, spec_k=2)
-    with pytest.raises(ValueError, match='K/V rows only'):
-        ContinuousBatchingEngine(model, num_slots=2, max_len=64)
 
 
 def test_the_tolerance_fails_a_state_kept_in_bf16(family, served,

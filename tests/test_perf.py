@@ -274,7 +274,7 @@ def test_spec_engine_perf_estimate_prices_the_verify_program():
 
 def test_engine_rebind_perf_moves_registry_and_owner():
     import paddle_tpu as paddle
-    from paddle_tpu.serving import ContinuousBatchingEngine
+    from paddle_tpu.serving import PagedContinuousBatchingEngine
     from paddle_tpu.text.models import GPTConfig, GPTForCausalLM
 
     cfg = GPTConfig(vocab_size=211, hidden_size=64, num_layers=2,
@@ -283,8 +283,9 @@ def test_engine_rebind_perf_moves_registry_and_owner():
     paddle.seed(7)
     m = GPTForCausalLM(cfg)
     m.eval()
-    eng = ContinuousBatchingEngine(m, num_slots=2, max_len=32,
-                                   prefill_chunk=8, decode_block=2)
+    eng = PagedContinuousBatchingEngine(m, num_seqs=2, max_len=32,
+                                        page_size=8, prefill_chunk=8,
+                                        decode_block=2)
     try:
         old_wd = eng.perf
         reg = MetricRegistry()

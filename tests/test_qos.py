@@ -31,8 +31,7 @@ from paddle_tpu.capacity.qos import (REJECT_REASONS, QosPolicy,
 from paddle_tpu.capacity.simulator import ServiceModel, simulate, sweep_qos
 from paddle_tpu.monitor import events as _events
 from paddle_tpu.monitor.registry import MetricRegistry
-from paddle_tpu.serving import (ContinuousBatchingEngine,
-                                PagedContinuousBatchingEngine,
+from paddle_tpu.serving import (PagedContinuousBatchingEngine,
                                 ServingGateway)
 from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
 
@@ -125,9 +124,10 @@ def test_policy_roundtrip_and_priorities():
 # ---- gateway admission ------------------------------------------------
 
 
-def _slot_factory(model):
-    return lambda: ContinuousBatchingEngine(
-        model, num_slots=2, max_len=32, prefill_chunk=8, decode_block=2)
+def _factory(model):
+    return lambda: PagedContinuousBatchingEngine(
+        model, num_seqs=2, max_len=32, page_size=8, prefill_chunk=8,
+        decode_block=2)
 
 
 def test_gateway_rate_and_quota_rejections(model, prompts):
@@ -135,7 +135,7 @@ def test_gateway_rate_and_quota_rejections(model, prompts):
     try:
         clock = FakeClock()
         gw = ServingGateway(
-            _slot_factory(model), replicas=1, clock=clock,
+            _factory(model), replicas=1, clock=clock,
             registry=MetricRegistry(),
             admission=QosPolicy(classes=[
                 TenantClass('premium', priority=1),
@@ -189,7 +189,7 @@ def test_gateway_bounded_queue_and_deadline_shed(model, prompts):
     try:
         clock = FakeClock()
         gw = ServingGateway(
-            _slot_factory(model), replicas=1, clock=clock,
+            _factory(model), replicas=1, clock=clock,
             registry=MetricRegistry(),
             admission=QosPolicy(
                 classes=[TenantClass('hi', priority=1),
@@ -220,7 +220,7 @@ def test_gateway_bounded_queue_and_deadline_shed(model, prompts):
 def test_gateway_fifo_within_priority_class(model, prompts):
     """Parked work drains best-class-first, FIFO inside a class."""
     gw = ServingGateway(
-        _slot_factory(model), replicas=1, registry=MetricRegistry(),
+        _factory(model), replicas=1, registry=MetricRegistry(),
         admission=QosPolicy(classes=[TenantClass('hi', priority=1),
                                      TenantClass('lo', priority=0)]))
     gw.kill_replica(0)
@@ -323,8 +323,9 @@ def test_preempt_budget_exhausted_is_terminal(paged_preempt, prompts):
 
 @pytest.mark.slow
 def test_engine_priority_admission_fifo_within_class(model, prompts):
-    eng = ContinuousBatchingEngine(model, num_slots=1, max_len=32,
-                                   prefill_chunk=8, decode_block=2)
+    eng = PagedContinuousBatchingEngine(model, num_seqs=1, max_len=32,
+                                        page_size=8, prefill_chunk=8,
+                                        decode_block=2)
     reqs = [eng.add_request(prompts[i], max_new_tokens=4, priority=p)
             for i, p in enumerate((0, 0, 1, 0))]
     while eng.scheduler.pending:
@@ -345,7 +346,7 @@ def test_kill_replica_mid_burst_with_active_shedding(model, prompts):
     log, prev = _capture_log()
     try:
         gw = ServingGateway(
-            _slot_factory(model), replicas=2, registry=MetricRegistry(),
+            _factory(model), replicas=2, registry=MetricRegistry(),
             admission=QosPolicy(classes=[
                 TenantClass('premium', priority=1),
                 TenantClass('bg', rate=1.0, burst=2.0)]))

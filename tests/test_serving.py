@@ -3,12 +3,11 @@
 The two load-bearing assertions from the engine's contract:
   1. greedy tokens through the engine are IDENTICAL to sequential
      model.generate() for mixed-length prompts — continuous batching
-     must not buy throughput with output drift; the paged engine must
-     hold the same bar with prefix sharing and speculative decoding on;
+     must not buy throughput with output drift, with prefix sharing and
+     speculative decoding on or off, and with a pool under the demand;
   2. the compiled program set is FIXED and traces once per program
      across an arbitrary admit/retire workload — churn must never
-     retrace (two programs for the slot engine, at most four overall
-     for the paged engine).
+     retrace (prefill + decode, or prefill + verify under speculation).
 """
 import threading
 
@@ -16,9 +15,9 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.serving import (ContinuousBatchingEngine,
-                                PagedContinuousBatchingEngine, Scheduler,
-                                ServingMetrics, SlotAllocator)
+from paddle_tpu.serving import (PageAllocator, PagedContinuousBatchingEngine,
+                                PagedScheduler, ServingMetrics,
+                                SlotAllocator)
 from paddle_tpu.serving.metrics import percentile
 from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
 
@@ -48,37 +47,63 @@ def _sequential(model, prompt, mnt, **kw):
     return [int(t) for t in out.numpy()[0][len(prompt):]]
 
 
-@pytest.mark.slow
-def test_greedy_parity_and_zero_retrace(model, prompts):
+def _engine(model, **kw):
+    """The engine at the suite's small shape; `kw` overrides."""
+    args = dict(num_seqs=2, max_len=64, page_size=8, prefill_chunk=8,
+                decode_block=4)
+    args.update(kw)
+    return PagedContinuousBatchingEngine(model, **args)
+
+
+def _pools(small):
+    """The default pool (every sequence at max_len: num_seqs * blocks + 1
+    pages, prefix cache on) and one of `small` pages, under the test's
+    demand, so that slots AND pages turn over and admission blocks on
+    pages."""
+    return pytest.mark.parametrize(
+        'pool', [dict(), dict(num_pages=small, prefix_cache=False)],
+        ids=['default_pool', 'small_pool_no_prefix'])
+
+
+@_pools(10)
+def test_greedy_parity_and_bounded_compilation(model, prompts, pool):
     """The acceptance bar: token-identical to generate() for mixed
-    lengths with slots << requests (forces admit/retire churn), and the
-    compiled-program count stays at one prefill + one decode."""
+    lengths with sequences << requests (forces admit/retire churn), the
+    program set stays at the fixed prefill/decode pair, and every page
+    returns to the free list or the prefix cache when the workload
+    drains."""
     mnt = 11
     expect = [_sequential(model, p, mnt) for p in prompts]
-    eng = ContinuousBatchingEngine(model, num_slots=3, max_len=64,
-                                   prefill_chunk=8, decode_block=4)
-    got = eng.generate(prompts, max_new_tokens=mnt)
-    assert got == expect
-    assert eng.compiled_sizes() == {'prefill': 1, 'decode': 1}
+    eng = _engine(model, num_seqs=3, **pool)
+    reqs = [eng.add_request(p, max_new_tokens=mnt) for p in prompts]
+    eng.run()
+    assert [r.tokens for r in reqs] == expect
+    assert eng.compiled_sizes() == {'prefill': 1, 'decode': 1, 'verify': 0}
     # every slot cycled through several occupants
     assert eng.allocator.in_use == 0
     assert eng.scheduler.pending == 0
+    if eng.prefix is not None:
+        # only prefix-cache references may outlive the requests
+        assert eng.pages.in_use == len(eng.prefix)
+    else:
+        assert eng.pages.in_use == 0
+        assert eng.num_pages - 1 < eng.num_slots * eng.num_blocks
+        assert any('pages' in r._admit_waits for r in reqs)
 
 
-@pytest.mark.slow
-def test_sampling_stream_parity(model, prompts):
+@_pools(5)     # 4 usable pages: the 17-token prompt alone takes 3 of them
+def test_sampling_stream_parity(model, prompts, pool):
     """Per-request PRNG streams mirror generate(): same seed, same
-    temperature/top-k, same sampled tokens."""
+    temperature/top-k, same sampled tokens — page indirection must not
+    perturb logits or key order."""
     mnt = 8
     kw = dict(do_sample=True, temperature=0.8, top_k=5, seed=42)
     expect = [_sequential(model, p, mnt, **kw) for p in prompts[:4]]
-    eng = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
-                                   prefill_chunk=8, decode_block=4)
+    eng = _engine(model, **pool)
     got = eng.generate(prompts[:4], max_new_tokens=mnt, **kw)
     assert got == expect
 
 
-@pytest.mark.slow
 def test_per_request_sampling_params(model, prompts):
     """Requests with DIFFERENT sampling configs share the batch; each
     must match its own sequential run (the vectorized pick must not mix
@@ -90,35 +115,32 @@ def test_per_request_sampling_params(model, prompts):
     mnt = 7
     expect = [_sequential(model, p, mnt, **kw)
               for p, kw in zip(prompts, specs)]
-    eng = ContinuousBatchingEngine(model, num_slots=4, max_len=64,
-                                   prefill_chunk=8, decode_block=4)
+    eng = _engine(model, num_seqs=4)
     reqs = [eng.add_request(p, max_new_tokens=mnt, **kw)
             for p, kw in zip(prompts, specs)]
     eng.run()
     assert [r.tokens for r in reqs] == expect
 
 
-@pytest.mark.slow
-def test_slot_reuse_no_crosstalk(model, prompts):
+@pytest.mark.parametrize('page_size', [8, 16])
+def test_slot_reuse_no_crosstalk(model, prompts, page_size):
     """A slot's next occupant sees none of the previous one: running the
     same workload at 2 slots (heavy reuse) and at 8 slots (no reuse)
     yields identical outputs."""
     mnt = 6
     outs = []
     for slots in (2, 8):
-        eng = ContinuousBatchingEngine(model, num_slots=slots, max_len=64,
-                                       prefill_chunk=8, decode_block=4)
+        eng = _engine(model, num_seqs=slots, page_size=page_size,
+                      prefix_cache=False)
         outs.append(eng.generate(prompts[:8], max_new_tokens=mnt))
     assert outs[0] == outs[1]
 
 
-@pytest.mark.slow
 def test_varied_budgets_and_immediate_finish(model, prompts):
     """max_new_tokens=1 finishes at prefill; longer budgets coexist in
     the same burst and each stops exactly at its own budget."""
     budgets = [1, 3, 9, 2]
-    eng = ContinuousBatchingEngine(model, num_slots=4, max_len=64,
-                                   prefill_chunk=8, decode_block=4)
+    eng = _engine(model, num_seqs=4)
     reqs = [eng.add_request(p, max_new_tokens=b)
             for p, b in zip(prompts, budgets)]
     eng.run()
@@ -128,8 +150,7 @@ def test_varied_budgets_and_immediate_finish(model, prompts):
 
 
 def test_stream_yields_all_tokens(model, prompts):
-    eng = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
-                                   prefill_chunk=8, decode_block=4)
+    eng = _engine(model)
     req = eng.add_request(prompts[0], max_new_tokens=9, stream=True)
     streamed = list(eng.stream(req))
     assert streamed == req.tokens
@@ -143,8 +164,7 @@ def test_thread_safe_front_door(model, prompts):
     outputs prove no cross-talk)."""
     mnt = 5
     expect = [_sequential(model, p, mnt) for p in prompts[:6]]
-    eng = ContinuousBatchingEngine(model, num_slots=3, max_len=64,
-                                   prefill_chunk=8, decode_block=4)
+    eng = _engine(model, num_seqs=3)
     results = [None] * 3
     def worker(i):
         results[i] = eng.generate(prompts[2 * i:2 * i + 2],
@@ -156,43 +176,34 @@ def test_thread_safe_front_door(model, prompts):
         t.join()
     got = [tok for pair in results for tok in pair]
     assert got == expect
-    assert eng.compiled_sizes() == {'prefill': 1, 'decode': 1}
+    assert eng.compiled_sizes() == {'prefill': 1, 'decode': 1, 'verify': 0}
 
 
 def test_admission_validation(model):
-    eng = ContinuousBatchingEngine(model, num_slots=2, max_len=32,
-                                   prefill_chunk=8, decode_block=2)
+    eng = _engine(model, max_len=32, decode_block=2)
     with pytest.raises(ValueError, match='empty prompt'):
         eng.add_request([], max_new_tokens=4)
     with pytest.raises(ValueError, match='max_new_tokens'):
         eng.add_request([1, 2], max_new_tokens=0)
     with pytest.raises(ValueError, match='cache rows'):
         eng.add_request(list(range(30)), max_new_tokens=8)   # 30+8-1 > 32
-    # prompt + budget fit but the PADDED last prefill chunk would not
-    # (26 pads to 32 > 30): a clamped write would silently corrupt rows
-    eng30 = ContinuousBatchingEngine(model, num_slots=2, max_len=30,
-                                     prefill_chunk=8, decode_block=2)
+    # prompt + budget fit but the PADDED last prefill chunk might not
+    # (a prefix hit mid-chunk shifts the chunk grid: up to 24 + 7 > 30):
+    # a clamped write would silently corrupt rows
+    eng30 = _engine(model, max_len=30, decode_block=2)
     with pytest.raises(ValueError, match='cache rows'):
-        eng30.add_request(list(range(26)), max_new_tokens=2)
+        eng30.add_request(list(range(24)), max_new_tokens=2)
     # capacity errors must not wedge the queue for valid requests
     req = eng.add_request([1, 2, 3], max_new_tokens=2)
     eng.run()
     assert len(req.tokens) == 2
 
 
-@pytest.mark.parametrize('make', [
-    lambda m: ContinuousBatchingEngine(m, num_slots=2, max_len=32,
-                                       prefill_chunk=8, decode_block=2),
-    lambda m: PagedContinuousBatchingEngine(m, num_seqs=2, max_len=32,
-                                            page_size=8, prefill_chunk=8,
-                                            decode_block=2),
-], ids=['slot', 'paged'])
-def test_front_door_rejects_unservable_worst_case(model, make):
-    """Both engines share the _EngineBase submission-time guard: a
-    request whose worst case (prompt + budget - 1) exceeds max_len gets
-    a clear ValueError naming max_len at add_request, instead of
-    wedging the queue head forever."""
-    eng = make(model)
+def test_front_door_rejects_unservable_worst_case(model):
+    """The submission-time guard: a request whose worst case (prompt +
+    budget - 1) exceeds max_len gets a clear ValueError naming max_len
+    at add_request, instead of wedging the queue head forever."""
+    eng = _engine(model, max_len=32, decode_block=2)
     with pytest.raises(ValueError, match='max_len=32'):
         eng.add_request(list(range(1, 20)), max_new_tokens=20)  # 38 > 32
     # the guard is exact: worst case == max_len still admits and runs
@@ -204,7 +215,7 @@ def test_front_door_rejects_unservable_worst_case(model, make):
 
 def test_engine_cap_exceeds_model_positions(model):
     with pytest.raises(ValueError, match='max_position_embeddings'):
-        ContinuousBatchingEngine(model, num_slots=2, max_len=4096)
+        PagedContinuousBatchingEngine(model, num_seqs=2, max_len=4096)
 
 
 def test_slot_allocator():
@@ -225,7 +236,8 @@ def test_slot_allocator():
 def test_scheduler_chunk_plan():
     from paddle_tpu.serving.scheduler import Request
     a = SlotAllocator(2)
-    s = Scheduler(a, max_len=32, prefill_chunk=8)
+    s = PagedScheduler(a, PageAllocator(9), max_len=32, prefill_chunk=8,
+                       page_size=8)
     r = Request(list(range(1, 12)), max_new_tokens=4)   # 11 tokens
     s.submit(r)
     assert s.admit() == [(0, r)]
@@ -291,26 +303,6 @@ def test_percentile_is_linear_interpolation_not_nearest_rank():
     assert percentile([1.0, 2.0, 4.0], 75) == pytest.approx(3.0)  # not 2/4
 
 
-@pytest.mark.slow
-def test_paged_greedy_parity_and_bounded_compilation(model, prompts):
-    """The paged acceptance bar: token-identical to generate() with
-    sequences << requests (page/slot churn), the program set stays at
-    the fixed prefill/decode pair, and every page returns to the free
-    list or the prefix cache when the workload drains."""
-    mnt = 11
-    expect = [_sequential(model, p, mnt) for p in prompts]
-    eng = PagedContinuousBatchingEngine(model, num_seqs=3, max_len=64,
-                                        page_size=8, prefill_chunk=8,
-                                        decode_block=4)
-    got = eng.generate(prompts, max_new_tokens=mnt)
-    assert got == expect
-    assert eng.compiled_sizes() == {'prefill': 1, 'decode': 1, 'verify': 0}
-    assert eng.allocator.in_use == 0
-    assert eng.scheduler.pending == 0
-    # only prefix-cache references may outlive the requests
-    assert eng.pages.in_use == len(eng.prefix)
-
-
 def test_paged_prefix_sharing_parity_and_reduced_prefill(model):
     """Requests sharing a system prompt hit the prefix cache (> 0 hit
     rate), skip the shared blocks' prefill (fewer prefilled tokens than
@@ -362,20 +354,6 @@ def test_paged_spec_decode_parity(model, prompts):
         eng.add_request(prompts[0], max_new_tokens=4, do_sample=True)
 
 
-def test_paged_sampling_stream_parity(model, prompts):
-    """With spec off, the paged engine serves sampled requests through
-    the same per-request PRNG stream as generate() — page indirection
-    must not perturb logits or key order."""
-    mnt = 8
-    kw = dict(do_sample=True, temperature=0.8, top_k=5, seed=42)
-    expect = [_sequential(model, p, mnt, **kw) for p in prompts[:4]]
-    eng = PagedContinuousBatchingEngine(model, num_seqs=2, max_len=64,
-                                        page_size=8, prefill_chunk=8,
-                                        decode_block=4)
-    got = eng.generate(prompts[:4], max_new_tokens=mnt, **kw)
-    assert got == expect
-
-
 @pytest.mark.slow
 def test_predictor_decode_engine(model, prompts, tmp_path):
     """The serving front door reached the inference API: a jit.save'd
@@ -385,16 +363,14 @@ def test_predictor_decode_engine(model, prompts, tmp_path):
     paddle.jit.save(model, path)
     from paddle_tpu import inference
     pred = inference.create_predictor(inference.Config(path))
-    eng = pred.decode_engine(num_slots=2, max_len=64, prefill_chunk=8,
-                             decode_block=4)
+    eng = pred.decode_engine(num_seqs=2, max_len=64, page_size=8,
+                             prefill_chunk=8, decode_block=4)
+    assert isinstance(eng, PagedContinuousBatchingEngine)
     got = eng.generate(prompts[:3], max_new_tokens=6)
     assert got == [_sequential(model, p, 6) for p in prompts[:3]]
-    # and the paged variant through the same door
-    paged = pred.decode_engine(num_slots=2, max_len=64, prefill_chunk=8,
-                               decode_block=4, paged=True, page_size=8)
-    assert paged.generate(prompts[:3], max_new_tokens=6) == got
-    with pytest.raises(TypeError, match='paged=True'):
-        pred.decode_engine(page_size=8)
+    # one engine, no switch: `paged=` fails as any unknown keyword does
+    with pytest.raises(TypeError, match='paged'):
+        pred.decode_engine(num_seqs=2, paged=True)
 
 
 def test_predictor_decode_engine_rejects_non_lm(tmp_path):

@@ -79,7 +79,7 @@ def test_live_metrics_scrape_during_concurrent_serving():
     import urllib.request
 
     from paddle_tpu import monitor
-    from paddle_tpu.serving import ContinuousBatchingEngine
+    from paddle_tpu.serving import PagedContinuousBatchingEngine
     from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
     from test_monitor import _parse_exposition
 
@@ -101,8 +101,9 @@ def test_live_metrics_scrape_during_concurrent_serving():
         return reg.get(name).labels().value() if reg.get(name) else 0.0
 
     # engine construction registers the families; baselines AFTER it
-    eng = ContinuousBatchingEngine(model, num_slots=3, max_len=64,
-                                   prefill_chunk=8, decode_block=4)
+    eng = PagedContinuousBatchingEngine(model, num_seqs=3, max_len=64,
+                                        page_size=8, prefill_chunk=8,
+                                        decode_block=4)
     base = {n: counter(n) for n in
             ('serving_requests_total', 'serving_requests_admitted_total',
              'serving_requests_retired_total', 'serving_tokens_total')}
@@ -153,7 +154,7 @@ def test_live_metrics_scrape_during_concurrent_serving():
         base['serving_requests_retired_total'] == len(prompts)
     assert counter('serving_tokens_total') - \
         base['serving_tokens_total'] == len(prompts) * mnt
-    assert eng.compiled_sizes() == {'prefill': 1, 'decode': 1}
+    assert eng.compiled_sizes() == {'prefill': 1, 'decode': 1, 'verify': 0}
     # the zero-retrace invariant is itself scrapeable
     trace = {(l['program'], v) for n, l, v in samples
              if n == 'serving_trace_count'}

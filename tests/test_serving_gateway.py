@@ -21,8 +21,7 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.monitor.registry import MetricRegistry
-from paddle_tpu.serving import (ContinuousBatchingEngine,
-                                PagedContinuousBatchingEngine,
+from paddle_tpu.serving import (PagedContinuousBatchingEngine,
                                 ServingGateway)
 from paddle_tpu.serving.gateway import (AutoscalePolicy, LeastLoadedRouter,
                                         RoundRobinRouter, slo_burn_rate)
@@ -53,25 +52,18 @@ def prompts():
 @pytest.fixture(scope='module')
 def reference(model, prompts):
     """Single-engine greedy outputs — the parity oracle."""
-    eng = ContinuousBatchingEngine(model, num_slots=2, max_len=32,
-                                   prefill_chunk=8, decode_block=2)
-    return eng.generate(prompts, max_new_tokens=MNT)
+    return _factory(model)().generate(prompts, max_new_tokens=MNT)
 
 
-def _slot_factory(model):
-    return lambda: ContinuousBatchingEngine(
-        model, num_slots=2, max_len=32, prefill_chunk=8, decode_block=2)
-
-
-def _paged_factory(model):
+def _factory(model, **kw):
     return lambda: PagedContinuousBatchingEngine(
         model, num_seqs=2, max_len=32, page_size=8, prefill_chunk=8,
-        decode_block=2)
+        decode_block=2, **kw)
 
 
 def _gw(model, factory=None, **kw):
     kw.setdefault('registry', MetricRegistry())
-    return ServingGateway(factory or _slot_factory(model), **kw)
+    return ServingGateway(factory or _factory(model), **kw)
 
 
 def _counter(gw, name, labels=None):
@@ -109,9 +101,12 @@ def test_round_robin_router(model, prompts, reference):
     assert routed == [2.0, 2.0]
 
 
-def test_paged_replicas_parity(model, prompts, reference):
-    """The gateway fronts paged engines through the same contract."""
-    gw = _gw(model, factory=_paged_factory(model), replicas=2)
+def test_small_pool_replicas_parity(model, prompts, reference):
+    """Replicas whose pool is under the demand (5 usable pages for two
+    sequences of up to 4, no prefix cache) block admission on pages and
+    still answer with the reference's tokens."""
+    gw = _gw(model, replicas=2,
+             factory=_factory(model, num_pages=6, prefix_cache=False))
     assert gw.generate(prompts[:6], max_new_tokens=MNT) == reference[:6]
 
 
@@ -429,8 +424,8 @@ def test_predictor_decode_gateway(model, prompts, tmp_path):
     from paddle_tpu import inference
     pred = inference.create_predictor(inference.Config(path))
     gw = pred.decode_gateway(replicas=2, registry=MetricRegistry(),
-                             num_slots=2, max_len=64, prefill_chunk=8,
-                             decode_block=4)
+                             num_seqs=2, max_len=64, page_size=8,
+                             prefill_chunk=8, decode_block=4)
     got = gw.generate(prompts[:3], max_new_tokens=6)
     expect = [[int(t) for t in model.generate(
         paddle.to_tensor([p]), max_new_tokens=6).numpy()[0][len(p):]]

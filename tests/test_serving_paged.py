@@ -1,8 +1,8 @@
 """Paged-KV host bookkeeping + engine lifecycle tests.
 
 Parity of the paged/prefix/speculative MODEL paths lives in
-tests/test_serving.py next to the slot engine's; this file covers the
-host side the paged engine stands on — page refcounts, prefix-cache
+tests/test_serving.py; this file covers the host side the engine stands
+on — page refcounts, prefix-cache
 hashing/eviction, page-aware admission — plus the lifecycle edges:
 allocator double-free strictness, FIFO fairness under sustained full
 occupancy, shutdown semantics, and page-leak-free churn.
@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.serving import (ContinuousBatchingEngine, NGramProposer,
+from paddle_tpu.serving import (NGramProposer,
                                 PagedContinuousBatchingEngine,
                                 PagedScheduler, SlotAllocator)
 from paddle_tpu.serving.kv_cache import (SCRATCH_PAGE, PageAllocator,
@@ -256,15 +256,8 @@ def test_engine_fifo_fairness_under_full_occupancy(model):
     assert eng.metrics.report()['occupancy_mean'] > 0.25
 
 
-@pytest.mark.parametrize('make', [
-    lambda m: ContinuousBatchingEngine(m, num_slots=2, max_len=32,
-                                       prefill_chunk=8, decode_block=2),
-    lambda m: PagedContinuousBatchingEngine(m, num_seqs=2, max_len=32,
-                                            page_size=8, prefill_chunk=8,
-                                            decode_block=2),
-], ids=['slot', 'paged'])
-def test_shutdown_rejects_new_requests_but_drains(model, make):
-    eng = make(model)
+def test_shutdown_rejects_new_requests_but_drains(model):
+    eng = _paged(model)
     req = eng.add_request([1, 2, 3], max_new_tokens=3)
     eng.shutdown()
     with pytest.raises(RuntimeError, match='shut down'):
@@ -274,20 +267,13 @@ def test_shutdown_rejects_new_requests_but_drains(model, make):
     assert eng.scheduler.pending == 0
 
 
-@pytest.mark.parametrize('make', [
-    lambda m: ContinuousBatchingEngine(m, num_slots=2, max_len=32,
-                                       prefill_chunk=8, decode_block=2),
-    lambda m: PagedContinuousBatchingEngine(m, num_seqs=2, max_len=32,
-                                            page_size=8, prefill_chunk=8,
-                                            decode_block=2),
-], ids=['slot', 'paged'])
-def test_shutdown_races_active_stream_consumers(model, make):
+def test_shutdown_races_active_stream_consumers(model):
     """shutdown() lands WHILE stream() consumers are cooperatively
     driving the engine: the front door closes, but every consumer's
     stream still terminates cleanly with its full token budget (the
     retire/churn half of this contract is covered above)."""
     import threading
-    eng = make(model)
+    eng = _paged(model)
     reqs = [eng.add_request(p, max_new_tokens=6, stream=True)
             for p in ([1, 2, 3], [4, 5], [6, 7, 8, 9])]
     got = {i: [] for i in range(len(reqs))}
@@ -371,7 +357,6 @@ from paddle_tpu.monitor import MetricRegistry                 # noqa: E402
 from paddle_tpu.monitor.registry import set_default_registry  # noqa: E402
 from paddle_tpu.monitor.tracing import (FlightRecorder,       # noqa: E402
                                         Tracer, set_default_tracer)
-from paddle_tpu.serving.scheduler import Scheduler            # noqa: E402
 
 
 class _Ticks:
@@ -412,14 +397,10 @@ def _dur(s):
     return s['end_mono'] - s['start_mono']
 
 
-@pytest.mark.parametrize('make', [
-    lambda m: ContinuousBatchingEngine(m, num_slots=2, max_len=32,
-                                       prefill_chunk=8, decode_block=2),
-    _paged], ids=['slot', 'paged'])
 @pytest.mark.parametrize('traced', [_Ticks], indirect=True)
-def test_step_span_has_its_phases_as_children(model, traced, make):
+def test_step_span_has_its_phases_as_children(model, traced):
     tr, clock = traced
-    eng = make(model)
+    eng = _paged(model)
     eng.metrics._clock = clock          # the engine on the same clock
     reqs = [eng.add_request(list(range(1, 12)), max_new_tokens=4),
             eng.add_request([5, 6, 7], max_new_tokens=4)]
@@ -432,7 +413,7 @@ def test_step_span_has_its_phases_as_children(model, traced, make):
     assert steps[0]['tags']['queue_depth'] == 2
     assert steps[0]['tags']['residents'] == 0
     assert all(s['tags']['cpu_s'] >= 0.0 for s in steps)
-    assert ('pages_in_use' in steps[0]['tags']) == hasattr(eng, 'pages')
+    assert steps[0]['tags']['pages_in_use'] == 0
     kids = {}
     for s in spans:
         kids.setdefault(s['parent_id'], []).append(s)
@@ -511,7 +492,8 @@ def _slot_view(pool, table, page, dh):
 @pytest.mark.parametrize('n', [1, 3, 5])
 def test_pool_read_agrees_with_the_gathered_view(model, monkeypatch, n):
     """Float32 logits and greedy picks of the two reads over one pool of
-    random rows, and of the slot path over the same rows: a page shared
+    random rows, and the first layer's attention under each read against
+    plain float64 attention over the same rows: a page shared
     by two rows, scratch entries behind a row's pages, an idle row (all
     scratch, length 0), a frozen lane (a row still in prefill, at a page
     boundary) and a row that this call fills to capacity. Whatever lies
@@ -531,10 +513,10 @@ def test_pool_read_agrees_with_the_gathered_view(model, monkeypatch, n):
     assert shape == (1, num_pages * page, 128)   # 8 heads of 16 fill a row
     pools = [tuple(rng.randn(*shape).astype(np.float32) for _ in 'kv')
              for _ in range(layers)]
-    assert gpt.paged_kv_read(5, nb * page, num_pages * page) == 'pool'
+    assert cache_mod.paged_kv_read(5, nb * page, num_pages * page) == 'pool'
 
     def run(read):
-        monkeypatch.setattr(gpt, 'paged_kv_read', lambda *shape: read)
+        monkeypatch.setattr(cache_mod, 'paged_kv_read', lambda *shape: read)
         caches = [gpt.GPTPagedCache(paddle.to_tensor(k), paddle.to_tensor(v),
                                     tables, lens, page) for k, v in pools]
         logits, new = model(paddle.to_tensor(ids), caches=caches)
@@ -560,14 +542,29 @@ def test_pool_read_agrees_with_the_gathered_view(model, monkeypatch, n):
     live = slice(0, heads * dh)        # (the other lanes of a row are padding)
     assert not (pool_kv[0][0][:, ~kept][:, page:, live]
                 == k0[:, ~kept][:, page:, live]).any()
-    # the slot path over each row's logical view
-    slots = [gpt.GPTSlotCache(*(paddle.to_tensor(np.stack(
-        [_slot_view(x, tables[s], page, dh)[:, :heads] for s in range(5)]))
-        for x in kv), lens) for kv in pools]
-    slot, _ = model(paddle.to_tensor(ids), caches=slots)
-    for paged in (pool, gather):
-        np.testing.assert_allclose(paged, slot.numpy(), rtol=0, atol=1e-5)
-        assert (paged.argmax(-1) == slot.numpy().argmax(-1)).all()
+    # plain float64 attention over each row's logical view, against the
+    # first layer's attention under either read
+    attn = model.gpt.h[0].attn
+    x = paddle.to_tensor(rng.randn(5, n, heads * dh).astype(np.float32))
+    qkv = attn.qkv_proj(x).numpy().astype(np.float64).reshape(
+        5, n, 3, heads, dh)
+    want = []
+    for s in range(5):
+        kview, vview = (
+            _slot_view(p, tables[s], page, dh)[:, :heads].astype(np.float64)
+            for p in pools[0])
+        pos = lens[s] + np.arange(n)
+        kview[pos], vview[pos] = qkv[s, :, 1], qkv[s, :, 2]
+        want.append(_attend(qkv[s, :, 0], kview, vview, pos).reshape(n, -1))
+    want = np.stack(want) @ attn.out_proj.weight.numpy().astype(np.float64) \
+        + attn.out_proj.bias.numpy()
+    for read in ('pool', 'gather'):
+        monkeypatch.setattr(cache_mod, 'paged_kv_read', lambda *shape: read)
+        cache = gpt.GPTPagedCache(*(paddle.to_tensor(p) for p in pools[0]),
+                                  tables, lens, page)
+        got, new = attn(x, cache=cache)
+        assert new.kv_read == read
+        np.testing.assert_allclose(got.numpy(), want, rtol=0, atol=1e-5)
 
 
 def _attend(q, kview, vview, qpos):
@@ -590,7 +587,8 @@ def _attend(q, kview, vview, qpos):
 @pytest.mark.parametrize('start,n', [
     (0, 16), (16, 16), (13, 16), (3, 8), (40, 16), (37, 16), (5, 6),
     (21, 1), (47, 1)])
-def test_a_call_writes_its_rows_and_no_other(heads, dh, start, n):
+def test_a_call_writes_its_rows_and_no_other(monkeypatch, heads, dh, start,
+                                             n):
     """A one-row call's rows `[start, start + n)` land in the row's pages
     (what runs past its last block on the scratch page), every other pool
     row stays bit for bit — from an unaligned start too, which no
@@ -623,8 +621,8 @@ def test_a_call_writes_its_rows_and_no_other(heads, dh, start, n):
     def call(read, lens):
         c = cache_mod.PagedKVCache(paddle.to_tensor(kp), paddle.to_tensor(vp),
                                    table, lens, page)
-        out, new = cache_mod.paged_attention(q, k, v, c, 't',
-                                             lambda *shape: read)
+        monkeypatch.setattr(cache_mod, 'paged_kv_read', lambda *shape: read)
+        out, new = cache_mod.paged_attention(q, k, v, c, 't')
         assert new.kv_read == read
         return out._data, new.k._data, new.v._data
 
@@ -652,8 +650,8 @@ def test_shape_rule_and_the_spans_kv_read_tag(model, traced, spec_k):
     together are at least the pool reads the pool, a one-row chunk
     gathers; the programs remember it, the spans carry it, and the
     tokens are the ones a pool too large for the rule gives."""
-    assert gpt.paged_kv_read(24, 1024, 512 * 16) == 'pool'
-    assert gpt.paged_kv_read(1, 1024, 512 * 16) == 'gather'
+    assert cache_mod.paged_kv_read(24, 1024, 512 * 16) == 'pool'
+    assert cache_mod.paged_kv_read(1, 1024, 512 * 16) == 'gather'
     tr, _ = traced
     rng = np.random.RandomState(3)
     system = [int(t) for t in rng.randint(0, 211, 8)]   # one shared page
@@ -697,20 +695,19 @@ def test_admit_pass_counts_and_causes_on_full_pool_and_full_slots():
     sched.retire(hog)
     assert len(sched.admit()) == 2 and sched.head_left == 'none'
     assert big._admit_waits == {'pages': 2}             # kept, not reset
-    # the SLOTS are full (one slot, pages to spare), both schedulers
-    for sched in (_mk_sched(num_seqs=1, num_pages=30)[0],
-                  Scheduler(SlotAllocator(1), 32, 4)):
-        a, b, c = (Request([1, 2, 3], max_new_tokens=2) for _ in range(3))
-        for r in (a, b, c):
-            sched.submit(r)
-        assert [r for _, r in sched.admit()] == [a]
-        assert sched.head_left == 'slots'
-        assert (b._admit_waits, c._admit_waits) == (
-            {'slots': 1}, {'behind_head': 1})
-        sched.mark_prefilled(a, 3)
-        sched.retire(a)
-        assert [r for _, r in sched.admit()] == [b]
-        assert c._admit_waits == {'slots': 1, 'behind_head': 1}
+    # the SLOTS are full (one slot, pages to spare)
+    sched = _mk_sched(num_seqs=1, num_pages=30)[0]
+    a, b, c = (Request([1, 2, 3], max_new_tokens=2) for _ in range(3))
+    for r in (a, b, c):
+        sched.submit(r)
+    assert [r for _, r in sched.admit()] == [a]
+    assert sched.head_left == 'slots'
+    assert (b._admit_waits, c._admit_waits) == (
+        {'slots': 1}, {'behind_head': 1})
+    sched.mark_prefilled(a, 3)
+    sched.retire(a)
+    assert [r for _, r in sched.admit()] == [b]
+    assert c._admit_waits == {'slots': 1, 'behind_head': 1}
 
 
 def test_blocked_passes_reach_the_span_and_the_registry(model, traced):

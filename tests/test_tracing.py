@@ -40,8 +40,7 @@ from paddle_tpu.distributed.resilience import (CircuitBreaker,
                                                ResilientChannel,
                                                RetryPolicy)
 from paddle_tpu.distributed.ps.embedding_service import EmbeddingServer
-from paddle_tpu.serving import (ContinuousBatchingEngine,
-                                PagedContinuousBatchingEngine)
+from paddle_tpu.serving import PagedContinuousBatchingEngine
 from paddle_tpu.testing import chaos
 from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
 
@@ -312,8 +311,9 @@ def test_disabled_tracing_keeps_call_payload_clean(traced):
 
 def test_serving_lifecycle_spans_and_exemplars(model, traced):
     tr, reg, flight = traced
-    eng = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
-                                   prefill_chunk=8, decode_block=4)
+    eng = PagedContinuousBatchingEngine(model, num_seqs=2, max_len=64,
+                                        page_size=8, prefill_chunk=8,
+                                        decode_block=4)
     prompts = [[int(t) for t in np.random.RandomState(5).randint(0, 211, n)]
                for n in (12, 3)]
     eng.generate(prompts, max_new_tokens=6)
@@ -539,8 +539,9 @@ def test_disabled_tracing_adds_no_measurable_decode_overhead(model,
     off: the disabled path must not be slower beyond scheduling noise
     (a decode step costs milliseconds; the guard is absolute)."""
     tr, reg, flight = traced
-    eng = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
-                                   prefill_chunk=8, decode_block=4)
+    eng = PagedContinuousBatchingEngine(model, num_seqs=2, max_len=64,
+                                        page_size=8, prefill_chunk=8,
+                                        decode_block=4)
     prompt = [1, 2, 3]
 
     def run_one():
@@ -645,8 +646,9 @@ def test_annotated_span_is_the_one_dual_sink_path(traced, monkeypatch):
 
 def test_disabled_tracing_opens_no_step_span(model, traced):
     tr, reg, flight = traced
-    eng = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
-                                   prefill_chunk=8, decode_block=4)
+    eng = PagedContinuousBatchingEngine(model, num_seqs=2, max_len=64,
+                                        page_size=8, prefill_chunk=8,
+                                        decode_block=4)
     tr.disable()
     try:
         out = eng.generate([[1, 2, 3]], max_new_tokens=6)

@@ -24,9 +24,8 @@ Gate mode (tools/gate_common protocol, like check_bench_regression):
   * --slo-ms X       : any request whose TTFT exceeds X ms is a finding;
   * --kv-integral X  : the per-request kv_page_seconds must sum to the
     allocator's pool-occupancy integral X within --kv-tol relative
-    error (slot engine: exact by construction; paged + prefix sharing
-    legitimately exceeds it — pass the paged pool's own integral only
-    when sharing is off). Mismatch is a finding.
+    error (prefix sharing legitimately exceeds it — pass the pool's
+    integral only when sharing is off). Mismatch is a finding.
 
 No events -> exit 2; findings -> exit 1; otherwise 0 with a summary.
 """

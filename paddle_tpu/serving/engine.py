@@ -68,24 +68,56 @@ from .scheduler import PagedScheduler, Request
 __all__ = ['PagedContinuousBatchingEngine', 'NGramProposer']
 
 
-@jax.named_scope('serving.pick_token')    # names the device ops, no more
-def _pick_token(lg, key, temp, topk, sample):
-    """Next token for ONE row of logits — generate()'s pick, per slot.
+def _topk_threshold(lt, topk):
+    """The value a row's top-k keeps down to: the `topk`-th largest of
+    the float32 row `lt` — `jnp.sort(lt)[clip(V - topk, 0, V - 1)]`, bit
+    for bit, so topk >= V keeps all — or -inf for topk == 0 (no
+    threshold). Found by selection, not by sorting the vocabulary.
 
-    All branches execute and select (jit-safe): greedy argmax vs
-    temperature/top-k categorical, chosen by the `sample` flag. topk==0
-    means full vocab (threshold -inf), same as generate().
-    """
-    lg = lg.astype(jnp.float32)
+    Floats map onto uint32 so that integer order is float order; the
+    answer is the largest `t` with at least topk keys >= t, built from
+    the top bit down: 32 compare-and-count passes over the row. Exact,
+    ties included (-0.0 sorts under 0.0 here and beside it in a sort,
+    which no `lt >= thr` can tell apart)."""
+    top = jnp.uint32(1 << 31)
+    bits = jax.lax.bitcast_convert_type(lt, jnp.uint32)
+    keys = jnp.where(bits >= top, ~bits, bits | top)
+    k = jnp.clip(topk, 1, lt.shape[-1])
+
+    def narrow(i, t):
+        cand = t | (top >> i.astype(jnp.uint32))
+        return jnp.where(jnp.sum(keys >= cand) >= k, cand, t)
+
+    t = jax.lax.fori_loop(0, 32, narrow, jnp.uint32(0))
+    kth = jax.lax.bitcast_convert_type(jnp.where(t >= top, t ^ top, ~t),
+                                       jnp.float32)
+    return jnp.where(topk > 0, kth, -jnp.inf)
+
+
+def _sample_token(lg, key, temp, topk, sample):
+    """ONE row's pick where some row of the batch samples — generate()'s:
+    temperature/top-k categorical under the `sample` flag, else the
+    argmax; ties at the top-k threshold are kept."""
     greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
     lt = lg / jnp.maximum(temp, 1e-6)
-    v = lt.shape[-1]
-    srt = jnp.sort(lt, axis=-1)                    # ascending
-    kth = srt[jnp.clip(v - topk, 0, v - 1)]        # the top-k'th value
-    thr = jnp.where(topk > 0, kth, -jnp.inf)
-    lt = jnp.where(lt >= thr, lt, -1e30)
+    lt = jnp.where(lt >= _topk_threshold(lt, topk), lt, -1e30)
     sampled = jax.random.categorical(key, lt).astype(jnp.int32)
     return jnp.where(sample, sampled, greedy)
+
+
+@jax.named_scope('serving.pick_token')    # names the device ops, no more
+def _pick_tokens(lg, keys, temps, topks, sample):
+    """Next token for each row of logits [N, V]. The program branches on
+    what the BATCH holds (a `lax.cond` outside the vmap: under it a
+    per-row predicate lowers to a select and runs both sides): no row
+    samples — the argmax and nothing else; some row does — every row
+    through `_sample_token`, a greedy row still getting its argmax.
+    `sample` is already masked to the rows whose pick is kept."""
+    lg = lg.astype(jnp.float32)
+    return jax.lax.cond(
+        jnp.any(sample),
+        lambda: jax.vmap(_sample_token)(lg, keys, temps, topks, sample),
+        lambda: jnp.argmax(lg, axis=-1).astype(jnp.int32))
 
 
 class NGramProposer:
@@ -638,7 +670,9 @@ class PagedContinuousBatchingEngine:
                 if sp:
                     sp.tags.update(slot=slot, start=start, tokens=valid,
                                    final=final,
-                                   kv_read=self.kv_read['prefill'])
+                                   kv_read=self.kv_read['prefill'],
+                                   pick=('sample' if req.do_sample
+                                         else 'argmax'))
             calls += 1
             tokens += valid
             self.metrics.on_prefill_tokens(valid)
@@ -811,7 +845,8 @@ class PagedContinuousBatchingEngine:
         last = jax.lax.dynamic_index_in_dim(lg[0], valid - 1, axis=0,
                                             keepdims=False)
         key2, sub = jax.random.split(key)
-        tok = _pick_token(last, sub, temp, topk, sample)
+        tok = _pick_tokens(last[None], sub[None], temp[None], topk[None],
+                           sample[None])[0]
         return self._unpack('prefill', pools, new_cs, slot), tok, key2
 
     def _decode_fn(self, params, bufs, pools, bt, lens, tok, gen,
@@ -840,8 +875,9 @@ class PagedContinuousBatchingEngine:
             ks = jax.vmap(jax.random.split)(keys)
             subs = ks[:, 1]
             keys2 = jnp.where(step_active[:, None], ks[:, 0], keys)
-            nxt = jax.vmap(_pick_token)(lg[:, -1], subs, temps, topks,
-                                        sample)
+            # a frozen lane's stale flag must not choose the branch
+            nxt = _pick_tokens(lg[:, -1], subs, temps, topks,
+                               sample & step_active)
             tok2 = jnp.where(step_active, nxt, tok[:, 0])[:, None]
             return ((self._unpack('decode', pools, new_cs), lens + inc,
                      tok2, gen + inc, keys2), (tok2[:, 0], step_active))
@@ -905,6 +941,10 @@ class PagedContinuousBatchingEngine:
                 self._gen, self._budgets, self._active, self._keys,
                 self._temps, self._topks, self._sample)
         self._decode_args = args
+        # the program's own predicate at the burst's first step (lanes
+        # only leave `step_active` inside a burst): which pick it takes
+        sampling = np.any(self._sample & self._active
+                          & (self._gen < self._budgets))
         clock = self.metrics.now
         t0 = clock()
         with self._tracer.start_span(
@@ -917,7 +957,9 @@ class PagedContinuousBatchingEngine:
             lens, last, gen, keys, toks, actives = jax.device_get(
                 (lens, last, gen, keys, toks, actives))
             burst = self._burst_done(sp, t0, t1, clock(),
-                                     kv_read=self.kv_read['decode'])
+                                     kv_read=self.kv_read['decode'],
+                                     pick=('sample' if sampling
+                                           else 'argmax'))
         self._lens = np.array(lens)
         self._last = np.array(last)
         self._gen = np.array(gen)
@@ -959,7 +1001,8 @@ class PagedContinuousBatchingEngine:
             t1 = clock()
             picks = np.asarray(jax.device_get(picks))
             burst = self._burst_done(sp, t0, t1, clock(),
-                                     kv_read=self.kv_read['verify'])
+                                     kv_read=self.kv_read['verify'],
+                                     pick='argmax')
         for slot in slots:
             req = self._requests[slot]
             d, g = drafts[slot], picks[slot]

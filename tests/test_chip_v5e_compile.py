@@ -14,16 +14,17 @@ an unattached device cannot be read back.
 
 Tier-1 compiles the kernels at the benchmark's shapes, the train step at full
 WIDTH with the depth cut to two layers (what the compiler checks per layer
-does not change with depth) and the one serving program that compiles in
-seconds; the 12-layer train step, whose memory is read against the chip's
-16 GB, and the serving programs that sort the vocabulary are marked slow.
+does not change with depth) and the three serving programs (8 - 11 s each:
+none sorts the vocabulary any more, which alone took ~25 s a program); the
+12-layer train step, whose memory is read against the chip's 16 GB, is
+marked slow.
 
 The paged programs are also held to the K/V pools' invariant (`_pool_invariant`):
 no program copies or re-lays a pool, and a pool keeps the default layout of
 its shape from the parameters to the outputs — tier-1 for `paged_attention`
-alone at the benchmark's two head shapes and for the verify program, slow for
-the programs that sort and for the decode programs at the page counts the
-configurations want next (1 024 and 3 072), read against the chip's memory.
+alone at the benchmark's two head shapes and for the three engine programs,
+slow for the decode programs at the page counts the configurations want next
+(1 024 and 3 072), read against the chip's memory.
 """
 import math
 import os
@@ -268,14 +269,11 @@ def test_paged_attention_leaves_the_pools_where_they_lie(chip, on_chip_dispatch,
     assert m.temp_size_in_bytes < held / 2
 
 
-# every program that picks a token sorts the 30528-wide vocabulary row
-# (serving/engine.py _pick_token), and that sort alone takes ~25 s to compile
-# for the chip whatever the depth — so tier-1 compiles the one program
-# without it and the rest are marked slow
-@pytest.mark.parametrize('name', [
-    'paged_verify',
-    pytest.param('paged_prefill', marks=pytest.mark.slow),
-    pytest.param('paged_decode', marks=pytest.mark.slow)])
+# no program sorts the 30528-wide vocabulary row to pick a token
+# (serving/engine.py _pick_tokens: the argmax, or a threshold by selection);
+# while two of them did, that sort alone took ~25 s to compile for the chip
+@pytest.mark.parametrize('name', ['paged_verify', 'paged_prefill',
+                                  'paged_decode'])
 def test_engine_program_compiles_for_v5e(chip, on_chip_dispatch, name):
     jitted, args = _engine_programs(layers=12)[name]
     compiled = jitted.lower(*_abstract(args, chip)).compile()
@@ -283,6 +281,7 @@ def test_engine_program_compiles_for_v5e(chip, on_chip_dispatch, name):
     # far under its 512-row floor): these are pure XLA programs
     text = compiled.as_text()
     assert text.count('tpu_custom_call') == 0
+    assert not re.search(r'\bsort\(', text)
     assert _hbm_bytes(compiled) < HBM_BYTES
     scopes = ['gpt.attn.paged_write']
     if name != 'paged_verify':

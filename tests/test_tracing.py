@@ -15,6 +15,7 @@ Plus the no-overhead guard: tracing disabled must not measurably slow
 the RPC or serving decode hot paths (same discipline as the metrics
 registry's disabled-path test in test_monitor.py).
 """
+import collections
 import glob
 import json
 import os
@@ -155,6 +156,34 @@ def test_server_span_always_pops_trace_key():
     sp.finish()
     # untraced message on an enabled tracer: no span, nothing popped
     assert tr.server_span({'op': 'pull'}, 'ps.server') is NULL_SPAN
+
+
+def test_default_ring_holds_a_serving_window(model, traced):
+    """The benchmark's `window_spans` readers give None once the ring has
+    dropped a span of their window, so the default ring has to hold what
+    the engine finishes in one: the spans a step and a request, COUNTED
+    from a run (a span added to the step shows here), times the steps and
+    requests of the fullest window a cell takes — 45 s of
+    serve-xl.offline-decode, 990 steps and 128 requests at 45 ms a step
+    (PERF.md section 7) — with room for a step a third as long."""
+    tr, reg, flight = traced
+    eng = PagedContinuousBatchingEngine(model, num_seqs=2, max_len=64,
+                                        page_size=8, prefill_chunk=8,
+                                        decode_block=4)
+    n_requests = 4
+    for i in range(n_requests):
+        eng.add_request([1 + i, 2, 3, 4, 5, 6, 7, 8, 9], max_new_tokens=5)
+    eng.run()
+    assert tr.recorder.dropped == 0
+    count = collections.Counter(s['name'] for s in tr.recorder.spans())
+    steps = count.pop('serving.step')
+    of_a_request = sum(count.pop(n) for n in (
+        'serving.request', 'serving.prefill', 'serving.decode',
+        'serving.prefill_call'))
+    a_step = 1 + sum(count.values()) / steps      # what is left nests in it
+    a_request = of_a_request / n_requests
+    window = 3 * (990 * a_step + 128 * a_request)
+    assert window <= FlightRecorder(registry=MetricRegistry()).capacity
 
 
 def test_flight_recorder_ring_dump_and_cooldown(tmp_path):

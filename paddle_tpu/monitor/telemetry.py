@@ -48,12 +48,19 @@ def record_dryrun_step(registry, step_seconds, loss, batch=None):
 # (not in serving/metrics.py) so the schema-baseline gate and the engine
 # register the exact same names/types — same single-source rule as
 # record_dryrun_step. (kind, name, help) with no labels: registration
-# alone creates the unlabeled child, so these appear in every snapshot.
+# alone creates the unlabeled child, so these appear in every snapshot;
+# an optional fourth entry names labels (`counter`: the closed set of
+# names the served model's layers count under, text/models/cache.py).
 SERVING_PAGED_FAMILIES = (
     ('gauge', 'serving_kv_pages_in_use',
      'physical KV pages currently referenced (sequences + prefix cache)'),
     ('gauge', 'serving_state_bytes',
      'bytes of per-slot recurrent state that belong to a resident'),
+    ('gauge', 'serving_latent_bytes',
+     'bytes of latent rows in the pages sequences hold'),
+    ('gauge', 'serving_layer_counter',
+     'what the model\'s layers counted on the device in the last decode '
+     'burst', ('counter',)),
     ('counter', 'serving_prefix_cache_hits_total',
      'full prompt blocks served from the prefix cache'),
     ('counter', 'serving_prefix_cache_misses_total',
@@ -71,8 +78,8 @@ def record_serving_schema(registry):
     and by dryrun_registry so the committed schema baseline covers
     serving without a serving run."""
     out = {}
-    for kind, name, doc in SERVING_PAGED_FAMILIES:
-        out[name] = getattr(registry, kind)(name, doc)
+    for kind, name, doc, *labels in SERVING_PAGED_FAMILIES:
+        out[name] = getattr(registry, kind)(name, doc, *labels)
     return out
 
 

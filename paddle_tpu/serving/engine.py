@@ -61,7 +61,8 @@ from ..monitor.perf import CompileWatchdog, StepTimeline
 from ..monitor.perf import costmodel as _costmodel
 from .kv_cache import (PageAllocator, PrefixCache, SlotAllocator,
                        build_paged_pools, cache_specs, kv_row_bytes,
-                       layer_caches, layer_state, state_bytes_per_seq)
+                       latent_row_bytes, layer_caches, layer_counters,
+                       layer_state, state_bytes_per_seq)
 from .metrics import ServingMetrics
 from .scheduler import PagedScheduler, Request
 
@@ -217,6 +218,12 @@ class PagedContinuousBatchingEngine:
         # page pool, or per-SLOT arrays that every token rewrites
         self._specs = cache_specs(model)
         self._state_seq_bytes = state_bytes_per_seq(self._specs)
+        self._latent_page_bytes = latent_row_bytes(self._specs) \
+            * int(page_size)
+        # what the layers counted on the device in the last burst
+        # (`layer_counters`), fetched with its tokens: {} for a model
+        # that counts nothing
+        self._burst_counters = {}
         if self._state_seq_bytes and prefix_cache:
             raise ValueError(
                 'prefix_cache=True with a recurrent layer: shared pages '
@@ -445,7 +452,8 @@ class PagedContinuousBatchingEngine:
                                    slots_in_use=self.allocator.in_use,
                                    pages_in_use=self.pages.in_use,
                                    state_slots_in_use=slots,
-                                   state_bytes=nbytes)
+                                   state_bytes=nbytes,
+                                   latent_bytes=self._latent_in_use())
                 with tr.start_span('serving.step.admit',
                                    annotate=True) as ph_admit:
                     admitted = self._admit()
@@ -466,6 +474,9 @@ class PagedContinuousBatchingEngine:
                 self.metrics.on_queue_depth(len(sched.queue))
                 self.metrics.on_pages_in_use(self.pages.in_use)
                 self.metrics.on_state_bytes(self._state_in_use()[1])
+                self.metrics.on_latent_bytes(self._latent_in_use())
+                if burst is not None and self._burst_counters:
+                    self.metrics.on_layer_counters(self._burst_counters)
                 if self.prefix is not None:
                     h, m = self.prefix.hits, self.prefix.misses
                     self.metrics.on_prefix_lookup(
@@ -480,6 +491,8 @@ class PagedContinuousBatchingEngine:
                         '%s steady state' % type(self).__name__)
                 detail = None
                 if sp:
+                    if burst is not None:
+                        sp.tags.update(self._burst_counters)
                     sp.set_tag('cpu_s', time.process_time() - cpu0)
                     sp.finish()
                     detail = self._step_detail(
@@ -597,6 +610,11 @@ class PagedContinuousBatchingEngine:
         bytes): zeros for a model that keeps K/V rows only."""
         slots = self.allocator.in_use if self._state_seq_bytes else 0
         return slots, slots * self._state_seq_bytes
+
+    def _latent_in_use(self):
+        """Bytes of latent rows in the pages sequences hold: 0 for a
+        model without a latent layer."""
+        return self.pages.in_use * self._latent_page_bytes
 
     # ---- scheduler glue (lock held) -----------------------------------
 
@@ -880,13 +898,17 @@ class PagedContinuousBatchingEngine:
                                sample & step_active)
             tok2 = jnp.where(step_active, nxt, tok[:, 0])[:, None]
             return ((self._unpack('decode', pools, new_cs), lens + inc,
-                     tok2, gen + inc, keys2), (tok2[:, 0], step_active))
+                     tok2, gen + inc, keys2),
+                    (tok2[:, 0], step_active, layer_counters(new_cs)))
 
-        carry, (toks, actives) = jax.lax.scan(
+        carry, (toks, actives, counted) = jax.lax.scan(
             body, (pools, lens, tok, gen, keys), None,
             length=self.decode_block)
         pools2, lens2, tok2, gen2, keys2 = carry
-        return pools2, lens2, tok2, gen2, keys2, toks, actives
+        # over the burst's steps as over the layers: sums, or the largest
+        counted = {name: (jnp.max if name.endswith('_max') else jnp.sum)(v)
+                   for name, v in counted.items()}
+        return pools2, lens2, tok2, gen2, keys2, toks, actives, counted
 
     def _verify_fn(self, params, bufs, pools, bt, lens, toks):
         """ONE forward over [S, K+1] rows: position 0 feeds each row's
@@ -951,11 +973,12 @@ class PagedContinuousBatchingEngine:
                 'serving.decode_burst', annotate=True, mono=t0,
                 tags={'rows': len(slots),
                       'block': self.decode_block}) as sp:
-            (self._pools, lens, last, gen, keys, toks,
-             actives) = self._decode_jit(*args)
+            (self._pools, lens, last, gen, keys, toks, actives,
+             counted) = self._decode_jit(*args)
             t1 = clock()
-            lens, last, gen, keys, toks, actives = jax.device_get(
-                (lens, last, gen, keys, toks, actives))
+            lens, last, gen, keys, toks, actives, counted = jax.device_get(
+                (lens, last, gen, keys, toks, actives, counted))
+            self._burst_counters = {k: int(v) for k, v in counted.items()}
             burst = self._burst_done(sp, t0, t1, clock(),
                                      kv_read=self.kv_read['decode'],
                                      pick=('sample' if sampling

@@ -29,8 +29,9 @@ from collections import OrderedDict
 
 __all__ = ['SlotAllocator', 'PageAllocator',
            'PrefixCache', 'build_paged_pools', 'SCRATCH_PAGE',
-           'cache_specs', 'kv_row_bytes', 'state_bytes_per_seq',
-           'layer_caches', 'layer_state']
+           'cache_specs', 'kv_row_bytes', 'latent_row_bytes',
+           'state_bytes_per_seq',
+           'layer_caches', 'layer_counters', 'layer_state']
 
 
 class SlotAllocator:
@@ -343,6 +344,11 @@ def _is_paged(spec):
     return isinstance(spec, PagedKVSpec)
 
 
+def _is_latent(spec):
+    from ..text.models.cache import PagedLatentSpec
+    return isinstance(spec, PagedLatentSpec)
+
+
 def _nbytes(shape, dtype):
     import jax.numpy as jnp
     n = jnp.dtype(dtype).itemsize
@@ -352,17 +358,24 @@ def _nbytes(shape, dtype):
 
 
 def kv_row_bytes(specs):
-    """Bytes one held token costs over the layers that keep K/V rows —
-    the conversion factor between page·seconds and byte·seconds for
-    per-tenant billing."""
+    """Bytes one held token costs over the layers that keep rows in
+    pages (K and V of every head, or one latent row) — the conversion
+    factor between page·seconds and byte·seconds for per-tenant
+    billing."""
     return sum(2 * _nbytes((s.num_heads, s.head_dim), s.dtype)
-               for s in specs if _is_paged(s))
+               for s in specs if _is_paged(s)) + latent_row_bytes(specs)
+
+
+def latent_row_bytes(specs):
+    """The latent layers' part of `kv_row_bytes`."""
+    return sum(_nbytes((s.width,), s.dtype) for s in specs if _is_latent(s))
 
 
 def state_bytes_per_seq(specs):
     """Bytes one resident sequence keeps in the recurrent layers,
     whatever its length."""
-    return sum(_nbytes(shape, dtype) for s in specs if not _is_paged(s)
+    return sum(_nbytes(shape, dtype) for s in specs
+               if not (_is_paged(s) or _is_latent(s))
                for shape, dtype in s.arrays)
 
 
@@ -370,11 +383,12 @@ def build_paged_pools(model, num_pages, page_size, num_seqs=0):
     """The engine's persistent device state, one entry per layer:
     a (k_pool, v_pool) pair `[G, num_pages * page_size, W]` (pool row
     `page * page_size + r`; `cache.paged_pool_shape`) for a layer that
-    keeps K/V rows, a tuple of `[num_seqs, ...]` arrays for a recurrent
+    keeps K/V rows, one pool (`cache.latent_pool_shape`) for a latent
+    layer, a tuple of `[num_seqs, ...]` arrays for a recurrent
     one. Block tables / lengths stay host-side (the engine passes them
     per dispatch)."""
     import jax.numpy as jnp
-    from ..text.models.cache import paged_pool_shape
+    from ..text.models.cache import latent_pool_shape, paged_pool_shape
     state = []
     for spec in cache_specs(model):
         if _is_paged(spec):
@@ -382,6 +396,9 @@ def build_paged_pools(model, num_pages, page_size, num_seqs=0):
                                      num_pages, page_size)
             state.append((jnp.zeros(shape, spec.dtype),
                           jnp.zeros(shape, spec.dtype)))
+        elif _is_latent(spec):
+            state.append((jnp.zeros(latent_pool_shape(
+                spec.width, num_pages, page_size), spec.dtype),))
         else:
             state.append(tuple(jnp.zeros((num_seqs,) + tuple(shape), dtype)
                                for shape, dtype in spec.arrays))
@@ -398,13 +415,18 @@ def layer_caches(specs, state, block_tables, lengths, valid, page_size,
     arrays are that sequence's row alone."""
     import jax
     from ..framework.core import Tensor
-    from ..text.models.cache import PagedKVCache, RecurrentCache
+    from ..text.models.cache import (PagedKVCache, PagedLatentCache,
+                                     RecurrentCache)
     caches = []
     for spec, arrays in zip(specs, state):
         if _is_paged(spec):
             k, v = arrays
             caches.append(PagedKVCache(Tensor(k), Tensor(v), block_tables,
                                        lengths, page_size))
+            continue
+        if _is_latent(spec):
+            caches.append(PagedLatentCache(arrays[0], block_tables, lengths,
+                                           valid, page_size))
             continue
         if slot is not None:
             arrays = [jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=0)
@@ -413,16 +435,36 @@ def layer_caches(specs, state, block_tables, lengths, valid, page_size,
     return caches
 
 
+def layer_counters(caches):
+    """What the layers counted in the forward that returned `caches`
+    (`counters` on a cache, text/models/cache.py): {name: device scalar},
+    added up over the layers — the largest for a name ending `_max`.
+    Empty for a model that counts nothing."""
+    import jax.numpy as jnp
+    out = {}
+    for c in caches:
+        for name, value in (getattr(c, 'counters', None) or {}).items():
+            if name not in out:
+                out[name] = value
+            elif name.endswith('_max'):
+                out[name] = jnp.maximum(out[name], value)
+            else:
+                out[name] = out[name] + value
+    return out
+
+
 def layer_state(state, caches, slot=None):
     """The device state after a forward returned `caches`: the new pools,
     and the recurrent arrays (with `slot`, written back into that row of
     `state`'s)."""
     import jax
-    from ..text.models.cache import PagedKVCache
+    from ..text.models.cache import PagedKVCache, PagedLatentCache
     out = []
     for old, c in zip(state, caches):
         if isinstance(c, PagedKVCache):
             out.append((c.k._data, c.v._data))
+        elif isinstance(c, PagedLatentCache):
+            out.append((c.pool,))
         elif slot is None:
             out.append(tuple(c.arrays))
         else:

@@ -77,6 +77,8 @@ class ServingMetrics:
         paged = record_serving_schema(r)
         self._m_pages = paged['serving_kv_pages_in_use']
         self._m_state_bytes = paged['serving_state_bytes']
+        self._m_latent_bytes = paged['serving_latent_bytes']
+        self._m_layer_counter = paged['serving_layer_counter']
         self._m_prefix_hits = paged['serving_prefix_cache_hits_total']
         self._m_prefix_misses = paged['serving_prefix_cache_misses_total']
         self._m_spec_proposed = paged['serving_spec_tokens_proposed_total']
@@ -184,6 +186,16 @@ class ServingMetrics:
 
     def on_state_bytes(self, nbytes):
         self._m_state_bytes.set(nbytes)
+
+    def on_latent_bytes(self, nbytes):
+        self._m_latent_bytes.set(nbytes)
+
+    def on_layer_counters(self, counters):
+        """What the model's layers counted on the device in the last
+        decode burst ({name: int}; the names are the model code's own
+        closed set, e.g. an expert layer's `moe_pairs_held`)."""
+        for name, value in counters.items():
+            self._m_layer_counter.labels(name).set(value)
 
     def on_pages_in_use(self, pages):
         self._pages_in_use = pages

@@ -3,17 +3,20 @@ and read every model's full attention shares.
 
 A model names what each of its layers keeps (`cache_specs()`, one entry
 per layer): a `PagedKVSpec` — rows of K and V in a page pool, addressed
-through block tables — or a `RecurrentSpec` — a fixed set of arrays per
-SEQUENCE that every token rewrites (a linear-attention state, the tail
-of a causal convolution). `paddle_tpu/serving/kv_cache.py` builds the
-device state from those specs and hands the model one cache object per
-layer and dispatch: `PagedKVCache` or `RecurrentCache`.
+through block tables —, a `PagedLatentSpec` — ONE row of `width` values a
+token in a pool of its own, shared by every head and read both as keys
+and as values (latent attention), addressed through the same block
+tables — or a `RecurrentSpec` — a fixed set of arrays per SEQUENCE that
+every token rewrites (a linear-attention state, the tail of a causal
+convolution). `paddle_tpu/serving/kv_cache.py` builds the device state
+from those specs and hands the model one cache object per layer and
+dispatch: `PagedKVCache`, `PagedLatentCache` or `RecurrentCache`.
 
-The two kinds differ in what a padded or frozen position may do. A paged
-layer lets it write: the rows land past the sequence's length or on the
-scratch page, where no query looks, and the next real pass overwrites
-them. A recurrent layer has no dead rows: `RecurrentCache.valid` says
-how many of a row's tokens are real, and the layer must leave its arrays
+The kinds differ in what a padded or frozen position may do. A paged
+layer (of either kind) lets it write: the rows land past the sequence's
+length or on the scratch page, where no query looks, and the next real
+pass overwrites them. A recurrent layer has no dead rows:
+`RecurrentCache.valid` says how many of a row's tokens are real, and the layer must leave its arrays
 bit for bit where `valid` is 0 and take nothing from the positions at or
 past it; `lengths == 0` means the row starts a sequence, from zeros.
 """
@@ -25,8 +28,10 @@ import jax.numpy as jnp
 
 from ...framework.core import Tensor
 
-__all__ = ['PagedKVSpec', 'RecurrentSpec', 'PagedKVCache', 'RecurrentCache',
-           'paged_pool_shape', 'paged_kv_read', 'paged_attention']
+__all__ = ['PagedKVSpec', 'PagedLatentSpec', 'RecurrentSpec', 'PagedKVCache',
+           'PagedLatentCache', 'RecurrentCache', 'paged_pool_shape',
+           'latent_pool_shape', 'paged_kv_read', 'paged_attention',
+           'paged_latent_rows']
 
 _scope = jax.named_scope
 
@@ -34,7 +39,14 @@ _scope = jax.named_scope
 # per sequence
 PagedKVSpec = collections.namedtuple('PagedKVSpec',
                                      'num_heads head_dim dtype')
+PagedLatentSpec = collections.namedtuple('PagedLatentSpec', 'width dtype')
 RecurrentSpec = collections.namedtuple('RecurrentSpec', 'arrays')
+
+# A cache a layer RETURNS may carry `counters`: {name: device scalar} of
+# what the layer counted in this call (an expert layer: the chosen pairs
+# that fell on experts it holds). Not a pytree leaf. The engine adds them
+# up over the layers and the steps of a burst — a name that ends in
+# `_max` takes the largest instead — and fetches them with the tokens.
 
 
 def _tensor_leaf(x):
@@ -148,6 +160,31 @@ jax.tree_util.register_pytree_node(
     lambda _, ch: RecurrentCache(*ch))
 
 
+class PagedLatentCache:
+    """One pool a layer, `[1, num_pages * page_size, W]`
+    (`latent_pool_shape`: a row is the latent one token leaves, every
+    head reads it, as its key and as its value; `W` is the spec's width
+    rounded up to whole lanes, the rest zeros), beside the block table,
+    lengths and `page_size` of `PagedKVCache` — the same pages,
+    invariants and scratch page — and `valid` `[B]` as `RecurrentCache`
+    has it (None where every position is real). The pool, lengths and
+    valid are raw jax arrays."""
+
+    def __init__(self, pool, block_tables, lengths, valid, page_size):
+        self.pool = pool
+        self.block_tables = block_tables
+        self.lengths = lengths
+        self.valid = valid
+        self.page_size = int(page_size)
+        self.kv_read = None      # as `PagedKVCache.kv_read`
+
+
+jax.tree_util.register_pytree_node(
+    PagedLatentCache,
+    lambda c: ((c.pool, c.block_tables, c.lengths, c.valid), c.page_size),
+    lambda page_size, ch: PagedLatentCache(*ch, page_size))
+
+
 _LANES = 128     # the minor axis of a TPU tile
 
 
@@ -157,6 +194,15 @@ def paged_pool_shape(num_heads, head_dim, num_pages, page_size):
     a whole number of them fills it."""
     per = _LANES // head_dim if _LANES % head_dim == 0 else 1
     return (-(-num_heads // per), num_pages * page_size, per * head_dim)
+
+
+def latent_pool_shape(width, num_pages, page_size):
+    """`[1, rows, W]` of a latent layer's pool: `width` rounded up to a
+    multiple of the 128 lanes. A row that is not whole lanes wide (576 is
+    4.5) makes the device lay the pool rows-last, and every program then
+    copies it on entry and on exit; in memory its tiles are padded to
+    whole lanes anyway."""
+    return (1, num_pages * page_size, -(-width // _LANES) * _LANES)
 
 
 def paged_kv_read(batch, capacity, pool_rows):
@@ -330,3 +376,32 @@ def paged_attention(q, k, v, cache, scope):
     new_cache = PagedKVCache(Tensor(ck), Tensor(cv), bt, t, page)
     new_cache.kv_read = read
     return Tensor(out), new_cache
+
+
+def paged_latent_rows(rows, cache, scope):
+    """Write this call's latent rows `[B, n, width]` into `cache`'s pool
+    at each row's length (`_write`: the pages and block tables K/V rows
+    take; zeros fill the pool's lanes past `width`) and return (the rows
+    each sequence then holds, `[B, capacity, W]` through its block table,
+    as wide as the pool, the cache with the new pool). The
+    read is ALWAYS of a sequence's own rows: attending over every pool
+    row under a mask, as `paged_kv_read` chooses for K/V once the views
+    are at least the pool, counts bytes, and a latent's scores cost every
+    head `width` operations a row — a decode batch over a whole pool
+    would be thousands of times the work. Rows at or past a sequence's
+    length are garbage: the caller masks by position."""
+    pool, page = cache.pool, cache.page_size
+    bt = jnp.asarray(cache.block_tables)
+    with _scope(scope + '.paged_write'):
+        rows = jnp.pad(rows.astype(pool.dtype), (
+            (0, 0), (0, 0), (0, pool.shape[-1] - rows.shape[-1])))
+        pool, = _write((pool,), [rows[:, None]], jnp.asarray(cache.lengths),
+                       bt, page)
+    with _scope(scope + '.paged_gather'):
+        # (not `_row_views`: with one group its swap of the group and
+        # batch axes is a second copy of the views)
+        held = jnp.take(pool[0].reshape(-1, page, pool.shape[-1]), bt,
+                        axis=0).reshape(bt.shape[0], -1, pool.shape[-1])
+    new_cache = PagedLatentCache(pool, bt, cache.lengths, cache.valid, page)
+    new_cache.kv_read = 'gather'
+    return held, new_cache

@@ -362,3 +362,59 @@ def test_olmo_hybrid_decode_program_fits_3072_pages(chip, on_chip_dispatch):
         3072, e['page_size']))
     assert _hbm_bytes(compiled) < HBM_BYTES - 1.5e9
 
+
+
+@pytest.mark.slow
+def test_kimi_linear_programs_fit_128_slots(chip, on_chip_dispatch):
+    """`kimi-linear-48b-serve-1chip` at its own sizes: 128 slots of KDA
+    state, 20 480 pages of latent rows, 64 of 256 experts a layer. Both
+    programs leave 1.5 GB of the chip, and neither copies a latent pool
+    (a row of whole lanes keeps the pool's layout) nor an expert's
+    weights (`[held, f, d]`: with the width d innermost the decode step's
+    products take them as they lie)."""
+    import json
+    from paddle_tpu import nn
+    from paddle_tpu.serving import PagedContinuousBatchingEngine
+    from paddle_tpu.serving import engine as engine_mod
+    from paddle_tpu.text.models import KimiLinearConfig, KimiLinearForCausalLM
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, '..', 'benchmarks', 'configs',
+                           'kimi-linear-48b-serve-1chip.json')) as f:
+        cfg = json.load(f)
+    m, e = cfg['model'], cfg['engine']
+    fields = KimiLinearConfig.__init__.__code__.co_varnames
+    keys = dict({k: v for k, v in m.items() if k in fields},
+                num_experts=m['num_experts_published'],
+                experts_held=tuple(m['experts_held']))
+    with nn.skip_init():
+        model = KimiLinearForCausalLM(KimiLinearConfig(**keys))
+    for name, param in model.named_parameters():
+        router = name.endswith(('router', 'e_score_correction_bias'))
+        param._data = jax.ShapeDtypeStruct(
+            param._data.shape, jnp.float32 if router else jnp.bfloat16)
+    model.eval()
+    build = engine_mod.build_paged_pools
+    engine_mod.build_paged_pools = lambda *a: jax.eval_shape(
+        lambda: build(*a))               # 2.8 GB of state: described
+    try:
+        eng = PagedContinuousBatchingEngine(
+            model, donate=True,
+            **{k: v for k, v in e.items() if k != 'class'})
+    finally:
+        engine_mod.build_paged_pools = build
+    decode = eng._decode_jit.lower(*_abstract((
+        eng._params, eng._bufs, eng._pools, eng.scheduler.block_tables,
+        eng._lens, eng._last, eng._gen, eng._budgets, eng._active,
+        eng._keys, eng._temps, eng._topks, eng._sample), chip)).compile()
+    prefill = eng._prefill_jit.lower(*_abstract((
+        eng._params, eng._bufs, eng._pools, eng.scheduler.block_tables[:1],
+        np.zeros((1,), np.int32),
+        np.zeros((1, e['prefill_chunk']), np.int32), np.int32(1),
+        np.zeros((2,), np.uint32), np.float32(1), np.int32(0),
+        np.asarray(False), np.int32(0)), chip)).compile()
+    for compiled in (decode, prefill):
+        assert _hbm_bytes(compiled) < HBM_BYTES - 1.5e9 - 0.25e9
+        text = compiled.as_text()
+        entry = text[text.index('\nENTRY '):]
+        assert not re.search(r'bf16\[1,327680,640\]\S* copy\(', entry)
+        assert not re.search(r'bf16\[64,1024,2304\]\S* copy\(', entry)

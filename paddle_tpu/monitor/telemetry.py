@@ -204,7 +204,8 @@ def record_tracing_schema(registry):
 # schema baseline both register through record_serving_request_schema.
 # Label budget: program is the engine's closed program set (prefill/
 # decode/verify); cause is why an admit pass left its head queued
-# (slots/pages).
+# (slots/pages); tail is whether a step left its decode burst in flight
+# across its return (overlapped/exposed).
 SERVING_REQUEST_FAMILIES = (
     ('counter', 'serving_requests_total',
      'requests submitted to the engine', ()),
@@ -232,6 +233,9 @@ SERVING_REQUEST_FAMILIES = (
     ('counter', 'serving_admit_blocked_total',
      'admit passes that left their head request queued, by cause',
      ('cause',)),
+    ('counter', 'serving_steps_total',
+     'engine steps, by whether the step left its decode burst in flight',
+     ('tail',)),
 )
 
 

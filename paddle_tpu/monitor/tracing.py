@@ -188,8 +188,10 @@ class Span:
         self.error = None
         self.tid = threading.get_ident()
         self._token = None
-        # the second sink: a TraceAnnotation lives on the opening
-        # thread until finish() — annotate only spans that nest
+        # the second sink: a TraceAnnotation from here until finish().
+        # Annotate spans that nest; one that is held across calls (the
+        # engine's decode burst) overlaps its neighbours in a trace
+        # viewer, which a reader of (name, start, end) does not mind
         self._ann = _annotation(name) if annotate else None
         if self._ann is not None:
             self._ann.__enter__()
@@ -621,9 +623,10 @@ class Tracer:
         span is NOT current until entered (``with``) — lifecycle spans
         held across calls (a serving request) just ``finish()``
         manually. `annotate=True` also enters a TraceAnnotation of the
-        same name until finish() (same thread, properly nested: use it
-        on `with` spans only); `mono` opens the span at a monotonic
-        stamp the caller already read."""
+        same name until finish() (meant for `with` spans, which nest;
+        on a span held across calls the annotation ends where finish()
+        is called and overlaps its neighbours); `mono` opens the span
+        at a monotonic stamp the caller already read."""
         if not self.enabled:
             return NULL_SPAN
         if root:

@@ -1,5 +1,6 @@
 """Paged continuous batching: block-granular KV + prefix reuse + spec
-decode, in exactly THREE compiled programs behind a thread-safe door.
+decode, in exactly THREE compiled programs (and one two-word write into
+the decode carry) behind a thread-safe door.
 
 The engine keeps one physical pool of fixed-size pages per layer and maps
 sequences onto it through host numpy block tables (vLLM's PagedAttention
@@ -30,9 +31,16 @@ can never retrace (trace-count gauges assert it):
 Only the layers' state lives on device — per layer, as the model names
 it (`cache_specs()`, serving/kv_cache.py): the page pools of a layer that
 keeps K/V rows, or `[num_seqs, ...]` arrays that belong to a slot for a
-recurrent layer (a linear-attention state). Block tables and lengths are
-host numpy handed to jit per dispatch (values change freely, shapes
-never). A recurrent layer's state cannot be shared by prefix nor taken
+recurrent layer (a linear-attention state) — and the decode carry: the
+token each lane feeds next and its PRNG key, results of one program
+handed to the next without a visit to the host. Block tables, lengths
+and the other per-lane control arrays are host numpy that the host keeps
+by arithmetic and sends when admission or retirement changed them
+(values change freely, shapes never). ONE decode burst stays in flight
+across `step()`'s return wherever an arrival could not be admitted
+before it anyway: what the host does with a burst's tokens runs while
+the device runs the next (`step`'s docstring has the order).
+A recurrent layer's state cannot be shared by prefix nor taken
 back after a rejected draft, so a model with one runs with
 `prefix_cache=False` and `spec_k=0` (the constructor says so); a
 preempted request recomputes from position 0, state and all.
@@ -155,6 +163,32 @@ class NGramProposer:
         return draft[:k]
 
 
+def _prng_key(seed):
+    """The two words of `jax.random.PRNGKey(seed)` (threefry2x32, the
+    stream generate() draws from), made on the host: an admission
+    dispatches nothing and waits for nothing. The high word is the
+    seed's only where jax holds 64-bit integers."""
+    hi = seed >> 32 if jax.config.jax_enable_x64 else 0
+    return np.array([hi & 0xFFFFFFFF, seed & 0xFFFFFFFF], np.uint32)
+
+
+class _Flight:
+    """The decode burst that is on the device: what its dispatch knew
+    (which lanes advance, by how many steps, and whether that takes them
+    to their budget: `step_active`'s rule, by count), the device arrays
+    `_deliver` will read, and its clock reads."""
+
+    __slots__ = ('index', 'lanes', 'toks', 'counted', 'span', 't0', 't1')
+
+    def __init__(self, index, lanes, toks, counted, span, t0, t1):
+        self.index = index        # the engine's count of bursts
+        self.lanes = lanes        # [(slot, request, steps, closed?)]
+        self.toks = toks          # device [decode_block, S]
+        self.counted = counted    # device {layer counter: scalar}
+        self.span = span          # `serving.decode_burst`, open
+        self.t0, self.t1 = t0, t1     # dispatch entered / returned
+
+
 class PagedContinuousBatchingEngine:
     """Page-granular continuous batching over a decoder that names its
     per-layer caches (`cache_specs()`): GPTForCausalLM,
@@ -169,11 +203,14 @@ class PagedContinuousBatchingEngine:
     compares drafts against argmax picks.
 
     With the tracer on, a step explains itself: `serving.step` with
-    `serving.step.admit`, `serving.step.prefill` (one
-    `serving.prefill_call` per jitted call) and `serving.decode_burst`
-    as children, each also a TraceAnnotation, so the same names sit in
-    the flight ring on the engine's clock and in a device trace's host
-    plane. With it off, step() opens no span and reads no extra clock.
+    `serving.step.wait` (the host blocked on the burst in flight),
+    `serving.step.admit` and `serving.step.prefill` (one
+    `serving.prefill_call` per jitted call) as children, each also a
+    TraceAnnotation, so the same names sit in the flight ring on the
+    engine's clock and in a device trace's host plane.
+    `serving.decode_burst` runs from a burst's dispatch to its end as
+    the host sees it: it can outlive the step that dispatched it and is
+    no step's child. With the tracer off, step() opens no span.
     """
 
     # traced-body counter keys, one per compiled program; the zero-
@@ -241,20 +278,42 @@ class PagedContinuousBatchingEngine:
         self.metrics = ServingMetrics()
         self._params = _fm.extract_params(model)
         self._bufs = _fm.extract_buffers(model)
-        # per-slot control state lives HOST-side as numpy: admission and
+        # per-lane control state lives HOST-side as numpy: admission and
         # retirement mutate it in place for free instead of dispatching
-        # an eager .at[].set() per field (the jitted calls accept numpy
-        # operands directly). Only the KV caches stay device-resident.
+        # an eager .at[].set() per field, and a burst's effect on it is
+        # arithmetic (a lane advances one position a burst step until
+        # its budget: `_decode_fn`'s `step_active`), done where the
+        # burst is dispatched: the host holds the lanes as the burst in
+        # flight will leave them.
         s = self.num_slots
-        self._last = np.zeros((s, 1), np.int32)       # token fed next step
         self._gen = np.zeros((s,), np.int32)          # tokens generated
         self._budgets = np.zeros((s,), np.int32)      # max_new_tokens
         self._active = np.zeros((s,), bool)           # slot decodes?
-        self._keys = np.zeros((s, 2), np.uint32)      # per-slot PRNG
         self._temps = np.ones((s,), np.float32)
         self._topks = np.zeros((s,), np.int32)
         self._sample = np.zeros((s,), bool)
+        # the decode carry lives on the DEVICE: results of one program
+        # (`_decode_fn`'s outputs, a final chunk's pick and key through
+        # `_carry_fn`) and arguments of the next
+        self._last = jnp.zeros((s, 1), jnp.int32)     # token fed next step
+        self._keys = jnp.zeros((s, 2), jnp.uint32)    # per-slot PRNG
+        # the speculative path drafts on the host: its copy of `_last`
+        self._spec_last = np.zeros((s,), np.int32)
         self._requests = {}                           # slot -> Request
+        # the lane arrays as the last dispatch left them on the device
+        # (lengths and counts: that burst's own results), good for the
+        # next one until `_lanes_dirty` says the host changed a lane
+        self._lane_args = None
+        self._lanes_dirty = True
+        self._flight = None       # the burst on the device (`_Flight`)
+        self._landed = None       # ended, its tokens not yet delivered
+        # (dispatch, dispatch-to-end, waited) seconds of the bursts that
+        # ended in the running step, for the timeline
+        self._ended = []
+        self._bursts = 0          # bursts dispatched, the spans' `burst`
+        # final chunks dispatched this step, their picks still on the
+        # device: [(slot, request, pick, closed by count?)]
+        self._picks = []
         self._lock = threading.RLock()
         self._closed = False
         # cached at construction (like the registry): swap the default
@@ -322,6 +381,8 @@ class PagedContinuousBatchingEngine:
             donate = jax.default_backend() in ('tpu', 'gpu')
         dn = (2,) if donate else ()
         self._prefill_jit = jax.jit(self._prefill_fn, donate_argnums=dn)
+        self._carry_jit = jax.jit(self._carry_fn,
+                                  donate_argnums=(0, 1) if donate else ())
         self._decode_jit = jax.jit(self._decode_fn, donate_argnums=dn)
         self._verify_jit = jax.jit(self._verify_fn, donate_argnums=dn)
         self._verify_args = None
@@ -426,16 +487,53 @@ class PagedContinuousBatchingEngine:
         return req
 
     def shutdown(self):
-        """Refuse all future add_request calls. In-flight requests may
-        still be driven to completion with step()/run(); shutdown only
-        closes the front door."""
-        with self._lock:
+        """Refuse all future add_request calls, and bring home the burst
+        that is on the device: its tokens are delivered here, so what
+        was generated is on the requests when this returns. Requests
+        still resident may be driven to completion with step()/run();
+        shutdown only closes the front door."""
+        with self._lock, no_grad_guard():
             self._closed = True
+            self._land()
+            self._deliver()
+            self._end_bursts()
             self.perf.close()
 
     def step(self):
-        """One scheduler iteration: admit → prefill chunks → decode
-        burst → retire. Returns the number of requests still pending."""
+        """One scheduler iteration; returns the number of requests still
+        pending. One decode burst stays in flight across the return:
+
+            wait for the burst the last step queued to END         (the
+                step's one wait for the device that nothing hides)
+            admit; dispatch the prefill calls, none of them read back
+            dispatch the next burst        -- the device is busy from here
+            close BY COUNT the lanes it will finish: slot, pages, lane
+            read the final chunks' picks (the burst runs behind them)
+            and the ended burst's tokens (on their way to the host since
+            its dispatch), deliver both, finish the requests the ended
+            burst closed, metrics
+
+        so what the host does with a burst's tokens, and what the caller
+        does between two steps, runs while the device runs the next
+        burst; the next burst is dispatched only after the running one
+        ended. What admission depends on is released by count (nothing
+        can take a slot or a page before the next admit, which follows
+        the burst's end), so it is free in the step a serial order frees
+        it in; what needs token values happens after the dispatch.
+
+        A caller hands requests over BETWEEN steps, so one that arrives
+        under a burst left in flight is seen only after the next burst
+        is queued too, and waits out both. Where that can cost anyone —
+        nothing is queued and a slot is free, so whoever arrives could
+        be admitted at the next pass — the step waits for its own burst
+        before it returns (the same wait, at the step's end) and hands
+        back an idle device, as a serial order does. Where a head is
+        queued behind full slots or pages, an arrival waits behind it
+        (FIFO) whatever the device does, and the burst stays in flight.
+
+        The speculative path (`spec_k`) drafts from the delivered tokens
+        and so reads before it dispatches: dispatch, read, deliver in
+        one step."""
         with self._lock, no_grad_guard():
             self._step_index += 1
             tr, sched = self._tracer, self.scheduler
@@ -445,6 +543,8 @@ class PagedContinuousBatchingEngine:
                     # CPU reads "the host was busy"
                     cpu0 = time.process_time()
                     compiles0 = self.perf.counts['compile']
+                self._land()
+                if sp:
                     slots, nbytes = self._state_in_use()
                     sp.tags.update(step=self._step_index,
                                    residents=len(sched.resident),
@@ -464,19 +564,35 @@ class PagedContinuousBatchingEngine:
                 with tr.start_span('serving.step.prefill',
                                    annotate=True) as ph_prefill:
                     calls, tokens = self._prefill_step()
+                    # a final chunk's pick is read in this step (a first
+                    # token does not wait out a burst): the burst goes
+                    # behind the calls first, and the phase ends with
+                    # their picks on the host. The speculative path
+                    # reads before it dispatches
+                    behind = bool(self._picks) and not self.spec_k
+                    if behind:
+                        self._decode_step()
                     if ph_prefill:
-                        ph_prefill.tags.update(calls=calls, tokens=tokens)
+                        ph_prefill.tags.update(calls=calls, tokens=tokens,
+                                               picks=len(self._picks))
+                    self._deliver_picks()
+                if not behind:
+                    self._decode_step()
+                # whoever arrives under this burst could be admitted at
+                # the next pass: it must not find a second one queued
+                in_flight = self._flight is not None
+                overlapped = in_flight and bool(
+                    sched.queue or not self.allocator.available)
+                self._deliver()
                 if sched.head_left != 'none':
                     self.metrics.on_admit_blocked(sched.head_left)
                 self.metrics.on_prefill_calls(calls)
-                burst = self._decode_step()
-                self.metrics.on_step(self.allocator.in_use, self.num_slots)
+                self.metrics.on_step(self.allocator.in_use, self.num_slots,
+                                     overlapped)
                 self.metrics.on_queue_depth(len(sched.queue))
                 self.metrics.on_pages_in_use(self.pages.in_use)
                 self.metrics.on_state_bytes(self._state_in_use()[1])
                 self.metrics.on_latent_bytes(self._latent_in_use())
-                if burst is not None and self._burst_counters:
-                    self.metrics.on_layer_counters(self._burst_counters)
                 if self.prefix is not None:
                     h, m = self.prefix.hits, self.prefix.misses
                     self.metrics.on_prefix_lookup(
@@ -489,35 +605,49 @@ class PagedContinuousBatchingEngine:
                         for p in self._warm_programs):
                     self.perf.declare_warmup(
                         '%s steady state' % type(self).__name__)
+                if in_flight and not overlapped:
+                    self._land()
+                    self._deliver()
+                if self._ended and self._burst_counters:
+                    self.metrics.on_layer_counters(self._burst_counters)
                 detail = None
                 if sp:
-                    if burst is not None:
+                    if self._ended:
                         sp.tags.update(self._burst_counters)
+                    sp.set_tag('tail_under_burst', overlapped)
                     sp.set_tag('cpu_s', time.process_time() - cpu0)
                     sp.finish()
                     detail = self._step_detail(
-                        sp, ph_admit, ph_prefill, burst,
+                        sp, ph_admit, ph_prefill,
                         self.perf.counts['compile'] - compiles0)
-                if burst is not None:
-                    # the timeline's step is the burst (its totals feed
-                    # the straggler rule and the burst percentiles); a
-                    # flagged one carries the whole step's phases
-                    self.timeline.end_step(detail=detail)
+                self._end_bursts(detail)
             return sched.pending
 
-    def _step_detail(self, sp, ph_admit, ph_prefill, burst, compiles):
+    def _step_detail(self, sp, ph_admit, ph_prefill, compiles):
         """Where a step went, for a `perf.straggler` record: phase
         seconds from the spans' own stamps (self = the step minus its
-        phases), CPU seconds, compiles counted during it, its index."""
+        child spans: the waits, admit, prefill), CPU seconds, compiles
+        counted during it, its index."""
         admit = ph_admit.end_mono - ph_admit.start_mono
         prefill = ph_prefill.end_mono - ph_prefill.start_mono
-        dispatch, block = burst or (0.0, 0.0)
+        wait = sum(w for _, _, w in self._ended)
         wall = sp.end_mono - sp.start_mono
         return {'engine_step': self._step_index, 'step_s': wall,
-                'admit_s': admit, 'prefill_s': prefill,
-                'burst_dispatch_s': dispatch, 'burst_block_s': block,
-                'self_s': wall - admit - prefill - dispatch - block,
+                'wait_s': wait, 'admit_s': admit, 'prefill_s': prefill,
+                'self_s': wall - wait - admit - prefill,
                 'cpu_s': sp.tags['cpu_s'], 'compiles': compiles}
+
+    def _end_bursts(self, detail=None):
+        """The timeline's step is a burst, from its dispatch to its end
+        as the host saw it (its totals feed the straggler rule and the
+        burst percentiles): one for each burst that ended in this engine
+        step; a flagged one carries the step's phases beside its own."""
+        ended, self._ended = self._ended, []
+        for dispatch, block, _ in ended:
+            self.timeline.record('host_dispatch', dispatch)
+            self.timeline.record('device_block', block)
+            self.timeline.end_step(detail=detail and dict(
+                detail, burst_dispatch_s=dispatch, burst_block_s=block))
 
     def run(self):
         """Drive until every submitted request has finished."""
@@ -644,7 +774,7 @@ class PagedContinuousBatchingEngine:
             self._sample[slot] = req.do_sample
             # generate()'s stream: key = PRNGKey(seed), split once at
             # prefill end — created here, advanced by the final chunk
-            req._key = np.asarray(jax.random.PRNGKey(req.seed))
+            req._key = _prng_key(req.seed)
             # no cache reset needed: the first prefill chunk writes from
             # the occupant's own offset and its length unreaches the
             # previous occupant's rows. A prefix hit means rows [0, hit)
@@ -654,6 +784,8 @@ class PagedContinuousBatchingEngine:
             if req._prefix_hit and req._span is not None:
                 req._span.add_event('prefix_cache_hit',
                                     tokens=req._prefix_hit)
+        if admitted:
+            self._lanes_dirty = True
         return len(admitted)
 
     def _trace_prefill(self, req, start, valid, final):
@@ -670,9 +802,11 @@ class PagedContinuousBatchingEngine:
 
     def _prefill_step(self):
         """One chunk per prefilling resident, each its own jitted call
-        (`_prefill_call`); returns (calls, prompt tokens forwarded). A
-        call's span ends after its host sync, so the host time BETWEEN
-        two calls is `serving.step.prefill`'s self time."""
+        (`_prefill_call`); returns (calls, prompt tokens forwarded). No
+        call is read back here: a final chunk's pick and key go into the
+        decode carry on the device and the pick onto `_picks`, for
+        `_deliver_picks`; what the host needs of the lane it knows by
+        count. A call's span is its dispatch."""
         tr = self._tracer
         calls = tokens = 0
         for req, start, ids, valid, final in self.scheduler.prefill_plan():
@@ -683,8 +817,9 @@ class PagedContinuousBatchingEngine:
                 # only the final chunk's split advances the sampling
                 # stream
                 tok, key2 = self._prefill_call(req, start, ids, valid)
-                if final:
-                    tok = int(tok)           # the call's host sync
+                if final and not self.spec_k:
+                    self._last, self._keys = self._carry_jit(
+                        self._last, self._keys, np.int32(slot), tok, key2)
                 if sp:
                     sp.tags.update(slot=slot, start=start, tokens=valid,
                                    final=final,
@@ -698,28 +833,74 @@ class PagedContinuousBatchingEngine:
             self._trace_prefill(req, start, valid, final)
             if not final:
                 continue
-            self._last[slot, 0] = tok
+            tok.copy_to_host_async()
             self._gen[slot] = 1
-            self._keys[slot] = np.asarray(key2)
             self._active[slot] = True
-            self._emit(req, [tok])
-            if len(req.tokens) >= req.max_new_tokens:
-                self._retire(req)
+            closed = req.max_new_tokens <= 1
+            if closed:
+                self._release(req)
+            self._picks.append((slot, req, tok, closed))
         return calls, tokens
 
-    def _burst_done(self, span, t0, t1, t2, **tags):
-        """A burst's one set of clock reads — dispatch returned at t1,
-        results on the host at t2 — feeds the timeline's phases and the
-        `serving.decode_burst` span alike (`tags`: what else the span
-        says of the burst); returns (dispatch, block) seconds, what
-        `_decode_step` hands back to step()."""
-        dispatch, block = t1 - t0, t2 - t1
-        self.timeline.record('host_dispatch', dispatch)
-        self.timeline.record('device_block', block)
-        if span:
-            span.tags.update(dispatch_s=dispatch, block_s=block, **tags)
-            span.finish(mono=t2)
-        return dispatch, block
+    def _deliver_picks(self):
+        """The step's final chunks' picks, read when their calls end (a
+        burst dispatched meanwhile runs behind them): first tokens."""
+        picks, self._picks = self._picks, []
+        if not picks:
+            return
+        toks = jax.device_get([p[2] for p in picks])
+        for (slot, req, _, closed), tok in zip(picks, toks):
+            self._spec_last[slot] = tok
+            self._emit(req, [int(tok)])
+            if closed:
+                self._finish(req)
+
+    def _land(self):
+        """Wait for the burst in flight, if there is one, to END: the
+        step's one wait for the device that nothing hides.
+        Its token values, on their way to the host since its dispatch,
+        are not needed before the next dispatch: `_deliver` reads them.
+        Its (dispatch, dispatch-to-end, waited) seconds go onto `_ended`,
+        for the timeline."""
+        flight, self._flight = self._flight, None
+        if flight is None:
+            return
+        tw, t2 = self._wait(flight.index, flight.toks)
+        dispatch, block = flight.t1 - flight.t0, t2 - flight.t1
+        if flight.span:
+            flight.span.tags.update(dispatch_s=dispatch, block_s=t2 - tw)
+            flight.span.finish(mono=t2)
+        self._ended.append((dispatch, block, t2 - tw))
+        self._landed = flight
+
+    def _wait(self, burst, result):
+        """Block until the device has `result`, under the step's child
+        span `serving.step.wait`; returns the clock before and after."""
+        clock = self.metrics.now
+        tw = clock()
+        with self._tracer.start_span('serving.step.wait', annotate=True,
+                                     mono=tw, tags={'burst': burst}) as sp:
+            result.block_until_ready()
+            t2 = clock()
+            if sp:
+                sp.set_tag('waited_s', t2 - tw)
+                sp.finish(mono=t2)
+        return tw, t2
+
+    def _deliver(self):
+        """The ended burst's tokens and layer counters to the host
+        (their copy began with the dispatch) and on to their requests,
+        in lane order, with the second half of retirement for the lanes
+        the burst finished."""
+        flight, self._landed = self._landed, None
+        if flight is None:
+            return
+        toks, counted = jax.device_get((flight.toks, flight.counted))
+        self._burst_counters = {k: int(v) for k, v in counted.items()}
+        for slot, req, n, closed in flight.lanes:
+            self._emit(req, toks[:n, slot].tolist())
+            if closed:
+                self._finish(req)
 
     def _emit(self, req, tokens):
         if req._replay:
@@ -753,13 +934,24 @@ class PagedContinuousBatchingEngine:
             req.id, len(tokens), t=now,
             trace_id=None if req._span is None else req._span.trace_id)
 
-    def _retire(self, req, outcome='ok'):
-        req.outcome = outcome
+    def _release(self, req):
+        """Retirement's first half, by count, once the last program that
+        touches the lane is dispatched: what admission depends on — the
+        lane, the slot, the pages (the billing window closes with them)
+        — goes back before the next admit pass, which cannot come before
+        that program has ended."""
         slot = req.slot
         self._active[slot] = False
         self._lens[slot] = 0
+        self._lanes_dirty = True
         del self._requests[slot]
-        self.scheduler.retire(req)     # sets req.kv_page_seconds
+        self.scheduler.release(req)    # sets req.kv_page_seconds
+
+    def _finish(self, req, outcome='ok'):
+        """Retirement's second half, once the request's last tokens are
+        delivered: outcome, metrics, spans, the wide event, and the
+        request's waiters."""
+        req.outcome = outcome
         req._finish_t = self.metrics.now()
         self.metrics.on_retired(req.id)
         self.metrics.on_tenant_retired(
@@ -772,6 +964,7 @@ class PagedContinuousBatchingEngine:
             req._span.add_event('retired')
             req._span.finish()
         self._emit_wide_event(req, outcome)
+        self.scheduler.finish(req)
 
     def _emit_wide_event(self, req, outcome):
         """THE canonical per-request record (monitor/events.py). One
@@ -816,7 +1009,20 @@ class PagedContinuousBatchingEngine:
         billing window and sets the finished flag after this returns)."""
         self._active[slot] = False
         self._lens[slot] = 0
+        # (the scheduler's hook: admit() runs under step()'s lock)
+        self._lanes_dirty = True  # graftlint: disable=lock-guard-write
         self._requests.pop(slot, None)
+        # the ended burst's tokens, not yet delivered: a victim that
+        # comes back regenerates them (`_replay` counts what it was
+        # given), one that is dropped gets them now
+        landed = self._landed
+        for lane in landed.lanes if landed else ():
+            if lane[1] is req:
+                landed.lanes.remove(lane)
+                if dropped:
+                    self._emit(req, np.asarray(landed.toks)[
+                        :lane[2], slot].tolist())
+                break
         self.metrics.on_preempted(req._tenant_label)
         if req._phase is not None:
             req._phase.finish()
@@ -866,6 +1072,11 @@ class PagedContinuousBatchingEngine:
         tok = _pick_tokens(last[None], sub[None], temp[None], topk[None],
                            sample[None])[0]
         return self._unpack('prefill', pools, new_cs, slot), tok, key2
+
+    def _carry_fn(self, last, keys, slot, tok, key):
+        """A final chunk's pick and advanced key into lane `slot` of the
+        decode carry, on the device: two words and one."""
+        return last.at[slot, 0].set(tok), keys.at[slot].set(key)
 
     def _decode_fn(self, params, bufs, pools, bt, lens, tok, gen,
                    budgets, active, keys, temps, topks, sample):
@@ -936,65 +1147,80 @@ class PagedContinuousBatchingEngine:
         # the program learns its slot only where a layer's state lives
         # per slot; a model of K/V rows alone is addressed by `bt1`
         where = (np.int32(slot),) if self._state_seq_bytes else ()
+        # (a copy of the row: the call is not waited for, and the row
+        # may change under it — a one-token request is released at once)
         self._pools, tok, key2 = self._prefill_jit(
             self._params, self._bufs, self._pools,
-            self.scheduler.block_tables[slot:slot + 1],
+            self.scheduler.block_tables[slot:slot + 1].copy(),
             np.asarray([start], np.int32),
             np.asarray(ids, np.int32)[None, :],
             np.int32(valid), req._key,
             np.float32(req.temperature), np.int32(req.top_k),
             np.asarray(req.do_sample), *where)
         self._lens[slot] = start + valid
+        self._lanes_dirty = True
         return tok, key2
 
     def _decode_step(self):
+        """Dispatch the next burst and leave it in flight (`_flight`);
+        nothing of it is read here. Under `spec_k` the draft-and-verify
+        step instead, which reads its own results."""
         slots = self.scheduler.decode_slots()
         if not slots:
             return
         if self.spec_k:
             return self._spec_step(slots)
-        # the span covers dispatch AND the device_get sync — the burst's
-        # actual wall time, not just the async enqueue; `_burst_done`
-        # splits the same window (host_dispatch vs device_block) and the
-        # dispatch args are stashed for perf_estimate's cost-model
-        # lowering (identical avals, so no retrace).
-        args = (self._params, self._bufs, self._pools,
-                self.scheduler.block_tables, self._lens, self._last,
-                self._gen, self._budgets, self._active, self._keys,
-                self._temps, self._topks, self._sample)
+        # the lane arrays go to the device when the host changed one
+        # (admission, a prefill chunk, retirement, preemption); a burst
+        # that follows a burst takes them as they lie there, lengths and
+        # counts as the last burst returned them, which is what the host
+        # counted. The dispatch args are stashed for perf_estimate's
+        # cost-model lowering (identical avals, so no retrace).
+        if self._lanes_dirty:
+            # (copies: the host writes its arrays again under the burst)
+            self._lane_args = jax.device_put([a.copy() for a in (
+                self.scheduler.block_tables, self._lens, self._gen,
+                self._budgets, self._active, self._temps, self._topks,
+                self._sample)])
+            self._lanes_dirty = False
+        bt, lens, gen, budgets, active, temps, topks, sample = \
+            self._lane_args
+        args = (self._params, self._bufs, self._pools, bt, lens,
+                self._last, gen, budgets, active, self._keys, temps,
+                topks, sample)
         self._decode_args = args
-        # the program's own predicate at the burst's first step (lanes
-        # only leave `step_active` inside a burst): which pick it takes
-        sampling = np.any(self._sample & self._active
-                          & (self._gen < self._budgets))
+        # the program's own predicate (lanes only leave `step_active`
+        # inside a burst): how far each lane advances, which pick it takes
+        left = self._budgets - self._gen
+        lanes = [(slot, self._requests[slot],
+                  min(self.decode_block, int(left[slot])),
+                  left[slot] <= self.decode_block) for slot in slots]
+        sampling = any(self._sample[slot] for slot in slots)
+        self._bursts += 1
         clock = self.metrics.now
         t0 = clock()
-        with self._tracer.start_span(
-                'serving.decode_burst', annotate=True, mono=t0,
-                tags={'rows': len(slots),
-                      'block': self.decode_block}) as sp:
-            (self._pools, lens, last, gen, keys, toks, actives,
-             counted) = self._decode_jit(*args)
-            t1 = clock()
-            lens, last, gen, keys, toks, actives, counted = jax.device_get(
-                (lens, last, gen, keys, toks, actives, counted))
-            self._burst_counters = {k: int(v) for k, v in counted.items()}
-            burst = self._burst_done(sp, t0, t1, clock(),
-                                     kv_read=self.kv_read['decode'],
-                                     pick=('sample' if sampling
-                                           else 'argmax'))
-        self._lens = np.array(lens)
-        self._last = np.array(last)
-        self._gen = np.array(gen)
-        self._keys = np.array(keys)
-        for slot in slots:
-            req = self._requests[slot]
-            new = [int(toks[k, slot]) for k in range(toks.shape[0])
-                   if actives[k, slot]]
-            self._emit(req, new)
-            if len(req.tokens) >= req.max_new_tokens:
-                self._retire(req)
-        return burst
+        # dispatch to results on the host: the span outlives this step
+        # (`_land` closes it), so it is a root and no step's child
+        span = self._tracer.start_span(
+            'serving.decode_burst', root=True, annotate=True, mono=t0,
+            tags={'burst': self._bursts, 'rows': len(slots),
+                  'block': self.decode_block,
+                  'pick': 'sample' if sampling else 'argmax'})
+        (self._pools, lens, self._last, gen, self._keys, toks, _,
+         counted) = self._decode_jit(*args)
+        span.set_tag('kv_read', self.kv_read['decode'])
+        self._lane_args = [bt, lens, gen] + self._lane_args[3:]
+        for out in (toks, *counted.values()):
+            out.copy_to_host_async()
+        self._flight = _Flight(self._bursts, lanes, toks, counted, span,
+                               t0, clock())
+        # the burst's effect on the lanes, by count: what it will have
+        # done when it has ended, and what admission may have then
+        for slot, req, n, closed in lanes:
+            self._gen[slot] += n
+            self._lens[slot] += n
+            if closed:
+                self._release(req)
 
     def _spec_step(self, slots):
         """Draft K tokens per decoding row, verify all rows in ONE
@@ -1002,7 +1228,9 @@ class PagedContinuousBatchingEngine:
         matches the model's own greedy picks, plus the pick after it
         (the 'bonus' token — free, since the verify forward already
         computed it). Worst case (0 accepted) this emits 1 token per
-        row, exactly a decode step; best case K+1."""
+        row, exactly a decode step; best case K+1. It drafts from the
+        tokens delivered so far, so dispatch, read and delivery are one
+        step's."""
         K = self.spec_k
         toks = np.zeros((self.num_slots, K + 1), np.int32)
         drafts = {}
@@ -1010,22 +1238,27 @@ class PagedContinuousBatchingEngine:
             req = self._requests[slot]
             d = self._proposer.propose(req.prompt + req.tokens, K)
             drafts[slot] = d
-            toks[slot, 0] = self._last[slot, 0]
+            toks[slot, 0] = self._spec_last[slot]
             toks[slot, 1:] = d
         args = (self._params, self._bufs, self._pools,
                 self.scheduler.block_tables, self._lens, toks)
         self._verify_args = args
+        self._bursts += 1
         clock = self.metrics.now
         t0 = clock()
-        with self._tracer.start_span(
-                'serving.decode_burst', annotate=True, mono=t0,
-                tags={'rows': len(slots), 'spec_k': K}) as sp:
-            self._pools, picks = self._verify_jit(*args)
-            t1 = clock()
-            picks = np.asarray(jax.device_get(picks))
-            burst = self._burst_done(sp, t0, t1, clock(),
-                                     kv_read=self.kv_read['verify'],
-                                     pick='argmax')
+        span = self._tracer.start_span(
+            'serving.decode_burst', root=True, annotate=True, mono=t0,
+            tags={'burst': self._bursts, 'rows': len(slots), 'spec_k': K,
+                  'pick': 'argmax'})
+        self._pools, picks = self._verify_jit(*args)
+        t1 = clock()
+        tw, t2 = self._wait(self._bursts, picks)
+        picks = jax.device_get(picks)
+        self._ended.append((t1 - t0, t2 - t1, t2 - tw))
+        if span:
+            span.tags.update(dispatch_s=t1 - t0, block_s=t2 - tw,
+                             kv_read=self.kv_read['verify'])
+            span.finish(mono=t2)
         for slot in slots:
             req = self._requests[slot]
             d, g = drafts[slot], picks[slot]
@@ -1045,8 +1278,8 @@ class PagedContinuousBatchingEngine:
                                     accepted=max(len(emit) - 1, 0))
             self._lens[slot] += len(emit)
             self._gen[slot] += len(emit)
-            self._last[slot, 0] = emit[-1]
+            self._spec_last[slot] = emit[-1]
             self._emit(req, emit)
-            if len(req.tokens) >= req.max_new_tokens:
-                self._retire(req)
-        return burst
+            if self._gen[slot] >= self._budgets[slot]:
+                self._release(req)
+                self._finish(req)

@@ -73,6 +73,9 @@ class ServingMetrics:
         self._m_prefill = req['serving_prefill_tokens_total']
         self._m_prefill_calls = req['serving_prefill_calls_total']
         self._m_admit_blocked = req['serving_admit_blocked_total']
+        steps = req['serving_steps_total']
+        self._m_steps = {True: steps.labels('overlapped'),
+                         False: steps.labels('exposed')}
         # page, state, prefix-cache and speculation families
         paged = record_serving_schema(r)
         self._m_pages = paged['serving_kv_pages_in_use']
@@ -162,10 +165,13 @@ class ServingMetrics:
         self._m_tokens.inc(count)
         self._end = t
 
-    def on_step(self, occupied, num_slots):
+    def on_step(self, occupied, num_slots, overlapped=False):
+        """One engine step; `overlapped`: it left its decode burst in
+        flight across its return."""
         frac = occupied / float(num_slots)
         self._occupancy.append(frac)
         self._m_occupancy.set(frac)
+        self._m_steps[bool(overlapped)].inc()
 
     def on_prefill_tokens(self, count):
         """`count` prompt tokens were actually forwarded through the

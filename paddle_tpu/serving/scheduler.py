@@ -11,8 +11,9 @@ Policy (Orca-style iteration-level scheduling, FIFO within a step):
      reason Sarathi/vLLM interleave prefill rather than running it to
      completion on arrival.
   3. DECODE: all slots whose prompt is fully consumed take one decode
-     burst together (engine-side); finished sequences retire and their
-     slots and pages return to the free lists the same step.
+     burst together (engine-side); finished sequences retire, and their
+     slots and pages return to the free lists (`release`) before the
+     admit pass of the step that learns the burst has ended.
 
 Everything here is host-side bookkeeping with plain Python ints plus
 host numpy block tables — the scheduler never touches device arrays, so
@@ -154,6 +155,9 @@ class PagedScheduler:
         self.prefill_chunk = int(prefill_chunk)
         self.queue = deque()
         self.resident = {}        # slot -> Request (PREFILL or DECODE)
+        # released and not yet finished: their last tokens are on the
+        # device or on their way to the caller
+        self.closing = set()
         self._submit_seq = itertools.count()
         # why the last admit() pass left its head queued: 'slots',
         # 'pages', or 'none' when it emptied the queue
@@ -394,9 +398,12 @@ class PagedScheduler:
         return [s for s in sorted(self.resident)
                 if self.resident[s].state == DECODE]
 
-    def retire(self, req):
-        """Release a finished request's pages and slot and wake any
-        waiters."""
+    def release(self, req):
+        """Retirement's first half: a finished request's pages and slot
+        go back to the free lists and its billing window closes. The
+        engine does this by count where it dispatches the request's last
+        program, so the next admit pass (which follows that program's
+        end) can use them; the request stays `pending` until `finish`."""
         slot = req.slot
         row = self.block_tables[slot]
         nblocks = self._nblocks.pop(slot, 0)
@@ -416,8 +423,14 @@ class PagedScheduler:
         # the pool integral under sharing). _kv_acc carries windows
         # closed out by earlier preemptions.
         req.kv_page_seconds = req._kv_acc + nblocks * held
-        req.state = DONE
         req.slot = None
+        self.closing.add(req)
+
+    def finish(self, req):
+        """Retirement's second half, once every token is delivered: the
+        request is DONE, its stream ends and its waiters wake."""
+        self.closing.discard(req)
+        req.state = DONE
         if req._stream_q is not None:
             req._stream_q.put(None)   # stream sentinel: end of tokens
         req._finished.set()
@@ -425,4 +438,4 @@ class PagedScheduler:
     @property
     def pending(self):
         """Requests not yet DONE anywhere in the system."""
-        return len(self.queue) + len(self.resident)
+        return len(self.queue) + len(self.resident) + len(self.closing)

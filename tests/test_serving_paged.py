@@ -148,7 +148,7 @@ def test_paged_scheduler_reserves_all_pages_up_front():
     assert (row[:3] > SCRATCH_PAGE).all()
     assert (row[3:] == SCRATCH_PAGE).all()
     sched.mark_prefilled(r, 6)
-    sched.retire(r)
+    sched.release(r)
     # full release: pages either free or held ONLY by the prefix cache
     assert pages.in_use == len(sched.prefix)
     assert (sched.block_tables[0] == SCRATCH_PAGE).all()
@@ -168,7 +168,7 @@ def test_paged_scheduler_head_blocking_keeps_fifo():
     assert sched.admit() == []
     assert pages.in_use == 4
     sched.mark_prefilled(hog, 12)
-    sched.retire(hog)
+    sched.release(hog)
     admitted = [req for _, req in sched.admit()]
     assert admitted[0] is big                           # FIFO restored
     assert small in admitted
@@ -207,14 +207,14 @@ def test_paged_scheduler_1k_churn_leaks_no_pages():
         if live and rng.rand() < 0.7:
             req = live.pop(int(rng.randint(len(live))))
             sched.mark_prefilled(req, len(req.prompt))
-            sched.retire(req)
+            sched.release(req)
     for req in live:
         sched.mark_prefilled(req, len(req.prompt))
-        sched.retire(req)
+        sched.release(req)
     while sched.queue:
         for _, req in sched.admit():
             sched.mark_prefilled(req, len(req.prompt))
-            sched.retire(req)
+            sched.release(req)
     assert pages.in_use == len(sched.prefix)
     sched.prefix.clear()
     assert pages.in_use == 0
@@ -388,9 +388,10 @@ def traced(request, tmp_path):
 
 
 def _paged(model, **kw):
-    return PagedContinuousBatchingEngine(model, num_seqs=2, max_len=32,
-                                         page_size=8, prefill_chunk=8,
-                                         decode_block=2, **kw)
+    args = dict(num_seqs=2, max_len=32, page_size=8, prefill_chunk=8,
+                decode_block=2)
+    args.update(kw)
+    return PagedContinuousBatchingEngine(model, **args)
 
 
 def _dur(s):
@@ -402,51 +403,87 @@ def test_step_span_has_its_phases_as_children(model, traced):
     tr, clock = traced
     eng = _paged(model)
     eng.metrics._clock = clock          # the engine on the same clock
+    # two slots, three requests: while the third is queued a burst stays
+    # in flight across the step's return; once nothing is queued and a
+    # slot is free, a step waits for its own burst before it returns
     reqs = [eng.add_request(list(range(1, 12)), max_new_tokens=4),
-            eng.add_request([5, 6, 7], max_new_tokens=4)]
+            eng.add_request([5, 6, 7], max_new_tokens=8),
+            eng.add_request([9, 8], max_new_tokens=4)]
     eng.run()
-    assert all(len(r.tokens) == 4 for r in reqs)
+    assert [len(r.tokens) for r in reqs] == [4, 8, 4]
     spans = tr.recorder.spans()
     steps = [s for s in spans if s['name'] == 'serving.step']
     assert [s['tags']['step'] for s in steps] == list(
         range(1, len(steps) + 1))
-    assert steps[0]['tags']['queue_depth'] == 2
+    assert steps[0]['tags']['queue_depth'] == 3
     assert steps[0]['tags']['residents'] == 0
     assert all(s['tags']['cpu_s'] >= 0.0 for s in steps)
     assert steps[0]['tags']['pages_in_use'] == 0
     kids = {}
     for s in spans:
         kids.setdefault(s['parent_id'], []).append(s)
-    bursts = calls = 0
+    waits, calls = {}, 0
     for st in steps:
-        mine = kids[st['span_id']]
-        names = [k['name'] for k in mine if k['name'] != 'perf.straggler']
-        assert names[:2] == ['serving.step.admit', 'serving.step.prefill']
-        assert names[2:] in ([], ['serving.decode_burst'])
+        mine = [k for k in kids[st['span_id']]
+                if k['name'] != 'perf.straggler']
+        names = [k['name'] for k in mine]
+        # a wait for the burst in flight comes first, one for the step's
+        # own burst (where it does not stay in flight) last
+        phases = [n for n in names if n != 'serving.step.wait']
+        assert phases == ['serving.step.admit', 'serving.step.prefill']
+        assert names.count('serving.step.wait') <= 2
         for k in mine:                  # inside the parent, in order
             assert st['start_mono'] <= k['start_mono'] <= k['end_mono'] \
                 <= st['end_mono']
         assert sum(_dur(k) for k in mine) <= _dur(st)
-        pre = mine[1]
+        pre = next(k for k in mine if k['name'] == 'serving.step.prefill')
         pcs = kids.get(pre['span_id'], [])
         assert [c['name'] for c in pcs] == ['serving.prefill_call'] * len(
             pcs)
         assert pre['tags']['calls'] == len(pcs)
         assert pre['tags']['tokens'] == sum(c['tags']['tokens']
                                             for c in pcs)
+        assert pre['tags']['picks'] == sum(c['tags']['final'] for c in pcs)
         assert sum(_dur(c) for c in pcs) <= _dur(pre)
         calls += len(pcs)
-        for b in mine[2:3]:
-            # ONE set of clock reads: the tags split the span exactly
-            assert b['tags']['dispatch_s'] + b['tags']['block_s'] == \
-                pytest.approx(_dur(b))
-            bursts += 1
-    assert calls == 3                   # 11 tokens: 2 chunks; 3 tokens: 1
-    assert bursts == eng.timeline.steps > 0
-    admit0 = kids[steps[0]['span_id']][0]['tags']
-    assert admit0 == {'admitted': 2, 'left': 0, 'head_left': 'none'}
+        for w in mine:
+            if w['name'] == 'serving.step.wait':
+                assert w['tags']['waited_s'] == _dur(w)
+                waits[w['tags']['burst']] = (w, st)
+    assert calls == 4                   # 11 tokens: 2 chunks; the others 1
+    # a burst runs from its dispatch to the host's knowing that it
+    # ended: no step's child, and the wait's span says how much of it
+    # the host really waited
+    bursts = [s for s in spans if s['name'] == 'serving.decode_burst']
+    assert [b['tags']['burst'] for b in bursts] == list(
+        range(1, len(bursts) + 1))
+    assert len(bursts) == len(waits) == eng.timeline.steps > 0
+    left_in_flight = 0
+    for b in bursts:
+        assert b['parent_id'] is None
+        w, ended_in = waits[b['tags']['burst']]
+        assert b['end_mono'] == w['end_mono']
+        assert b['tags']['block_s'] == w['tags']['waited_s']
+        assert b['tags']['dispatch_s'] + b['tags']['block_s'] <= _dur(b)
+        dispatched_in = [st for st in steps if st['start_mono']
+                         <= b['start_mono'] <= st['end_mono']]
+        assert len(dispatched_in) == 1
+        stayed = dispatched_in[0]['tags']['tail_under_burst']
+        assert ended_in['tags']['step'] == \
+            dispatched_in[0]['tags']['step'] + stayed
+        left_in_flight += stayed
+    # in flight while the third request was queued, not after
+    tails = [st['tags']['tail_under_burst'] for st in steps]
+    assert tails[0] is True and tails[-1] is False
+    assert tails == sorted(tails, reverse=True)
+    assert 0 < left_in_flight == sum(tails) < len(bursts)
+    fam = eng.metrics.registry.get('serving_steps_total')
+    assert fam.labels('overlapped').value() == sum(tails)
+    assert fam.labels('exposed').value() == len(steps) - sum(tails)
+    admit0 = kids[steps[0]['span_id']][0]['tags']    # no wait in step 1
+    assert admit0 == {'admitted': 2, 'left': 1, 'head_left': 'slots'}
     reg = eng.metrics.registry
-    assert reg.get('serving_prefill_calls_total').value() == 3
+    assert reg.get('serving_prefill_calls_total').value() == 4
 
 
 def test_decode_program_ops_carry_the_scope_names(model):
@@ -692,7 +729,7 @@ def test_admit_pass_counts_and_causes_on_full_pool_and_full_slots():
     assert big._admit_waits == {'pages': 2}
     assert small._admit_waits == {'behind_head': 2}
     sched.mark_prefilled(hog, 12)
-    sched.retire(hog)
+    sched.release(hog)
     assert len(sched.admit()) == 2 and sched.head_left == 'none'
     assert big._admit_waits == {'pages': 2}             # kept, not reset
     # the SLOTS are full (one slot, pages to spare)
@@ -705,7 +742,7 @@ def test_admit_pass_counts_and_causes_on_full_pool_and_full_slots():
     assert (b._admit_waits, c._admit_waits) == (
         {'slots': 1}, {'behind_head': 1})
     sched.mark_prefilled(a, 3)
-    sched.retire(a)
+    sched.release(a)
     assert [r for _, r in sched.admit()] == [b]
     assert c._admit_waits == {'slots': 1, 'behind_head': 1}
 
@@ -809,7 +846,9 @@ def test_on_token_sees_each_delivered_token_once(model):
     assert len(order) == 24
 
 
-def test_straggler_record_says_which_phase(model, traced, tmp_path):
+@pytest.mark.parametrize('queued', [False, True],
+                         ids=['idle_queue', 'queued'])
+def test_straggler_record_says_which_phase(model, traced, tmp_path, queued):
     import json
     import os
     import time
@@ -821,6 +860,9 @@ def test_straggler_record_says_which_phase(model, traced, tmp_path):
     eng.timeline = StepTimeline(registry=eng.metrics.registry, tracer=tr,
                                 min_history=3, straggler_factor=20.0)
     eng.add_request([1, 2, 3], max_new_tokens=24)
+    if queued:                # one behind the two slots: bursts stay in
+        eng.add_request([4, 5], max_new_tokens=24)              # flight
+        eng.add_request([6], max_new_tokens=2)
     for _ in range(5):
         eng.step()
     fast = eng._decode_jit
@@ -833,27 +875,407 @@ def test_straggler_record_says_which_phase(model, traced, tmp_path):
     eng.step()
     eng._decode_jit = fast
     eng.run()
+    # the record is the burst's, from its dispatch to its end, and
+    # carries the phases of the step that saw it end: step 6 itself, or
+    # step 7 where the burst stayed in flight across step 6's return
+    at = 6 + queued
     recs = [s for s in tr.recorder.spans() if s['name'] == 'perf.straggler'
-            and s['tags'].get('engine_step') == 6]
+            and s['tags'].get('engine_step') == at]
     assert len(recs) == 1 and eng.timeline.stragglers >= 1
     tags = recs[0]['tags']
     assert set(tags) >= {'total_s', 'median_s', 'step', 'engine_step',
-                         'step_s', 'admit_s', 'prefill_s',
+                         'step_s', 'wait_s', 'admit_s', 'prefill_s',
                          'burst_dispatch_s', 'burst_block_s', 'self_s',
                          'cpu_s', 'compiles'}
     assert tags['compiles'] == 0
     assert tags['burst_dispatch_s'] >= 0.5 > tags['burst_block_s']
-    assert tags['cpu_s'] < 0.5 <= tags['step_s']    # waiting, not busy
-    parts = sum(tags[k] for k in ('admit_s', 'prefill_s',
-                                  'burst_dispatch_s', 'burst_block_s',
+    assert tags['total_s'] == pytest.approx(
+        tags['burst_dispatch_s'] + tags['burst_block_s'], abs=1e-5)
+    assert tags['wait_s'] <= tags['burst_block_s'] + 1e-9
+    parts = sum(tags[k] for k in ('wait_s', 'admit_s', 'prefill_s',
                                   'self_s'))
     assert parts == pytest.approx(tags['step_s'])
     step = next(s for s in tr.recorder.spans()
                 if s['span_id'] == recs[0]['parent_id'])
-    assert step['name'] == 'serving.step' and step['tags']['step'] == 6
+    assert step['name'] == 'serving.step' and step['tags']['step'] == at
+    slow_step = next(s for s in tr.recorder.spans()
+                     if s['name'] == 'serving.step'
+                     and s['tags']['step'] == 6)
+    # waiting, not busy; and where the burst stayed in flight the half
+    # second sat in step 6, not in the step that found it ended
+    assert _dur(slow_step) >= 0.5 > slow_step['tags']['cpu_s']
+    assert (tags['step_s'] >= 0.5) == (not queued)
     # the road a recompile record takes: ring + one throttled dump
     dumps = os.listdir(str(tmp_path / 'flight'))
     assert dumps == ['flight_straggler_0001.json']      # throttled: one
     with open(os.path.join(str(tmp_path / 'flight'), dumps[0])) as f:
         assert any(s['name'] == 'perf.straggler'
                    for s in json.load(f)['spans'])
+
+
+# ---- one burst in flight: the step's order -------------------------------
+
+
+from paddle_tpu.serving import engine as engine_mod            # noqa: E402
+from paddle_tpu.text.models import olmo_hybrid as O            # noqa: E402
+
+
+@pytest.fixture(scope='module')
+def recurrent():
+    """A tiny model with per-slot recurrent state (gated delta-rule
+    layers 3:1 with full attention), as tests/test_olmo_hybrid.py's."""
+    paddle.seed(11)
+    m = O.OlmoHybridForCausalLM(O.OlmoHybridConfig(
+        vocab_size=211, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=4,
+        max_position_embeddings=128, linear_num_key_heads=4,
+        linear_num_value_heads=4, linear_key_head_dim=8,
+        linear_value_head_dim=16))
+    m.eval()
+    return m
+
+
+def _tails(model, prompts, budgets, **sampling):
+    return [[int(t) for t in model.generate(
+        paddle.to_tensor([p]), max_new_tokens=n, **s).numpy()[0][len(p):]]
+        for p, n, s in zip(prompts, budgets, _per_request(sampling,
+                                                          len(prompts)))]
+
+
+def _per_request(sampling, n):
+    """The i-th request's sampling arguments: `seed` counts up."""
+    return [dict(sampling, seed=sampling['seed'] + i) if sampling else {}
+            for i in range(n)]
+
+
+def _churn():
+    """More requests than slots, unequal budgets (one of a single
+    token, one that ends mid-burst), two prompts that share a block."""
+    rng = np.random.RandomState(3)
+    shared = [int(t) for t in rng.randint(0, 211, 8)]
+    prompts = [[int(t) for t in rng.randint(0, 211, n)]
+               for n in (5, 11, 3, 9)]
+    prompts += [shared + [4, 5, 6], shared + [9]]
+    return prompts, [7, 1, 12, 6, 5, 9]
+
+
+@pytest.mark.parametrize('sampling', [
+    {}, {'do_sample': True, 'temperature': 0.8, 'top_k': 20, 'seed': 5}],
+    ids=['greedy', 'sampled'])
+def test_tokens_equal_generates_over_admissions_and_retirements(
+        model, sampling):
+    prompts, budgets = _churn()
+    want = _tails(model, prompts, budgets, **sampling)
+    eng = _paged(model)
+    reqs = [eng.add_request(p, max_new_tokens=n, **s) for p, n, s in zip(
+        prompts, budgets, _per_request(sampling, len(prompts)))]
+    eng.run()
+    assert [r.tokens for r in reqs] == want
+    assert eng.metrics.report()['prefix_hits'] > 0
+    assert eng._flight is None and eng._landed is None
+    assert eng.compiled_sizes() == {'prefill': 1, 'decode': 1, 'verify': 0}
+
+
+@pytest.mark.parametrize('sampling', [
+    {}, {'do_sample': True, 'temperature': 0.7, 'top_k': 0, 'seed': 2}],
+    ids=['greedy', 'sampled'])
+def test_a_victim_preempted_under_a_burst_resumes_to_the_same_tokens(
+        model, sampling):
+    """The victim is evicted in the admit pass of a step that has just
+    fetched a burst: its tokens of that burst are not delivered twice
+    and not lost."""
+    prompts, budgets = _churn()[0][:3], [12, 12, 6]
+    want = _tails(model, prompts, budgets, **sampling)
+    per = _per_request(sampling, 3)
+    eng = _paged(model, preempt=True)
+    seen = [[], [], []]
+    r0, r1 = (eng.add_request(prompts[i], max_new_tokens=budgets[i],
+                              on_token=seen[i].append, **per[i])
+              for i in range(2))
+    while min(len(r0.tokens), len(r1.tokens)) < 3:
+        eng.step()
+    assert eng._flight is not None
+    r2 = eng.add_request(prompts[2], max_new_tokens=budgets[2], priority=1,
+                         on_token=seen[2].append, **per[2])
+    eng.run()
+    assert eng.scheduler.preempted == 1
+    assert [r0.tokens, r1.tokens, r2.tokens] == want == seen
+
+
+def test_a_recurrent_models_tokens_are_its_own_forwards(recurrent):
+    """State per slot: a slot released by count is re-used in the same
+    step, and its new occupant starts from zeros."""
+    rng = np.random.RandomState(6)
+    prompts = [[int(t) for t in rng.randint(0, 211, n)]
+               for n in (19, 7, 30, 12, 5)]
+    budgets = [17, 1, 10, 12, 7]
+    eng = PagedContinuousBatchingEngine(
+        recurrent, num_seqs=2, max_len=64, page_size=8, prefill_chunk=16,
+        decode_block=4, prefix_cache=False, preempt=True)
+    reqs = [eng.add_request(p, max_new_tokens=n)
+            for p, n in zip(prompts[:4], budgets)]
+    while len(reqs[0].tokens) < 3:
+        eng.step()
+    reqs.append(eng.add_request(prompts[4], max_new_tokens=budgets[4],
+                                priority=1))
+    eng.run()
+    assert eng.scheduler.preempted == 1
+    ids = np.zeros((5, 64), np.int64)
+    for row, p, r in zip(ids, prompts, reqs):
+        assert len(r.tokens) == r.max_new_tokens
+        row[:len(p) + len(r.tokens)] = p + r.tokens
+    picks = recurrent(paddle.to_tensor(ids)).numpy().argmax(-1)
+    for row, p, r in zip(picks, prompts, reqs):
+        assert list(row[len(p) - 1:len(p) - 1 + len(r.tokens)]) == r.tokens
+
+
+def _serial_schedule(prompts, budgets, block, **sched_kw):
+    """{request index: engine step it is admitted in} under the SERIAL
+    order (admit, prefill, burst, retire, all inside one step), from the
+    scheduler's bookkeeping alone: what the engine's order must keep."""
+    sched, _ = _mk_sched(**sched_kw)
+    reqs = [Request(p, max_new_tokens=n) for p, n in zip(prompts, budgets)]
+    for r in reqs:
+        sched.submit(r)
+    gen, when, step = {}, {}, 0
+    while sched.pending:
+        step += 1
+        for _, r in sched.admit():
+            when[reqs.index(r)] = step
+        for r, start, _, valid, final in sched.prefill_plan():
+            sched.mark_prefilled(r, start + valid)
+            if final:
+                gen[r.id] = 1
+        for r in [sched.resident[s] for s in sched.decode_slots()]:
+            gen[r.id] = min(gen[r.id] + block, r.max_new_tokens)
+        for r in [r for r in sched.resident.values()
+                  if gen.get(r.id, 0) >= r.max_new_tokens]:
+            sched.release(r)
+            sched.finish(r)
+    return when
+
+
+def test_the_schedule_is_the_serial_orders(model, traced):
+    """Two slots, a pool the two residents fill, three requests: the
+    third enters in the step that fetches the first one's last burst,
+    into the slot and the pages that step released by count, before the
+    first one's last tokens are delivered."""
+    tr, _ = traced
+    prompts = [[1, 2, 3], [4, 5, 6, 7], [8, 9]]
+    budgets = [5, 13, 6]
+    shape = dict(num_seqs=2, num_pages=5, max_len=32, chunk=8, page=8,
+                 prefix=False)
+    eng = _paged(model, num_pages=5, prefix_cache=False, decode_block=4)
+    reqs = [eng.add_request(p, max_new_tokens=n)
+            for p, n in zip(prompts, budgets)]
+    seen = []
+    admit = eng.scheduler.admit
+
+    def watched():
+        got = admit()
+        for slot, r in got:
+            seen.append((reqs.index(r), eng._step_index, slot,
+                         set(eng.scheduler.block_tables[slot])
+                         - {SCRATCH_PAGE}, len(reqs[0].tokens),
+                         reqs[0].done, eng.pages.in_use))
+        return got
+    eng.scheduler.admit = watched
+    eng.step()
+    # burst 1 takes request 0 to its budget of 5: by count, its slot and
+    # pages are back where the burst is dispatched, the request pending
+    assert eng._flight is not None and reqs[0].slot is None
+    assert (eng.allocator.in_use, eng.scheduler.pending) == (1, 3)
+    assert not reqs[0].done and len(reqs[0].tokens) == 1
+    eng.run()
+    when = {i: step for i, step, *_ in seen}
+    assert when == _serial_schedule(prompts, budgets, 4, **shape) \
+        == {0: 1, 1: 1, 2: 2}
+    first_slot, first_pages = seen[0][2:4]
+    i, step, slot, pages, delivered, done, in_use = seen[2]
+    # step 2 fetched burst 1: request 0's slot and pages were request
+    # 2's in that very pass, while request 0's last four tokens were
+    # still to be delivered
+    assert slot == first_slot and pages & first_pages
+    assert (delivered, done) == (1, False)
+    assert [len(r.tokens) for r in reqs] == budgets
+    # the engine's own record agrees: `admitted` events by engine step
+    steps = [s for s in tr.recorder.spans() if s['name'] == 'serving.step']
+    for r in reqs:
+        span = next(s for s in tr.recorder.spans()
+                    if s['name'] == 'serving.request'
+                    and s['tags']['request_id'] == r.id)
+        at = next(e['mono'] for e in span['events']
+                  if e['name'] == 'admitted')
+        inside = [s['tags']['step'] for s in steps
+                  if s['start_mono'] <= at <= s['end_mono']]
+        assert inside == [when[reqs.index(r)]]
+
+
+@pytest.mark.parametrize('slots,pages', [(2, 9), (3, 7), (4, 33)])
+def test_a_backlogs_schedule_is_the_serial_orders(model, slots, pages):
+    """A backlog limited by slots or by pages: every request enters at
+    the engine step the serial order admits it in."""
+    rng = np.random.RandomState(slots)
+    prompts = [[int(t) for t in rng.randint(0, 211, int(rng.randint(1, 20)))]
+               for _ in range(14)]
+    budgets = [int(n) for n in rng.randint(1, 12, 14)]
+    eng = PagedContinuousBatchingEngine(
+        model, num_seqs=slots, max_len=32, page_size=8, prefill_chunk=8,
+        decode_block=4, num_pages=pages, prefix_cache=False)
+    reqs = [eng.add_request(p, max_new_tokens=n)
+            for p, n in zip(prompts, budgets)]
+    when, admit = {}, eng.scheduler.admit
+
+    def watched():
+        got = admit()
+        when.update((reqs.index(r), eng._step_index) for _, r in got)
+        return got
+    eng.scheduler.admit = watched
+    eng.run()
+    assert when == _serial_schedule(
+        prompts, budgets, 4, num_seqs=slots, num_pages=pages, max_len=32,
+        chunk=8, page=8, prefix=False)
+    assert [len(r.tokens) for r in reqs] == budgets
+
+
+def test_a_bursts_tokens_come_after_the_next_bursts_dispatch(model, traced):
+    """While a request is queued behind the one slot, burst K's tokens
+    reach `on_token` after burst K+1 is on the device; once nobody is
+    queued and a slot is free, a step delivers its own burst's tokens
+    before it returns."""
+    import time
+    tr, _ = traced
+    eng = _paged(model, num_seqs=1, decode_block=4)
+    got = {'a': [], 'b': []}
+
+    def sink(name):
+        return lambda t: got[name].append(
+            (time.monotonic(), eng._bursts, eng._flight is not None))
+    a = eng.add_request([1, 2, 3], max_new_tokens=11, on_token=sink('a'))
+    b = eng.add_request([4, 5], max_new_tokens=7, on_token=sink('b'))
+    eng.run()
+    assert (len(a.tokens), len(b.tokens)) == (11, 7)
+    starts = {s['tags']['burst']: s['start_mono']
+              for s in tr.recorder.spans()
+              if s['name'] == 'serving.decode_burst'}
+    assert sorted(starts) == [1, 2, 3, 4, 5]
+    # a's first token (its final chunk's pick): burst 1 goes behind the
+    # prefill call before the pick is read; tokens 2-5 are burst 1's,
+    # delivered under burst 2; 6-9 under burst 3, which closes the lane
+    # by count after 2 of its 4 steps; step 4 admits b into the freed
+    # slot and delivers a's last two under b's first burst
+    assert [(n, under) for _, n, under in got['a']] == (
+        [(1, True)] + [(2, True)] * 4 + [(3, True)] * 4 + [(4, True)] * 2)
+    # b: nobody is queued behind it, but the one slot is its own, so its
+    # burst 4 stays in flight; burst 5 closes it, the slot is free, and
+    # the step that dispatched burst 5 delivers its tokens itself
+    assert [(n, under) for _, n, under in got['b']] == (
+        [(4, True)] + [(5, True)] * 4 + [(5, False)] * 2)
+    for t, n, _ in got['a'] + got['b']:
+        assert t > starts[n]
+    tails = [s['tags']['tail_under_burst'] for s in tr.recorder.spans()
+             if s['name'] == 'serving.step']
+    assert tails == [True, True, True, True, False]
+
+
+def test_the_host_counts_what_the_device_counts(model, monkeypatch):
+    """Lengths and counts are kept by arithmetic on the host and carried
+    by the programs on the device: they agree at every dispatch, and a
+    burst that follows a burst sends no lane array."""
+    eng = _paged(model, decode_block=2)
+    puts = []
+    put = jax.device_put
+    monkeypatch.setattr(engine_mod.jax, 'device_put',
+                        lambda x, *a, **k: (puts.append(1), put(x, *a, **k))[1])
+    reqs = [eng.add_request([1, 2, 3], max_new_tokens=9),
+            eng.add_request([4, 5], max_new_tokens=5),
+            eng.add_request([6], max_new_tokens=5)]
+    sent, checked = [], 0
+    while eng.scheduler.pending:
+        before = len(puts)
+        eng.step()
+        sent.append(len(puts) - before)
+        flight = eng._flight
+        if flight is None:
+            continue
+        # the host holds the lanes as the burst in flight leaves them
+        # (a lane it closes is cleared on the host, and sent again)
+        live = [slot for slot, _, _, closed in flight.lanes if not closed]
+        _, dev_lens, dev_gen = eng._lane_args[:3]
+        assert (np.asarray(dev_lens)[live] == eng._lens[live]).all()
+        assert (np.asarray(dev_gen)[live] == eng._gen[live]).all()
+        assert (eng._gen[live] < eng._budgets[live]).all()
+        checked += len(live)
+    assert [len(r.tokens) for r in reqs] == [9, 5, 5] and checked >= 4
+    # step 1 admits two (sent); step 2 follows a burst with a burst and
+    # closes the second request under it; step 3 admits the third into
+    # its slot (sent); step 4 follows and closes both: nothing queued,
+    # both slots free, so it waits for its own burst
+    assert sent == [1, 0, 1, 0]
+
+
+def test_no_door_loses_a_token_with_a_burst_in_flight(model):
+    prompts = [[1, 2, 3], [4, 5, 6, 7, 8], [9]]
+    want = _tails(model, prompts, [9, 9, 9])
+    # (the third request is queued behind the two slots: bursts stay in
+    # flight.) shutdown() brings the burst home: what was generated is
+    # delivered
+    eng = _paged(model, decode_block=4)
+    reqs = [eng.add_request(p, max_new_tokens=9) for p in prompts]
+    eng.step()
+    assert eng._flight is not None
+    assert [len(r.tokens) for r in reqs] == [1, 1, 0]
+    eng.shutdown()
+    assert eng._flight is None
+    assert [len(r.tokens) for r in reqs] == [5, 5, 0]
+    assert eng.scheduler.pending == 3       # still to be driven home
+    eng.run()
+    assert [r.tokens for r in reqs] == want and eng.scheduler.pending == 0
+    # a request a burst in flight will finish is released by count and
+    # still pending, so run() and `while step()` drain it
+    eng = _paged(model, decode_block=4)
+    reqs = [eng.add_request(p, max_new_tokens=9) for p in prompts]
+    in_flight = 0
+    while eng.step():
+        in_flight += eng._flight is not None
+        assert eng.scheduler.pending == len(eng.scheduler.queue) + len(
+            eng.scheduler.resident) + len(eng.scheduler.closing)
+    assert in_flight >= 2
+    assert [r.tokens for r in reqs] == want and eng._flight is None
+    assert all(r.done and r.wait(0) for r in reqs)
+    # generate() and stream()
+    eng = _paged(model, decode_block=4)
+    assert eng.generate(prompts, max_new_tokens=9) == want
+    streamed = [eng.add_request(p, max_new_tokens=9, stream=True)
+                for p in prompts]
+    assert [list(eng.stream(r)) for r in streamed] == want
+    assert eng.scheduler.pending == 0 and eng._flight is None
+
+
+def test_an_idle_queue_gets_an_idle_device_back(model):
+    """Nothing queued and a slot free: whoever arrives next could be
+    admitted at the next pass, so the step waits for its own burst and
+    its tokens are on the request when step() returns."""
+    eng = _paged(model, decode_block=4)
+    fam = eng.metrics.registry.get('serving_steps_total')
+    before = fam.labels('overlapped').value()
+    req = eng.add_request([1, 2, 3], max_new_tokens=9)
+    eng.step()
+    assert eng._flight is None and len(req.tokens) == 5
+    late = eng.add_request([4, 5], max_new_tokens=3)    # arrives between
+    eng.step()                                          # two steps
+    assert len(late.tokens) == 3 and late.done          # no burst to wait out
+    assert len(req.tokens) == 9 and req.done
+    assert fam.labels('overlapped').value() == before
+
+
+@pytest.mark.parametrize('seed', [0, 1, 7, 2 ** 31 - 1, 2 ** 31 + 5,
+                                  2 ** 32 - 1, 2 ** 32 + 9, 2 ** 40 + 3,
+                                  -1, -12345])
+@pytest.mark.parametrize('x64', [False, True])
+def test_the_host_made_key_is_jaxs(seed, x64):
+    with jax.enable_x64(x64):
+        want = np.asarray(jax.random.PRNGKey(seed))
+        got = engine_mod._prng_key(seed)
+    assert got.dtype == want.dtype == np.uint32
+    assert got.tolist() == want.tolist()

@@ -608,8 +608,9 @@ def test_every_span_carries_ordered_monotonic_stamps(model, traced):
     spans = tr.recorder.spans()
     assert {s['name'] for s in spans} >= {
         'serving.request', 'serving.prefill', 'serving.decode',
-        'serving.step', 'serving.step.admit', 'serving.step.prefill',
-        'serving.prefill_call', 'serving.decode_burst'}
+        'serving.step', 'serving.step.wait', 'serving.step.admit',
+        'serving.step.prefill', 'serving.prefill_call',
+        'serving.decode_burst'}
     for s in spans:
         assert t_before <= s['start_mono'] <= s['end_mono'] <= t_after
         assert s['start'] <= s['end'] and abs(s['start'] - time.time()) < 600
@@ -625,10 +626,13 @@ def test_every_span_carries_ordered_monotonic_stamps(model, traced):
         assert s['start_mono'] == ev['queued']['mono'] == r._arrival_t
         assert ev['admitted']['mono'] == r._admit_t
         assert ev['first_token']['mono'] == r._first_token_t
-    # the burst is a child of its step now, not a root
+    # a burst outlives the step that dispatched it: it is a root, and
+    # the step that fetches it has the wait as its child
     steps = {s['span_id'] for s in spans if s['name'] == 'serving.step'}
-    assert all(s['parent_id'] in steps for s in spans
+    assert all(s['parent_id'] is None for s in spans
                if s['name'] == 'serving.decode_burst')
+    assert all(s['parent_id'] in steps for s in spans
+               if s['name'] == 'serving.step.wait')
 
 
 def test_annotated_span_is_the_one_dual_sink_path(traced, monkeypatch):

@@ -375,8 +375,11 @@ class PagedContinuousBatchingEngine:
         self._prefix_seen = [0, 0]    # hit/miss totals already reported
         # which K/V read each program took ('pool' | 'gather'), written
         # where the program is traced (`_unpack`) from what attention
-        # recorded on the caches it returned; the spans' `kv_read` tag
+        # recorded on the caches it returned; the spans' `kv_read` tag.
+        # Likewise which product an expert layer took ('dense' |
+        # 'grouped'), for a model that has one: the tag `experts`
         self.kv_read = {}
+        self.experts = {}
         if donate is None:
             donate = jax.default_backend() in ('tpu', 'gpu')
         dn = (2,) if donate else ()
@@ -825,7 +828,8 @@ class PagedContinuousBatchingEngine:
                                    final=final,
                                    kv_read=self.kv_read['prefill'],
                                    pick=('sample' if req.do_sample
-                                         else 'argmax'))
+                                         else 'argmax'),
+                                   **self._expert_tag('prefill'))
             calls += 1
             tokens += valid
             self.metrics.on_prefill_tokens(valid)
@@ -1048,7 +1052,13 @@ class PagedContinuousBatchingEngine:
     def _unpack(self, program, pools, caches, slot=None):
         self.kv_read[program] = next(
             (c.kv_read for c in caches if hasattr(c, 'block_tables')), None)
+        self.experts[program] = next(
+            (c.experts for c in caches if getattr(c, 'experts', None)), None)
         return layer_state(pools, caches, slot)
+
+    def _expert_tag(self, program):
+        return ({'experts': self.experts[program]}
+                if self.experts.get(program) else {})
 
     def _prefill_fn(self, params, bufs, pools, bt1, len1, ids, valid,
                     key, temp, topk, sample, slot=None):
@@ -1209,6 +1219,8 @@ class PagedContinuousBatchingEngine:
         (self._pools, lens, self._last, gen, self._keys, toks, _,
          counted) = self._decode_jit(*args)
         span.set_tag('kv_read', self.kv_read['decode'])
+        for name, value in self._expert_tag('decode').items():
+            span.set_tag(name, value)
         self._lane_args = [bt, lens, gen] + self._lane_args[3:]
         for out in (toks, *counted.values()):
             out.copy_to_host_async()

@@ -573,6 +573,7 @@ class KimiLinearBlock(nn.Layer):
             # the tokens the counters count: the call's real ones
             y, new_cache.counters = self.mlp(
                 u, jnp.arange(x.shape[1])[None, :] < cache.valid[:, None])
+            new_cache.experts = self.mlp.path
         elif self.sparse:
             y = self.mlp(u)
         else:

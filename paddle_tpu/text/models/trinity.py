@@ -275,6 +275,7 @@ class TrinityBlock(nn.Layer):
                 b, jnp.arange(x.shape[1])[None, :] < cache.valid[:, None])
             new_cache.counters = dict(
                 getattr(new_cache, 'counters', None) or {}, **counted)
+            new_cache.experts = self.mlp.path
         elif self.sparse:
             y = self.mlp(b)
         else:

@@ -481,3 +481,51 @@ def test_trinity_programs_fit_64_slots(chip, on_chip_dispatch):
     # no ring is re-laid and no sequence's pages are gathered: a copy of
     # one layer's rings alone would be 1.07 GB (0.37 GB measured)
     assert decode.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+# (hidden, expert width, top-k, held) of the two served configurations'
+# expert layers, 256 experts each
+EXPERTS = {'trinity': (3072, 3072, 4, 16), 'kimi': (2304, 1024, 8, 64)}
+
+
+@pytest.mark.parametrize('family,rows', [('trinity', 512), ('trinity', 64),
+                                         ('kimi', 512)])
+def test_expert_layer_multiplies_the_chosen_pairs_on_v5e(
+        chip, on_chip_dispatch, family, rows):
+    """The routed part of the expert layer at the configurations' widths,
+    for a prefill chunk of 512 rows and Trinity's decode step of 64: the
+    shape rule takes the grouped product, the megablox kernel compiles
+    (Mosaic's custom call, no dense product left), its operations are
+    those of the rows' chosen pairs and their padding to whole row tiles
+    — at most half of every row through every held expert, whose
+    quarter (Trinity) or eighth (Kimi-Linear) the pairs are — and the
+    program fits the chip."""
+    from paddle_tpu import nn
+    from paddle_tpu.text.models import moe
+    d, f, top_k, held = EXPERTS[family]
+    with nn.skip_init():
+        layer = moe.SparseMoe(d, f, 256, top_k, 2.0, (16, 16 + held))
+    ws = [jax.ShapeDtypeStruct(w._data.shape, jnp.float32 if i < 2
+                               else jnp.bfloat16, sharding=chip)
+          for i, w in enumerate((layer.router, layer.e_score_correction_bias,
+                                 layer.gate_proj, layer.up_proj,
+                                 layer.down_proj))]
+    x = jax.ShapeDtypeStruct((rows, 1, d), jnp.bfloat16, sharding=chip)
+    real = jax.ShapeDtypeStruct((rows, 1), jnp.bool_, sharding=chip)
+    compiled = jax.jit(layer._routed).lower(x, real, *ws).compile()
+    assert layer.path == 'grouped'
+    text = compiled.as_text()
+    # three grouped products in the kernel; outside it the router's alone
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert len(re.findall(r' (?:convolution|dot)\(', text)) == 1
+    # the kernel's operations are those of its rows: the chosen pairs and
+    # the most their padding to whole tiles can be
+    tile = moe._row_tile(rows, top_k, 256)
+    most = rows * top_k + held * (tile - 1)
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    per_row = 2 * 3 * d * f
+    assert rows * top_k * per_row <= cost['flops'] \
+        < (-(-most // tile) * tile + 64) * per_row
+    assert cost['flops'] <= 0.5 * rows * held * per_row    # dense: all
+    assert _hbm_bytes(compiled) < HBM_BYTES - 1.5e9

@@ -292,9 +292,11 @@ def test_no_token_is_dropped_when_every_choice_falls_on_one_expert(
     moe = _moe_layer(family, stacked, 2, (5, 6))
     y, counters = moe(paddle.to_tensor(np.asarray(x)),
                       jnp.ones((2, 24), bool))
+    # 48 rows (under the ridge, every held expert touched): the dense
+    # product, one held expert times every row
     assert {k: int(v) for k, v in counters.items()} == {
         'moe_pairs': 192, 'moe_pairs_held': 48, 'moe_experts_touched': 1,
-        'moe_load_max': 48}
+        'moe_load_max': 48, 'moe_expert_rows': 48}
     p = _leaves(stacked, 2, 'mlp.')
     p.update({k: p[k][5:6] for k in ('gate_proj', 'up_proj', 'down_proj')})
     with reference.highest():
@@ -318,6 +320,97 @@ def test_a_token_none_of_whose_experts_is_held_gets_the_shared_alone(
     np.testing.assert_allclose(y._data, moe.shared(x)._data, atol=1e-7)
     # the normal path gives the same, and no counters
     np.testing.assert_allclose(moe(x)._data, y._data, atol=1e-7)
+
+
+# The grouped product against the dense one, each forced, over the same
+# weights: (experts held, tokens, the correction's favoured experts or
+# None, how many trailing tokens are not real). 3 tokens are 12 pairs,
+# inside one row tile (`_row_tile`: 8 rows); 300 are 1 200 pairs, past
+# nine tiles of 128
+GROUPED_CASES = {
+    'none-held': ((0, 4), 6, (8, 12), 0),
+    'one-expert': ((5, 6), 24, (4, 8), 0),
+    'not-real': ((4, 8), 24, None, 7),
+    'lo-past-0': ((8, 12), 24, None, 0),
+    'under-a-tile': ((4, 8), 3, None, 0),
+    'over-a-tile': ((12, 16), 300, None, 0),
+}
+
+
+@pytest.mark.parametrize('kernel', [True, False],
+                         ids=['megablox-interpreted', 'ragged-dot'])
+@pytest.mark.parametrize('case', sorted(GROUPED_CASES))
+def test_the_grouped_product_equals_the_dense_one(family, uncut, monkeypatch,
+                                                  case, kernel):
+    """Outputs and counters alike: only the rows the product multiplies
+    differ (every held expert times every row for the dense one; the
+    chosen pairs, each expert's rows up to whole tiles, for the grouped).
+    The megablox kernel under the Pallas interpreter, or XLA's ragged
+    dot where no kernel runs."""
+    from paddle_tpu.text.models import moe as moe_mod
+    held, t, favoured, fake = GROUPED_CASES[case]
+    whole, stacked = uncut
+    stacked = dict(stacked)
+    if favoured:
+        bias = np.zeros(16, np.float32)
+        bias[slice(*favoured)] = 10.0
+        stacked['model.layers.2.mlp.e_score_correction_bias'] = \
+            jnp.asarray(bias)
+    if kernel:
+        monkeypatch.setenv('PADDLE_TPU_FLASH_INTERPRET', '1')
+    moe = _moe_layer(family, stacked, 2, held)
+    x = jnp.asarray(np.random.RandomState(t).randn(1, t, 64), jnp.float32)
+    real = jnp.arange(t)[None, :] < t - fake
+    ws = [w._data for w in (moe.router, moe.e_score_correction_bias,
+                            moe.gate_proj, moe.up_proj, moe.down_proj)]
+    out = {}
+    for path in ('dense', 'grouped'):
+        monkeypatch.setattr(moe_mod, 'expert_path', lambda *a, p=path: p)
+        y, counters = moe._routed(x, real, *ws)
+        assert moe.path == path
+        out[path] = np.asarray(y), {k: int(v) for k, v in counters.items()}
+    (dense, c_dense), (grouped, c_grouped) = out['dense'], out['grouped']
+    # float32 both: the same products, summed in another order
+    np.testing.assert_allclose(grouped, dense,
+                               atol=1e-6 * max(1.0, np.abs(dense).max()))
+    rows = c_dense.pop('moe_expert_rows'), c_grouped.pop('moe_expert_rows')
+    assert c_grouped == c_dense
+    tile = moe_mod._row_tile(t, 4, 16)
+    assert rows[0] == (held[1] - held[0]) * t and rows[1] % tile == 0
+    if fake == 0:
+        # every held pair is multiplied, and a touched expert's rows are
+        # rounded up to whole tiles
+        pairs, touched = (c_dense[k] for k in ('moe_pairs_held',
+                                               'moe_experts_touched'))
+        assert pairs <= rows[1] <= pairs + touched * (tile - 1)
+    if case == 'none-held':
+        assert not grouped.any() and rows[1] == 0
+    if case == 'one-expert':
+        assert c_dense['moe_pairs_held'] == 24
+        assert rows[1] == -(-24 // tile) * tile
+
+
+@pytest.mark.parametrize('rows,top_k,held,path', [
+    (512, 4, 16, 'grouped'), (64, 4, 16, 'grouped'),
+    (512, 8, 64, 'grouped'), (128, 8, 64, 'dense')],
+    ids=['trinity-prefill', 'trinity-decode', 'kimi-prefill', 'kimi-decode'])
+def test_the_shape_rule_picks_each_served_call_s_product(rows, top_k, held,
+                                                         path):
+    """From the shapes alone, the served configurations' calls (a chunk
+    of 512, a decode step of every slot; top-k of 256): the dense
+    product is kept only where it reads its weights anyway, bandwidth
+    bound and nearly every held expert touched — Kimi-Linear's decode
+    step at 128 rows. The layer's own choice is the rule's."""
+    from paddle_tpu.text.models import moe as moe_mod
+    assert moe_mod.expert_path(rows, top_k, 256) == path
+    layer = moe_mod.SparseMoe(16, 8, 256, top_k, 1.0, (0, held))
+    x = jnp.zeros((rows, 1, 16), jnp.float32)
+    jax.eval_shape(lambda x, *ws: layer._routed(x, jnp.ones((rows, 1), bool),
+                                                *ws),
+                   x, *(w._data for w in (
+                       layer.router, layer.e_score_correction_bias,
+                       layer.gate_proj, layer.up_proj, layer.down_proj)))
+    assert layer.path == path
 
 
 # ---- each layer and the whole model against the reference -------------------

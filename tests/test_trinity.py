@@ -463,3 +463,34 @@ def test_the_decode_step_names_its_scopes(served):
                   'moe.route', 'moe.experts', 'moe.shared', 'mlp',
                   'lm_head'):
         assert 'trinity.' + scope in text, scope
+
+
+def test_the_spans_say_which_product_the_expert_layers_took(served):
+    """Each program's spans carry `experts`, the product its expert layers
+    took, which the shape rule gives for the program's rows alone (top 4
+    of 16: a chunk of 16 touches nearly every held expert, a decode step
+    of 3 slots about 58%), and a burst's counters the rows it
+    multiplied."""
+    from paddle_tpu.monitor.tracing import (FlightRecorder, Tracer,
+                                            set_default_tracer)
+    from paddle_tpu.text.models import moe
+    model, _, _ = served
+    tracer = Tracer(recorder=FlightRecorder(capacity=1024), enabled=True)
+    prev = set_default_tracer(tracer)
+    try:
+        eng = _engine(model, prefill_chunk=16)
+        eng.generate(_prompts(5, (20, 9)), max_new_tokens=6)
+    finally:
+        set_default_tracer(prev)
+    want = {'prefill': 'dense', 'decode': 'grouped'}
+    assert want == {p: moe.expert_path(n, 4, 16)
+                    for p, n in (('prefill', 16), ('decode', 3))}
+    assert eng.experts == want
+    spans = tracer.recorder.spans()
+    for name, program in (('serving.prefill_call', 'prefill'),
+                          ('serving.decode_burst', 'decode')):
+        tags = [s['tags'] for s in spans if s['name'] == name]
+        assert tags and all(t['experts'] == want[program] for t in tags)
+    # the grouped product's rows are whole tiles of 8
+    rows = eng._burst_counters['moe_expert_rows']
+    assert rows > 0 and rows % 8 == 0

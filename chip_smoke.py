@@ -67,34 +67,25 @@ def check(cond, what):
         raise AssertionError(what)
 
 
-class CompileClock:
-    """Seconds jax spent tracing, lowering and compiling (or loading from
-    the persistent cache) — so each phase reports compile and run apart."""
-
-    EVENTS = ('/jax/core/compile/jaxpr_trace_duration',
-              '/jax/core/compile/jaxpr_to_mlir_module_duration',
-              '/jax/core/compile/backend_compile_duration')
-
-    def __init__(self):
-        import jax
-        self.seconds = 0.0
-        self.programs = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **kw):
-        if event in self.EVENTS:
-            self.seconds += duration
-            self.programs += event == self.EVENTS[-1]
-
-    def close(self):
-        import jax
-        jax.monitoring.unregister_event_duration_listener(self._on)
-
-    def mark(self):
-        return self.seconds, self.programs
-
-    def since(self, mark):
-        return self.seconds - mark[0], self.programs - mark[1]
+def compile_line(phase, since_mono):
+    """What `phase` spent building programs since the monotonic stamp
+    `since_mono`, from the process's compile log: outer trace, lower and
+    compile (or load) seconds, the programs built and the five
+    costliest; 'not logged' where the log kept no record (tracing off,
+    or the log full)."""
+    from paddle_tpu.monitor.perf import compile_log
+    log = compile_log()
+    now = time.monotonic()
+    a, b = log.totals(since_mono), log.totals(now)
+    if a is None or b is None:
+        return '%s: compiling not logged' % phase
+    top = log.report(top=5, since_mono=since_mono, until_mono=now)
+    return '%s: %.1f s compiling %d program(s); costliest: %s' % (
+        phase, sum(b['outer_s'].values()) - sum(a['outer_s'].values()),
+        b['programs'] - a['programs'],
+        ', '.join('%s%s %.1f s' % (r['program'], ' (%s)' % r['owner']
+                                   if r['owner'] else '', r['seconds'])
+                  for r in top) or 'none')
 
 
 def memory_line(device):
@@ -159,10 +150,10 @@ def check_losses(losses, vocab, label):
           '%s: loss did not fall: %r' % (label, losses))
 
 
-def phase_train(device, widths, sizes, seed, clock):
+def phase_train(device, widths, sizes, seed):
     import jax
     from paddle_tpu.framework.functional import TrainStep, extract_params
-    t0, mark = time.perf_counter(), clock.mark()
+    t0, m0 = time.perf_counter(), time.monotonic()
     step, model = make_train_step(widths, sizes, seed, TrainStep)
     batch = train_batch(widths, sizes, seed)
 
@@ -195,14 +186,13 @@ def phase_train(device, widths, sizes, seed, clock):
     check(state and all(np.isfinite(a).all() for a in state.values()),
           'train: a parameter is unreadable or not finite after the steps')
 
-    compile_s, programs = clock.since(mark)
     print('train: %d steps, loss %.4f -> %.4f (ln vocab %.4f), %d parameters '
           'read back finite' % (len(losses), losses[0], losses[-1],
                                 math.log(widths['vocab_size']), len(state)))
-    print('train: %.1f s wall, %.1f s compiling %d program(s), steady step '
-          '%.1f ms (median of %d)'
-          % (time.perf_counter() - t0, compile_s, programs,
-             1e3 * float(np.median(times[2:])), len(times[2:])))
+    print('train: %.1f s wall, steady step %.1f ms (median of %d)'
+          % (time.perf_counter() - t0, 1e3 * float(np.median(times[2:])),
+             len(times[2:])))
+    print(compile_line('train', m0))
     print('train: ' + memory_line(device))
     del step, model, state, before
     gc.collect()
@@ -368,7 +358,7 @@ def agreement(tokens, ref):
     return same / len(ref), (min(first) if first else None)
 
 
-def phase_serve(device, widths, sizes, seed, clock):
+def phase_serve(device, widths, sizes, seed):
     import jax
     # the engine donates its pools on tpu/gpu, not on cpu
     expect_donation = device.platform in ('tpu', 'gpu')
@@ -378,7 +368,7 @@ def phase_serve(device, widths, sizes, seed, clock):
     model.eval()
 
     # parity first, on the f32 weights: engine tokens == generate() tails
-    t0, mark = time.perf_counter(), clock.mark()
+    t0, m0 = time.perf_counter(), time.monotonic()
     with jax.default_matmul_precision('highest'):
         ref = reference_tokens(model, prompts, nt)
         exact = run_engine(model, widths, sizes, prompts, expect_donation)
@@ -389,31 +379,29 @@ def phase_serve(device, widths, sizes, seed, clock):
               '%d of %d requests, first at token %r — a bug (donation, cache '
               'rows, page reuse), not rounding'
               % (name, round((1 - share) * N_REQUESTS), N_REQUESTS, first))
-    compile_s, programs = clock.since(mark)
     print('serve/parity: the engine, direct and behind the gateway, equals '
           'generate() token for token on %d requests x %d tokens (f32, '
-          'highest precision); '
-          '%.1f s wall, %.1f s compiling %d program(s)'
-          % (N_REQUESTS, nt, time.perf_counter() - t0, compile_s, programs))
+          'highest precision); %.1f s wall'
+          % (N_REQUESTS, nt, time.perf_counter() - t0))
+    print(compile_line('serve/parity', m0))
 
-    t0, mark = time.perf_counter(), clock.mark()
+    t0, m0 = time.perf_counter(), time.monotonic()
     model.bfloat16()
     bf16 = run_engine(model, widths, sizes, prompts, expect_donation)
     share, first = agreement(bf16['paged'], ref)
     print('serve/bf16: the engine agrees with the f32 reference on %.0f%% of '
           'requests (first divergence at token %s) — printed, not asserted'
           % (100 * share, first))
-    compile_s, programs = clock.since(mark)
     total = sum(len(t) for toks in bf16.values() for t in toks)
-    print('serve/bf16: %d tokens, %.1f s wall, %.1f s compiling %d '
-          'program(s)' % (total, time.perf_counter() - t0, compile_s,
-                          programs))
+    print('serve/bf16: %d tokens, %.1f s wall'
+          % (total, time.perf_counter() - t0))
+    print(compile_line('serve/bf16', m0))
     print('serve: ' + memory_line(device))
 
 
 # ---- multichip -------------------------------------------------------------
 
-def phase_multichip(device, widths, sizes, seed, clock):
+def phase_multichip(device, widths, sizes, seed):
     import jax
     from paddle_tpu.distributed import fleet
     from paddle_tpu.framework.functional import TrainStep
@@ -439,7 +427,7 @@ def phase_multichip(device, widths, sizes, seed, clock):
                ('dp2 x sharding2', {'dp_degree': 2, 'sharding_degree': 2},
                 {'sharding': True, 'sharding_configs': {'stage': 3}}))
     for label, hybrid, extra in layouts:
-        t0, mark = time.perf_counter(), clock.mark()
+        t0, m0 = time.perf_counter(), time.monotonic()
         strategy = fleet.DistributedStrategy()
         strategy.hybrid_configs = dict(
             {'dp_degree': 1, 'mp_degree': 1, 'pp_degree': 1,
@@ -488,7 +476,6 @@ def phase_multichip(device, widths, sizes, seed, clock):
         per_dev = [sum(s.data.nbytes for p in params.values()
                        for s in p._data.addressable_shards
                        if s.device == d) for d in jax.devices()]
-        compile_s, programs = clock.since(mark)
         print('multichip/%s: losses %s, max |diff| vs single %.4f (tol %.3f)'
               % (label, ' '.join('%.4f' % v for v in losses), worst, tol))
         print('multichip/%s: collectives %s; %d tpu_custom_call(s); %d of %d '
@@ -498,8 +485,9 @@ def phase_multichip(device, widths, sizes, seed, clock):
                                    if kv[1]),
                          kernels, split, len(params),
                          '/'.join('%.1f' % (b / 1e6) for b in per_dev)))
-        print('multichip/%s: %.1f s wall, %.1f s compiling %d program(s)'
-              % (label, time.perf_counter() - t0, compile_s, programs))
+        print('multichip/%s: %.1f s wall'
+              % (label, time.perf_counter() - t0))
+        print(compile_line('multichip/' + label, m0))
         del step, model, params
         gc.collect()
         jax.clear_caches()
@@ -536,16 +524,12 @@ def main(argv=None):
              cache_dir))
 
     widths, sizes = (TOY, TOY_SIZES) if args.rehearse else (FULL, FULL_SIZES)
-    clock = CompileClock()
     t0 = time.perf_counter()
-    try:
-        if args.chips == 4:
-            phase_multichip(device, widths, sizes, args.seed, clock)
-        else:
-            phase_train(device, widths, sizes, args.seed, clock)
-            phase_serve(device, widths, sizes, args.seed, clock)
-    finally:
-        clock.close()
+    if args.chips == 4:
+        phase_multichip(device, widths, sizes, args.seed)
+    else:
+        phase_train(device, widths, sizes, args.seed)
+        phase_serve(device, widths, sizes, args.seed)
     stats = compile_cache.stats()
     print('chip_smoke: all phases passed in %.1f s; compile cache hits %d, '
           'misses %d' % (time.perf_counter() - t0, stats['hits'],

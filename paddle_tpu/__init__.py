@@ -64,6 +64,9 @@ from . import utils  # noqa: F401
 from . import dataset  # noqa: F401
 from . import sysconfig  # noqa: F401
 from . import monitor  # noqa: F401
+# the process's one compile listener (monitor/perf/watchdog.py), before
+# anything builds a program
+monitor.perf.watchdog.install()
 from . import data  # noqa: F401
 
 from .nn.layer.layers import ParamAttr  # noqa: F401

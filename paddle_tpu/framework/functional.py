@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from .core import Tensor
 from . import random as rng_mod
 from ..monitor import tracing as _tracing
+from ..monitor.perf.watchdog import compile_log
 
 __all__ = ['extract_params', 'extract_buffers', 'functional_call',
            'make_loss_post', 'TrainStep']
@@ -488,6 +489,7 @@ class TrainStep:
         if self._out_shardings is not None:
             jit_kwargs['out_shardings'] = self._out_shardings
         self._pure_step = pure_step
+        compile_log().own(type(self).__name__, pure_step)
         return jax.jit(pure_step, **jit_kwargs)
 
     def _lr_array(self):
@@ -571,6 +573,7 @@ class TrainStep:
             (p, b, o), losses = jax.lax.scan(
                 body, (params, buffers, opt_state), (batches, keys))
             return p, b, o, losses
+        compile_log().own(type(self).__name__, multi)
         return jax.jit(multi, **jit_kwargs)
 
     def multi_step(self, inputs, labels):

@@ -3,9 +3,11 @@
 Three components, each usable alone (stdlib at import; jax touched only
 when live):
 
-- ``watchdog``  — CompileWatchdog: jax.monitoring compile listeners,
-  recompile attribution (callsite + abstract-shape signature), warmup
-  barrier with flight-dump + optional strict hard-fail;
+- ``watchdog``  — the process's compile log (every program jax builds:
+  which, which stage, whose, cache hit or not; ``compile_log()``) and
+  CompileWatchdog on it: recompile attribution (callsite +
+  abstract-shape signature), warmup barrier with flight-dump + optional
+  strict hard-fail;
 - ``timeline``  — StepTimeline: data-wait / host-dispatch /
   device-blocked phase split with rolling percentiles and straggler
   detection;
@@ -19,7 +21,9 @@ perf run. See docs/observability.md for the family/label inventory.
 """
 from . import costmodel
 from .timeline import PHASES, StepTimeline
-from .watchdog import COMPILE_EVENTS, CompileWatchdog, RecompileError
+from .watchdog import (COMPILE_EVENTS, CompileLog, CompileWatchdog,
+                       RecompileError, compile_log)
 
 __all__ = ['CompileWatchdog', 'RecompileError', 'COMPILE_EVENTS',
+           'CompileLog', 'compile_log',
            'StepTimeline', 'PHASES', 'costmodel']

@@ -65,7 +65,7 @@ from ..framework import functional as _fm
 from ..framework.core import Tensor, no_grad_guard
 from ..monitor import events as _events
 from ..monitor import tracing as _tracing
-from ..monitor.perf import CompileWatchdog, StepTimeline
+from ..monitor.perf import CompileWatchdog, StepTimeline, compile_log
 from ..monitor.perf import costmodel as _costmodel
 from .kv_cache import (PageAllocator, PrefixCache, SlotAllocator,
                        build_paged_pools, cache_specs, kv_row_bytes,
@@ -389,6 +389,9 @@ class PagedContinuousBatchingEngine:
         self._decode_jit = jax.jit(self._decode_fn, donate_argnums=dn)
         self._verify_jit = jax.jit(self._verify_fn, donate_argnums=dn)
         self._verify_args = None
+        # the compile log tags what these build with this class's name
+        compile_log().own(type(self).__name__, self._prefill_fn,
+                          self._carry_fn, self._decode_fn, self._verify_fn)
 
     @property
     def num_seqs(self):

@@ -55,3 +55,14 @@ def seed():
     import paddle_tpu as paddle
     paddle.seed(2024)
     return 2024
+
+
+@pytest.fixture(autouse=True)
+def _fresh_compile_log():
+    """Each test starts with an empty compile log, as a process does: the
+    log keeps the first entries of its process, and a worker runs many
+    tests (the benchmark's readers take what was built before a window)."""
+    import sys
+    mod = sys.modules.get('paddle_tpu.monitor.perf.watchdog')
+    if mod is not None:
+        mod.compile_log().reset()

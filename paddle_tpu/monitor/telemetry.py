@@ -20,7 +20,7 @@ __all__ = ['record_dryrun_step', 'record_serving_schema',
            'record_request_event_schema', 'record_tenant_schema',
            'record_qos_schema', 'record_capacity_schema',
            'record_ingest_schema', 'record_registry_schema',
-           'snapshot_line',
+           'record_kernel_schema', 'snapshot_line',
            'parse_snapshot_lines', 'LINE_RE']
 
 LINE_RE = re.compile(r'telemetry_snapshot\((?P<n>\d+)\)'
@@ -685,6 +685,25 @@ def record_registry_schema(registry):
     return out
 
 
+# the Pallas kernels' trace-time plan counter: ops/flash_attention.py
+# adds each traced causal standard-path call's tiles on the default
+# registry; dryrun_registry registers it so the baseline covers it.
+# Label budgets: pass is fwd | dq | dkv, kind unmasked | diagonal |
+# skipped — closed sets.
+KERNEL_FAMILIES = (
+    ('counter', 'flash_kernel_tiles_total',
+     'tiles of the causal flash kernels\' plans, per traced call, '
+     'summed over its batch x heads', ('kind', 'pass')),
+)
+
+
+def record_kernel_schema(registry):
+    """Register the kernel plan families on `registry` and return
+    {name: family}."""
+    return {name: getattr(registry, kind)(name, doc, labels)
+            for kind, name, doc, labels in KERNEL_FAMILIES}
+
+
 def dryrun_registry(step_seconds, loss, batch=None, registry=None):
     """Fresh per-config registry holding the full dryrun telemetry
     schema: training gauges + serving + tracing + perf families + one
@@ -711,6 +730,7 @@ def dryrun_registry(step_seconds, loss, batch=None, registry=None):
     record_capacity_schema(reg)
     record_ingest_schema(reg)
     record_registry_schema(reg)
+    record_kernel_schema(reg)
     RuntimeSampler(registry=reg, jax_metrics=True).sample_once()
     return reg
 

@@ -152,6 +152,62 @@ def _mm_f32(a, b, transpose_a=False, transpose_b=False):
                                preferred_element_type=jnp.float32)
 
 
+# -- the causal tile plan ----------------------------------------------------
+#
+# A grid cell of a standard-path kernel walks one axis of the [n, n] score
+# square: the K blocks of its query block (forward, dq) or the Q blocks of
+# its key block (dk/dv). Under the causal mask (n == m) a tile of that walk
+# with no pair key <= query contributes exactly 0, so it is not visited; a
+# tile wholly on the visible side needs no mask; only the tiles the
+# diagonal crosses pay _causal_mask. Neither a skipped tile nor a dropped
+# select changes a value, so the plan changes no bit of a result.
+
+def _causal_plan(i, block_q, block_k, n, walk):
+    """Grid cell i's walk as [(lo, hi, masked), ...] in walk order: walk
+    'k' visits the K blocks of query block i (the unmasked ones, then those
+    the diagonal crosses), walk 'q' the Q blocks of key block i (those the
+    diagonal crosses, then the unmasked ones). Plain arithmetic, so it
+    runs on Python ints (the tile counter) and traced program ids alike."""
+    if walk == 'k':
+        split = (i * block_q + 1) // block_k
+        end = ((i + 1) * block_q + block_k - 1) // block_k
+        return [(0, split, False), (split, end, True)]
+    first = i * block_k // block_q
+    split = ((i + 1) * block_k + block_q - 2) // block_q
+    return [(first, split, True), (split, n // block_q, False)]
+
+
+def _diag_sub_block(block_q, block_k):
+    """Rows (dk/dv: columns) per sub-block of a square diagonal tile, or
+    None where the tile is not split. Sub-block r of the tile meets the
+    diagonal at its own rows, so its walk ends there: at 512 / 512 the
+    tile's masked half shrinks to a quarter. Needs block_q == block_k
+    (the diagonal is then exactly one tile at a static offset) and a
+    sub-block of whole 128-lane tiles."""
+    if block_q != block_k or block_q < 256:
+        return None
+    return block_q // 2
+
+
+def _count_tiles(pass_name, heads, n, block_q, block_k):
+    """Add one traced causal call's plan to flash_kernel_tiles_total:
+    every tile of each of its `heads` (batch x heads) score squares, as
+    unmasked, diagonal (the mask applied) or skipped (not visited)."""
+    from ..monitor.registry import default_registry
+    from ..monitor.telemetry import record_kernel_schema
+    walk = 'q' if pass_name == 'dkv' else 'k'
+    cells, steps = ((n // block_k, n // block_q) if walk == 'q'
+                    else (n // block_q, n // block_k))
+    tiles = {'unmasked': 0, 'diagonal': 0}
+    for i in range(cells):
+        for lo, hi, masked in _causal_plan(i, block_q, block_k, n, walk):
+            tiles['diagonal' if masked else 'unmasked'] += hi - lo
+    tiles['skipped'] = cells * steps - tiles['unmasked'] - tiles['diagonal']
+    fam = record_kernel_schema(default_registry())['flash_kernel_tiles_total']
+    for kind, count in tiles.items():
+        fam.labels(kind, pass_name).inc(heads * count)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
                 block_k, seq_k):
     from jax.experimental import pallas as pl
@@ -160,19 +216,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
     block_q, head_dim = q.shape
     qi = pl.program_id(2)
 
-    m_i = jnp.full((block_q,), -jnp.inf, jnp.float32)
-    l_i = jnp.zeros((block_q,), jnp.float32)
-    acc = jnp.zeros((block_q, head_dim), jnp.float32)
-
-    num_kb = seq_k // block_k
-
-    def body(kb, carry):
+    def update(carry, q_rows, q_start, k_start, width, masked):
+        # one online-softmax step of q_rows over keys [k_start, +width)
         m_prev, l_prev, acc_prev = carry
-        k_blk = k_ref[pl.ds(kb * block_k, block_k), :]
-        v_blk = v_ref[pl.ds(kb * block_k, block_k), :]
-        s = _mm_f32(q, k_blk, transpose_b=True) * scale  # [bq, bk] f32
-        if causal:
-            s = _causal_mask(s, qi * block_q, kb * block_k)
+        k_blk = k_ref[pl.ds(k_start, width), :]
+        v_blk = v_ref[pl.ds(k_start, width), :]
+        s = _mm_f32(q_rows, k_blk, transpose_b=True) * scale  # f32
+        if masked:
+            s = _causal_mask(s, q_start, k_start)
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
         p = jnp.exp(s - m_cur[:, None])
         alpha = jnp.exp(m_prev - m_cur)
@@ -181,17 +232,37 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
             _mm_f32(p.astype(v_blk.dtype), v_blk)
         return m_cur, l_cur, acc_cur
 
-    if causal:
-        # only iterate over blocks at or before the diagonal
-        last = jnp.minimum(num_kb, (qi + 1) * block_q // block_k + 1)
-    else:
-        last = num_kb
-    m_i, l_i, acc = jax.lax.fori_loop(0, last, body, (m_i, l_i, acc))
-    l_safe = jnp.maximum(l_i, 1e-30)
-    o_ref[...] = (acc / l_safe[:, None]).astype(o_ref.dtype)
-    # lse carries a trailing singleton dim: Mosaic wants >=2-D blocks with
-    # an aligned (or full) minor dimension
-    lse_ref[...] = (m_i + jnp.log(l_safe))[:, None]
+    def walk(carry, lo, hi, masked):
+        return jax.lax.fori_loop(lo, hi, lambda kb, c: update(
+            c, q, qi * block_q, kb * block_k, block_k, masked), carry)
+
+    def finish(carry, rows):
+        m_i, l_i, acc = carry
+        l_safe = jnp.maximum(l_i, 1e-30)
+        o_ref[rows, :] = (acc / l_safe[:, None]).astype(o_ref.dtype)
+        # lse carries a trailing singleton dim: Mosaic wants >=2-D blocks
+        # with an aligned (or full) minor dimension
+        lse_ref[rows, :] = (m_i + jnp.log(l_safe))[:, None]
+
+    carry = (jnp.full((block_q,), -jnp.inf, jnp.float32),
+             jnp.zeros((block_q,), jnp.float32),
+             jnp.zeros((block_q, head_dim), jnp.float32))
+    if not causal:
+        finish(walk(carry, 0, seq_k // block_k, False), slice(None))
+        return
+    unmasked, diagonal = _causal_plan(qi, block_q, block_k, seq_k, 'k')
+    carry = walk(carry, *unmasked)
+    sub = _diag_sub_block(block_q, block_k)
+    if sub is None:
+        finish(walk(carry, *diagonal), slice(None))
+        return
+    # the diagonal is tile qi: row sub-block r sees keys up to its last row
+    q0 = qi * block_q
+    for r in range(block_q // sub):
+        rows = slice(r * sub, (r + 1) * sub)
+        part = update(tuple(x[rows] for x in carry), q[rows], q0 + r * sub,
+                      q0, (r + 1) * sub, True)
+        finish(part, rows)
 
 
 def _fwd_impl(q, k, v, causal, scale):
@@ -201,6 +272,8 @@ def _fwd_impl(q, k, v, causal, scale):
     b, h, n, d = q.shape
     m = k.shape[2]
     block_q, block_k = _std_blocks(n, m)
+    if causal:
+        _count_tiles('fwd', b * h, n, block_q, block_k)
 
     grid = (b, h, n // block_q)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -611,30 +684,46 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     block_q, head_dim = q.shape
     qi = pl.program_id(2)
 
-    dq = jnp.zeros((block_q, head_dim), jnp.float32)
-    num_kb = seq_k // block_k
-
-    def body(kb, dq_prev):
-        k_blk = k_ref[pl.ds(kb * block_k, block_k), :]
-        v_blk = v_ref[pl.ds(kb * block_k, block_k), :]
-        s = _mm_f32(q, k_blk, transpose_b=True) * scale
-        if causal:
-            s = _causal_mask(s, qi * block_q, kb * block_k)
+    def update(dq_prev, rows, q_start, k_start, width, masked):
+        # rows: (q, do, lse, delta) of the query rows; keys [k_start, +width)
+        q_r, do_r, lse_r, delta_r = rows
+        k_blk = k_ref[pl.ds(k_start, width), :]
+        v_blk = v_ref[pl.ds(k_start, width), :]
+        s = _mm_f32(q_r, k_blk, transpose_b=True) * scale
+        if masked:
+            s = _causal_mask(s, q_start, k_start)
         # clamped exp: for valid rows s - lse <= ~0; the headroom only
         # matters when a caller (ring attention) zero-weights a block it
         # computed unmasked — without the clamp an overflowing exp would
         # turn 0 * inf into NaN
-        p = jnp.exp(jnp.minimum(s - lse, 30.0))
-        dp = _mm_f32(do, v_blk, transpose_b=True)
-        ds = p * (dp - delta) * scale
+        p = jnp.exp(jnp.minimum(s - lse_r, 30.0))
+        dp = _mm_f32(do_r, v_blk, transpose_b=True)
+        ds = p * (dp - delta_r) * scale
         return dq_prev + _mm_f32(ds.astype(k_blk.dtype), k_blk)
 
-    if causal:
-        last = jnp.minimum(num_kb, (qi + 1) * block_q // block_k + 1)
-    else:
-        last = num_kb
-    dq = jax.lax.fori_loop(0, last, body, dq)
-    dq_ref[...] = dq.astype(dq_ref.dtype)
+    def walk(dq, lo, hi, masked):
+        return jax.lax.fori_loop(lo, hi, lambda kb, acc: update(
+            acc, (q, do, lse, delta), qi * block_q, kb * block_k, block_k,
+            masked), dq)
+
+    dq = jnp.zeros((block_q, head_dim), jnp.float32)
+    if not causal:
+        dq_ref[...] = walk(dq, 0, seq_k // block_k, False).astype(
+            dq_ref.dtype)
+        return
+    unmasked, diagonal = _causal_plan(qi, block_q, block_k, seq_k, 'k')
+    dq = walk(dq, *unmasked)
+    sub = _diag_sub_block(block_q, block_k)
+    if sub is None:
+        dq_ref[...] = walk(dq, *diagonal).astype(dq_ref.dtype)
+        return
+    # the diagonal is tile qi: row sub-block r sees keys up to its last row
+    q0 = qi * block_q
+    for r in range(block_q // sub):
+        rows = slice(r * sub, (r + 1) * sub)
+        part = update(dq[rows], tuple(x[rows] for x in (q, do, lse, delta)),
+                      q0 + r * sub, q0, (r + 1) * sub, True)
+        dq_ref[rows, :] = part.astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -646,37 +735,55 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     block_k, head_dim = k_blk.shape
     ki = pl.program_id(2)
 
-    dk = jnp.zeros((block_k, head_dim), jnp.float32)
-    dv = jnp.zeros((block_k, head_dim), jnp.float32)
-    num_qb = seq_q // block_q
-
-    def body(qb, carry):
+    def update(carry, k_cols, v_cols, k_start, q_start, width, masked):
+        # keys k_cols (from k_start) against queries [q_start, +width)
         dk_prev, dv_prev = carry
-        q_b = q_ref[pl.ds(qb * block_q, block_q), :]
-        do_b = do_ref[pl.ds(qb * block_q, block_q), :]
-        lse_b = lse_ref[pl.ds(qb * block_q, block_q), :]      # [bq, 1]
-        delta_b = delta_ref[pl.ds(qb * block_q, block_q), :]  # [bq, 1]
-        s = _mm_f32(q_b, k_blk, transpose_b=True) * scale  # [bq, bk]
-        if causal:
-            s = _causal_mask(s, qb * block_q, ki * block_k)
-        p = jnp.exp(jnp.minimum(s - lse_b, 30.0))  # [bq, bk]; see dq kernel
+        q_b = q_ref[pl.ds(q_start, width), :]
+        do_b = do_ref[pl.ds(q_start, width), :]
+        lse_b = lse_ref[pl.ds(q_start, width), :]      # [w, 1]
+        delta_b = delta_ref[pl.ds(q_start, width), :]  # [w, 1]
+        s = _mm_f32(q_b, k_cols, transpose_b=True) * scale  # [w, bk]
+        if masked:
+            s = _causal_mask(s, q_start, k_start)
+        p = jnp.exp(jnp.minimum(s - lse_b, 30.0))  # see the dq kernel
         dv_cur = dv_prev + _mm_f32(p.astype(do_b.dtype), do_b,
                                    transpose_a=True)
-        dp = _mm_f32(do_b, v_blk, transpose_b=True)  # [bq, bk]
+        dp = _mm_f32(do_b, v_cols, transpose_b=True)  # [w, bk]
         ds = p * (dp - delta_b) * scale
         dk_cur = dk_prev + _mm_f32(ds.astype(q_b.dtype), q_b,
                                    transpose_a=True)
         return dk_cur, dv_cur
 
-    if causal:
-        # rows strictly above the diagonal contribute nothing to this
-        # k block: start at the first q block that can see it
-        first = (ki * block_k) // block_q
-    else:
-        first = 0
-    dk, dv = jax.lax.fori_loop(first, num_qb, body, (dk, dv))
-    dk_ref[...] = dk.astype(dk_ref.dtype)
-    dv_ref[...] = dv.astype(dv_ref.dtype)
+    def walk(carry, lo, hi, masked):
+        return jax.lax.fori_loop(lo, hi, lambda qb, c: update(
+            c, k_blk, v_blk, ki * block_k, qb * block_q, block_q, masked),
+            carry)
+
+    def finish(carry, cols):
+        dk, dv = carry
+        dk_ref[cols, :] = dk.astype(dk_ref.dtype)
+        dv_ref[cols, :] = dv.astype(dv_ref.dtype)
+
+    carry = (jnp.zeros((block_k, head_dim), jnp.float32),
+             jnp.zeros((block_k, head_dim), jnp.float32))
+    if not causal:
+        finish(walk(carry, 0, seq_q // block_q, False), slice(None))
+        return
+    diagonal, unmasked = _causal_plan(ki, block_q, block_k, seq_q, 'q')
+    sub = _diag_sub_block(block_q, block_k)
+    if sub is None:
+        finish(walk(walk(carry, *diagonal), *unmasked), slice(None))
+        return
+    # the diagonal is tile ki, walked last: column sub-block c is seen by
+    # the queries from its own first column on
+    carry = walk(carry, *unmasked)
+    k0 = ki * block_k
+    for c in range(block_k // sub):
+        cols = slice(c * sub, (c + 1) * sub)
+        part = update(tuple(x[cols] for x in carry), k_blk[cols],
+                      v_blk[cols], k0 + c * sub, k0 + c * sub,
+                      block_k - c * sub, True)
+        finish(part, cols)
 
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -761,6 +868,9 @@ def _bwd_impl(q, k, v, o, lse, do, causal, scale):
     if block_q == n and block_k == m and _fused_bwd_enabled():
         # one tile covers the whole score matrix: single fused kernel
         return _bwd_impl_fused(q, k, v, lse, do, delta, causal, scale)
+    if causal:
+        _count_tiles('dq', b * h, n, block_q, block_k)
+        _count_tiles('dkv', b * h, n, block_q, block_k)
 
     kwargs = {}
     if interpret_mode():

@@ -32,10 +32,37 @@ def _interpret_strict(monkeypatch):
     monkeypatch.setenv('PADDLE_TPU_FLASH_STRICT', '1')
 
 
-@pytest.mark.parametrize('d', [64, 128])
-@pytest.mark.parametrize('causal', [False, True])
-def test_forward_matches_reference(d, causal):
-    q, k, v = _mk(d=d)
+# (causal, d, n, blocks): the first four at seq 256 (one tile); the rest
+# at seq 1024 with the standard path's blocks set to each pair, so the
+# causal tile plan runs every shape of walk — square tiles (the diagonal
+# split into sub-blocks), a wider query block, a wider key block — and
+# the non-causal walk runs beside it at one pair
+_CASES = [(False, 64, 256, None), (False, 128, 256, None),
+          (True, 64, 256, None), (True, 128, 256, None),
+          (True, 64, 1024, (512, 512)), (True, 64, 1024, (256, 256)),
+          (True, 64, 1024, (512, 256)), (True, 64, 1024, (256, 512)),
+          (False, 64, 1024, (512, 256))]
+_CASE_IDS = ['%s-%d' % (c, d) + ('-n%d-%dx%d' % ((n,) + b) if b else '')
+             for c, d, n, b in _CASES]
+
+
+def _set_blocks(monkeypatch, blocks):
+    """Both passes of the standard path at (block_q, block_k), for this
+    test only (the knob table and the environment stay as they are)."""
+    if blocks is None:
+        return
+    bq, bk = blocks
+    for name, value in (('_DEFAULT_BLOCK_Q', bq), ('_DEFAULT_BLOCK_K', bk),
+                        ('_BLOCK_Q_BWD', bq), ('_BLOCK_K_BWD', bk)):
+        monkeypatch.setattr(fa, name, value)
+    assert fa._std_blocks(1024, 1024) == blocks
+    assert fa._std_bwd_blocks(1024, 1024) == blocks
+
+
+@pytest.mark.parametrize('causal,d,n,blocks', _CASES, ids=_CASE_IDS)
+def test_forward_matches_reference(monkeypatch, causal, d, n, blocks):
+    _set_blocks(monkeypatch, blocks)
+    q, k, v = _mk(d=d, n=n)
     scale = 1.0 / np.sqrt(d)
     out = fa.flash_attention_bhnd(q, k, v, causal=causal)
     ref = fa._ref_bhnd(q, k, v, causal, scale)
@@ -43,10 +70,10 @@ def test_forward_matches_reference(d, causal):
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize('d', [64, 128])
-@pytest.mark.parametrize('causal', [False, True])
-def test_backward_matches_reference(d, causal):
-    q, k, v = _mk(d=d, n=256)
+@pytest.mark.parametrize('causal,d,n,blocks', _CASES, ids=_CASE_IDS)
+def test_backward_matches_reference(monkeypatch, causal, d, n, blocks):
+    _set_blocks(monkeypatch, blocks)
+    q, k, v = _mk(d=d, n=n)
     scale = 1.0 / np.sqrt(d)
 
     def f_flash(q, k, v):
@@ -62,6 +89,64 @@ def test_backward_matches_reference(d, causal):
                                    rtol=2e-4, atol=2e-4,
                                    err_msg='d%s/causal=%s grad %s'
                                            % (d, causal, name))
+
+
+def _brute_tiles(n, block_q, block_k):
+    """Each (q block, k block) tile of the causal [n, n] square by its
+    positions: 'unmasked' (every pair key <= query), 'diagonal' (some
+    pairs on each side) or 'skipped' (none visible)."""
+    pos = np.arange(n)
+    visible = pos[None, :] <= pos[:, None]
+    kinds = {}
+    for qb in range(n // block_q):
+        for kb in range(n // block_k):
+            tile = visible[qb * block_q:(qb + 1) * block_q,
+                           kb * block_k:(kb + 1) * block_k]
+            kinds[qb, kb] = ('unmasked' if tile.all() else
+                             'diagonal' if tile.any() else 'skipped')
+    return kinds
+
+
+@pytest.mark.parametrize('blocks', [(512, 512), (256, 256), (512, 256),
+                                    (256, 512)])
+@pytest.mark.parametrize('n', [512, 768, 1024, 2048])
+def test_causal_plan_matches_brute_force(n, blocks):
+    """Every tile with a visible pair is visited, no other tile is, and
+    the mask goes exactly on the tiles the diagonal crosses — for the K
+    walk of each query block (forward, dq) and the Q walk of each key
+    block (dk/dv), at the blocks the kernels would run (768 clamps)."""
+    bq, bk = fa._clamped(blocks[0], blocks[1], n, n)
+    kinds = _brute_tiles(n, bq, bk)
+    for walk, cells in (('k', n // bq), ('q', n // bk)):
+        for i in range(cells):
+            seen = {}
+            for lo, hi, masked in fa._causal_plan(i, bq, bk, n, walk):
+                for j in range(lo, hi):
+                    tile = (i, j) if walk == 'k' else (j, i)
+                    assert tile not in seen, (walk, tile)
+                    seen[tile] = 'diagonal' if masked else 'unmasked'
+            want = {t: kind for t, kind in kinds.items()
+                    if t[0 if walk == 'k' else 1] == i and kind != 'skipped'}
+            assert seen == want, (n, bq, bk, walk, i)
+
+
+def test_tile_counter_counts_one_traced_call(monkeypatch):
+    """flash_kernel_tiles_total grows, per pass, by the brute-force tile
+    counts of every (batch, head) square of one traced call: forward,
+    dq and dk/dv at 512 / 256 over seq 1024, batch 2 x 3 heads."""
+    from paddle_tpu.monitor.registry import MetricRegistry
+    from paddle_tpu.monitor import registry as registry_mod
+    monkeypatch.setattr(registry_mod, '_default', MetricRegistry())
+    _set_blocks(monkeypatch, (512, 256))
+    q, k, v = _mk(b=2, h=3, n=1024)
+    jax.eval_shape(jax.grad(lambda q: jnp.sum(
+        fa.flash_attention_bhnd(q, k, v, causal=True))), q)
+    fam = registry_mod.default_registry().get('flash_kernel_tiles_total')
+    kinds = list(_brute_tiles(1024, 512, 256).values())
+    for pass_name in ('fwd', 'dq', 'dkv'):
+        for kind in ('unmasked', 'diagonal', 'skipped'):
+            assert fam.labels(kind, pass_name).value() == \
+                2 * 3 * kinds.count(kind), (pass_name, kind)
 
 
 def test_cross_attention_shapes():
